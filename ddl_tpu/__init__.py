@@ -17,16 +17,6 @@ rotation inside ``shard_map``; the hybrid config (reference
 
 __version__ = "0.1.0"
 
-# Fill in modern JAX surface names (jax.set_mesh / jax.shard_map /
-# jax.lax.axis_size / pallas CompilerParams) when running on an older
-# runtime that spells them differently; a no-op on current JAX.  See
-# ddl_tpu/compat.py.  A box with no JAX at all (log-analysis host
-# running only `ddl_tpu obs`) imports fine — the obs report path never
-# touches JAX.
-try:
-    from ddl_tpu import compat as _compat
-except ImportError:
-    pass
-else:
-    _compat.install()
-    del _compat
+# Importing the package imports no JAX: the supervisor, the `obs`
+# readers and chip_smoke.py's parent must stay off the chip their
+# children need (a chip belongs to one process at a time).

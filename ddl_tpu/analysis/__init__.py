@@ -8,7 +8,7 @@ Two engines behind one CLI (``analysis/cli.py``):
   collective-symmetry (host-conditional barriers/collectives),
   recompile hazards (traced shape/dtype branches, unhashable/fresh jit
   static args, mutable-global closures), bare/over-broad excepts in
-  recovery paths, legacy-JAX spellings that bypass ``compat.py``,
+  recovery paths, legacy-JAX spellings,
   unregistered AND dead obs event names, unknown ``PartitionSpec``
   axes, missing jit donation.  Pure ``ast`` — no JAX import, runs
   anywhere in milliseconds.

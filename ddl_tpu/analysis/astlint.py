@@ -120,9 +120,9 @@ _RECOVERY_MODULES = frozenset({
 })
 
 # Step-function factory modules: every jitted train step must declare
-# buffer donation (checked here) — whether the runtime honors it is the
-# contract checker's runtime concern (compat.py strips donation on old
-# jaxlib, an explicit waiver).
+# buffer donation (checked here) — whether the compiled step aliases
+# the donated buffers is the contract checker's concern
+# (contracts.py zero_donation).
 _STEP_MODULES = frozenset({
     "train/steps.py",
     "train/lm_steps.py",
@@ -906,8 +906,9 @@ def _rule_excepts(tree, rel: str, add) -> None:
 
 
 def _rule_compat(tree, rel: str, add) -> None:
-    if rel_suffix(rel) == "compat.py":
-        return
+    """Legacy JAX spellings: one installation (jax 0.9.0) is supported
+    and nothing aliases the old names any more, so they fail at import
+    or call time on the chip — catch them here."""
     for node in ast.walk(tree):
         if isinstance(node, ast.ImportFrom):
             m = node.module or ""
@@ -916,13 +917,11 @@ def _rule_compat(tree, rel: str, add) -> None:
                 and any(a.name in ("shard_map", "pjit") for a in node.names)
             ):
                 add(node, "compat-bypass",
-                    "legacy jax.experimental.shard_map/pjit import bypasses "
-                    "the compat.py shim; use jax.shard_map / jax.jit "
-                    "(compat installs them on old runtimes)")
+                    "legacy jax.experimental.shard_map/pjit import; use "
+                    "jax.shard_map / jax.jit")
             elif m.startswith("jax.experimental.pjit"):
                 add(node, "compat-bypass",
-                    "legacy pjit import; use jax.jit (compat.py guarantees "
-                    "the modern surface)")
+                    "legacy pjit import; use jax.jit")
         elif isinstance(node, ast.Attribute):
             d = _dotted(node)
             if d and (
@@ -930,19 +929,18 @@ def _rule_compat(tree, rel: str, add) -> None:
                 or d.startswith("jax.experimental.pjit")
             ):
                 add(node, "compat-bypass",
-                    f"direct {d} use bypasses the compat.py shim; use the "
+                    f"direct {d} use is the legacy spelling; use the "
                     "modern jax.* name")
             elif node.attr == "TPUCompilerParams":
                 add(node, "compat-bypass",
                     "TPUCompilerParams is the legacy spelling; use "
-                    "pltpu.CompilerParams (compat.py aliases it on old "
-                    "runtimes)")
+                    "pltpu.CompilerParams")
         elif isinstance(node, ast.Call):
             for kw in node.keywords:
                 if kw.arg == "check_rep":
                     add(node, "compat-bypass",
                         "check_rep= is the legacy shard_map kwarg; pass "
-                        "check_vma= (compat.py translates on old runtimes)")
+                        "check_vma=")
 
 
 # Call attrs treated as obs-event emission sites: the writer itself and
@@ -1094,8 +1092,7 @@ def _rule_donation(tree, mod: _Module, rel: str, add) -> None:
             add(node, "donation-missing",
                 f"jax.jit({node.args[0].id}, ...) without donate_argnums: "
                 "the train state is copied instead of donated — 2x state "
-                "HBM held across the update (compat.py strips donation on "
-                "old runtimes; new step factories must still declare it)")
+                "HBM held across the update")
 
 
 def _rule_exit_intent(tree, mod: _Module, rel: str, add) -> None:

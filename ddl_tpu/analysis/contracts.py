@@ -31,10 +31,8 @@ TPU required, the same trick the test suite uses) and validated:
   measured per-device optimizer-state bytes vs the replicated layout
   (the ~(dp-1)/dp reduction of PAPERS.md's cross-replica sharding);
 * donation is declared by every train factory (the AST side checks the
-  call sites; here the *runtime* is probed — on old jaxlib
-  ``compat.py`` strips donation deliberately, which is reported as a
-  waiver note; when compat retires, ``zero_donation`` asserts the
-  donated buffers actually alias outputs in the compiled ZeRO step).
+  call sites; here the *compiled step* is probed — ``zero_donation``
+  asserts the donated buffers actually alias outputs).
 
 Probe configs are intentionally tiny (d_model 64, 2 layers) but sized so
 the big kernels cross ``REPLICATION_THRESHOLD`` — a replication
@@ -74,9 +72,9 @@ def ensure_simulated_mesh(min_devices: int = _MIN_DEVICES) -> int:
     import jax
 
     try:
-        # config.update wins over a registered-but-uninitialised TPU
-        # plugin (same reasoning as tests/conftest.py); if a backend is
-        # already up this is a no-op or a warning, never a crash
+        # the probes never claim a chip (same reasoning as
+        # tests/conftest.py); if a backend is already up this is a
+        # no-op or a warning, never a crash
         jax.config.update("jax_platforms", "cpu")
     except RuntimeError:
         pass
@@ -518,41 +516,38 @@ def _probe_lm_zero() -> _Probe:
 
 
 def _probe_zero_donation() -> _Probe:
-    """Donation effectiveness across the train-step families (PR-3
-    carry-over, generalized): on runtimes where compat.py strips jit
-    donation, report the waiver; once compat retires, compile one step
-    per family (CNN-ZeRO, LM, ViT) and measure how much of the donated
-    train state actually aliases outputs — aliased-bytes over
+    """Donation effectiveness across the train-step families: compile
+    one step per family (CNN-ZeRO, LM, ViT) and measure how much of the
+    donated train state actually aliases outputs — aliased-bytes over
     donatable-bytes from the compiled module's ``input_output_alias``
     header, parsed by the compiled-IR lint (analysis/hlolint.py).
     Donation that silently stopped aliasing would double state HBM
     right where ZeRO/donation is trying to save it."""
     import jax
 
+    from ddl_tpu.analysis.hlolint import parse_aliases
+    from ddl_tpu.obs.hbm import tree_shard_bytes
     from ddl_tpu.train.steps import make_dp_step_fns
 
     probe = _Probe(make_dp_step_fns)
-    if hasattr(jax.jit, "__wrapped__"):
-        probe.note(
-            "donation-effectiveness waived: compat.py strips jit donation "
-            "on this runtime (old jaxlib mis-aliases donated buffers "
-            "under shard_map); when compat retires, this probe compiles "
-            "one step per family (CNN-ZeRO, LM, ViT) and asserts "
-            "input_output_alias coverage of the donated state"
-        )
-        return probe
-
-    from ddl_tpu.analysis.hlolint import (
-        _state_bytes,
-        parse_aliases,
-        parse_param_bytes,
-    )
 
     def check(name: str, build) -> None:
         try:
             train, state = build()
-            text = train.lower(state, *train.probe_inputs()).compile(
-            ).as_text()
+            inputs = train.probe_inputs()
+            # the STEADY-STATE step: its state operand is the state the
+            # step itself returned.  A fresh init_state may be laid out
+            # differently from the step's output (moments replicated at
+            # init, sharded after), and a leaf whose layout changes
+            # cannot alias — true of step 1 only, not of the run
+            first = train.lower(state, *inputs).compile()
+            state = jax.tree.map(
+                lambda leaf, sh: jax.ShapeDtypeStruct(
+                    leaf.shape, leaf.dtype, sharding=sh
+                ),
+                state, first.output_shardings[0],
+            )
+            text = train.lower(state, *inputs).compile().as_text()
         except Exception as e:
             msg = str(e).splitlines()[0][:200] if str(e) else ""
             probe.add(
@@ -570,12 +565,16 @@ def _probe_zero_donation() -> _Probe:
                 "doubling state HBM across the update",
             )
             return
-        param_bytes = parse_param_bytes(text)
+        # jit flattens the state first, so its leaves are the module's
+        # parameters 0..n-1; bytes are one device's shards on both sides
+        # (sized from the leaves, not from ``parameter(N)`` shapes in
+        # the text: nested computations number their own parameters)
+        leaf_bytes = [tree_shard_bytes(leaf) for leaf in jax.tree.leaves(state)]
         aliased = sum(
-            param_bytes.get(p, 0)
-            for _out, p, pidx in aliases if pidx == ""
+            leaf_bytes[p] for _out, p, pidx in aliases
+            if pidx == "" and p < len(leaf_bytes)
         )
-        donatable = _state_bytes(state)
+        donatable = sum(leaf_bytes)
         probe.note(
             f"{name} donation effectiveness: {aliased}/{donatable} "
             f"bytes aliased ({aliased / max(donatable, 1):.0%})"
@@ -879,8 +878,6 @@ PROBES = (
 
 def run_contracts(min_devices: int = _MIN_DEVICES) -> ContractReport:
     """Run every registered probe; returns findings + waiver notes."""
-    import jax
-
     n = ensure_simulated_mesh(min_devices)
     findings: list[Finding] = []
     notes: list[str] = []
@@ -892,13 +889,6 @@ def run_contracts(min_devices: int = _MIN_DEVICES) -> ContractReport:
             "JAX initialises)"
         )
         return ContractReport(findings, notes)
-    if hasattr(jax.jit, "__wrapped__"):
-        notes.append(
-            "donation waived: compat.py strips jit donation on this "
-            "runtime (old jaxlib mis-aliases donated buffers under "
-            "shard_map) — factories still declare it, the AST rule "
-            "still enforces declaration"
-        )
     for name, probe_fn in PROBES:
         try:
             probe = probe_fn()

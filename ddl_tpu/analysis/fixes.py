@@ -7,7 +7,7 @@ edit is implied by the finding itself:
 * ``bare-except`` — ``except:`` → ``except Exception:`` (narrower is a
   human judgement; not swallowing SystemExit/KeyboardInterrupt is not);
 * ``compat-bypass`` — legacy ``jax.experimental.shard_map`` imports
-  rewritten to the compat-guaranteed ``from jax import shard_map``,
+  rewritten to ``from jax import shard_map``,
   ``check_rep=`` → ``check_vma=``, ``TPUCompilerParams`` →
   ``CompilerParams`` (the ``pjit`` variants need call-site rewrites and
   stay manual);
@@ -18,10 +18,8 @@ edit is implied by the finding itself:
 * ``obs-event-unregistered`` — the emitted-but-unregistered kind is
   appended to ``EVENT_KINDS`` in ``<package>/obs/events.py``;
 * ``donation-missing`` — ``donate_argnums=(0,)`` is inserted into the
-  flagged ``jax.jit(train_step, ...)`` call (behavior-safe: compat.py
-  strips donation on runtimes that can't honor it, and on runtimes that
-  can, donating the consumed train state is exactly what the finding
-  demands).
+  flagged ``jax.jit(train_step, ...)`` call (behavior-safe: donating
+  the consumed train state is exactly what the finding demands).
 
 The contract the tests pin: fixes are **deterministic** (same findings →
 same bytes) and **idempotent** (fix → clean lint for these classes → a

@@ -65,9 +65,9 @@ def main() -> None:
     from ddl_tpu.ops.quant import quantize_lm_params
     from ddl_tpu.parallel.sharding import LMMeshSpec, build_lm_mesh
     from ddl_tpu.train.lm_steps import LMTrainState, make_lm_step_fns
-    from ddl_tpu.utils.compile_cache import enable_compile_cache
+    from ddl_tpu.utils.compile_cache import activate_compile_cache
 
-    enable_compile_cache()
+    activate_compile_cache()
     cfg = LMConfig(
         vocab_size=args.vocab,
         d_model=args.d_model,

@@ -72,9 +72,9 @@ def main() -> None:
     ap.add_argument("--iters", type=int, default=10)
     args = ap.parse_args()
 
-    from ddl_tpu.utils.compile_cache import enable_compile_cache
+    from ddl_tpu.utils.compile_cache import activate_compile_cache
 
-    enable_compile_cache()
+    activate_compile_cache()
 
     cfg = LMConfig(
         vocab_size=args.vocab,

@@ -37,7 +37,6 @@ PEAK_BF16_FLOPS = {
     "TPU v5p": 459e12,
     "TPU v5 lite": 197e12,  # v5e
     "TPU v5e": 197e12,
-    "TPU v5": 197e12,  # bare "TPU v5" device_kind strings are v5e in practice
     "TPU v4": 275e12,
     "TPU v3": 123e12,
     "TPU v2": 46e12,
@@ -45,33 +44,36 @@ PEAK_BF16_FLOPS = {
 
 
 def device_peak_flops(device=None) -> float | None:
-    """Peak dense bf16 FLOP/s for ``device`` (default: first device), or
-    None when unknown (CPU, unlisted kind) — callers then omit the MFU
-    column rather than print a wrong one."""
+    """Peak dense bf16 FLOP/s for ``device`` (default: first device).
+    None on the CPU backend — callers then omit the MFU column.  Any
+    other device whose kind is not in the table is an error, not a
+    default: a utilization against an assumed peak is a wrong number."""
     d = device if device is not None else jax.devices()[0]
-    kind = str(getattr(d, "device_kind", "")).strip()
-    # longest prefix wins so "TPU v5p" does not fall through to "TPU v5"
+    kind = str(d.device_kind).strip()
+    # longest prefix wins so "TPU v5p" cannot fall through to a shorter key
     for k in sorted(PEAK_BF16_FLOPS, key=len, reverse=True):
         if kind.lower().startswith(k.lower()):
             return PEAK_BF16_FLOPS[k]
-    return None
+    if d.platform == "cpu":
+        return None
+    raise KeyError(
+        f"no peak bf16 FLOP/s known for device kind {kind!r} (platform "
+        f"{d.platform!r}); add it to PEAK_BF16_FLOPS with its source"
+    )
 
 
 def compiled_step_flops(fn, *args) -> float:
     """Exact executed FLOPs of one invocation of ``fn(*args)``.
 
     ``fn`` may be a jitted function or a plain callable (jitted here).
-    Returns NaN when the backend's cost analysis is unavailable."""
-    try:
-        lowered = (
-            fn.lower(*args) if hasattr(fn, "lower") else jax.jit(fn).lower(*args)
-        )
-        cost = lowered.compile().cost_analysis()
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0]
-        return float(cost["flops"])
-    except Exception:
-        return float("nan")
+    A program that does not lower or compile raises."""
+    lowered = (
+        fn.lower(*args) if hasattr(fn, "lower") else jax.jit(fn).lower(*args)
+    )
+    cost = lowered.compile().cost_analysis()
+    if isinstance(cost, (list, tuple)):
+        cost = cost[0]
+    return float(cost["flops"])
 
 
 def flash_attention_train_flops(

@@ -24,10 +24,10 @@ def capture(args, trace_dir: str) -> None:
 
     from ddl_tpu.infer.decode import make_lm_generator
     from ddl_tpu.models.transformer import LMConfig, TransformerLM
-    from ddl_tpu.utils.compile_cache import enable_compile_cache
+    from ddl_tpu.utils.compile_cache import activate_compile_cache
     from ddl_tpu.utils.timing import fence
 
-    enable_compile_cache()
+    activate_compile_cache()
     cfg = LMConfig(
         vocab_size=args.vocab,
         d_model=args.d_model,

@@ -35,10 +35,10 @@ def capture(batch: int, steps: int, trace_dir: str, impl: str = "packed"):
     from ddl_tpu.parallel.mesh import MeshSpec, build_mesh
     from ddl_tpu.train.state import create_train_state, make_optimizer
     from ddl_tpu.train.steps import make_dp_step_fns
-    from ddl_tpu.utils.compile_cache import enable_compile_cache
+    from ddl_tpu.utils.compile_cache import activate_compile_cache
     from ddl_tpu.utils.timing import fence
 
-    enable_compile_cache()
+    activate_compile_cache()
     cfg = ModelConfig(compute_dtype="bfloat16", dense_block_impl=impl)
     stages = build_stages(cfg, num_stages=1)
     tx = make_optimizer(TrainConfig())

@@ -27,10 +27,10 @@ def capture(args, trace_dir: str) -> None:
     from ddl_tpu.models.transformer import LMConfig
     from ddl_tpu.parallel.sharding import LMMeshSpec
     from ddl_tpu.train.lm_steps import make_lm_step_fns
-    from ddl_tpu.utils.compile_cache import enable_compile_cache
+    from ddl_tpu.utils.compile_cache import activate_compile_cache
     from ddl_tpu.utils.timing import fence
 
-    enable_compile_cache()
+    activate_compile_cache()
     cfg = LMConfig(
         vocab_size=50304,
         d_model=768,

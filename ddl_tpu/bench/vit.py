@@ -39,9 +39,9 @@ def main() -> None:
     ap.add_argument("--iters", type=int, default=30)
     args = ap.parse_args()
 
-    from ddl_tpu.utils.compile_cache import enable_compile_cache
+    from ddl_tpu.utils.compile_cache import activate_compile_cache
 
-    enable_compile_cache()
+    activate_compile_cache()
 
     cfg = ViTConfig(
         image_size=args.image_size,

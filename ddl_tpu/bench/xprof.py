@@ -10,11 +10,11 @@ PERF.md round 4), ``profile_lm``, and the anomaly-triggered capture path
 ``op_digest`` summary so a regression is explainable without opening
 TensorBoard.
 
-Runtime compatibility: newer JAX exposes ``jax.profiler.ProfileData``;
-the container's older runtime (see ``compat.py``) does not, so this
-module carries a minimal protobuf *wire-format* reader for the stable
-XSpace/XPlane schema — no TensorFlow/xprof import, just the handful of
-field numbers the analysis needs.  CPU traces additionally have no
+Traces are read with ``jax.profiler.ProfileData``.  A log-analysis
+host without JAX (``ddl_tpu bench digest`` over a mounted trace dir)
+gets a minimal protobuf *wire-format* reader for the stable
+XSpace/XPlane schema instead — no TensorFlow/xprof import, just the
+handful of field numbers the analysis needs.  CPU traces have no
 ``/device:`` plane at all (XLA ops land on ``/host:CPU`` thread-pool
 lines named ``tf_XLA*``), so the readers fall back to those when no
 device plane exists — the same digest, host-sided, which is exactly what
@@ -192,7 +192,7 @@ def _read_xplane_profiledata(path: str):
 def read_trace(trace_dir: str):
     """Read the newest ``*.xplane.pb`` under ``trace_dir`` into
     ``[(plane_name, [(line_name, [(event_name, dur_ms), ...]), ...])]``,
-    via ``ProfileData`` when this runtime has it, else the wire reader."""
+    via ``ProfileData``, or the wire reader on a host without JAX."""
     paths = glob.glob(
         os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True
     )

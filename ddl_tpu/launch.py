@@ -59,8 +59,8 @@ def force_cpu_devices(n: int) -> None:
     place the XLA_FLAGS + jax_platforms dance lives (used by the CLI's and
     the examples' ``--cpu-devices`` flags and mirrored by tests/conftest.py).
     Safe any time before the JAX backend initialises, even after ``import
-    jax``; ``config.update`` is preferred over the ``JAX_PLATFORMS`` env var,
-    which can hang under externally-registered platform plugins.  A no-op
+    jax`` (``config.update`` works where the ``JAX_PLATFORMS`` env var,
+    read at import, would be too late).  A no-op
     when the backend is already up on ``n``+ CPU devices (so callers can
     self-bootstrap without fighting tests/conftest.py)."""
     initialized = False
@@ -154,26 +154,30 @@ def bootstrap(
 
 
 def _arm_compile_cache() -> None:
-    """Warm restarts: arm the persistent, topology-keyed XLA compile
-    cache (``utils/compile_cache``) on the launch path — opt-in via
-    ``DDL_COMPILE_CACHE`` or pod mode (the rendezvous leader publishes
-    one shared NAS cache root for every host).  Runs AFTER distributed
-    init so the topology key sees the full world; failures degrade to a
-    cold compile, never a failed launch."""
+    """Arm the persistent XLA compile cache (``utils/compile_cache``) on
+    the launch path: ``JAX_COMPILATION_CACHE_DIR`` as placed, else the
+    checkout's fixed directory, or in pod mode the one NAS root the
+    rendezvous leader publishes for every host.  Runs AFTER distributed
+    init so the topology key sees the full world.  A cache that cannot
+    be armed (read-only checkout, coord failure) costs a cold compile,
+    never the launch: said once here, and ``cache_stats()`` stays None
+    for whoever reports the run."""
     from ddl_tpu import coord
     from ddl_tpu.utils.compile_cache import activate_compile_cache
 
     try:
         stats = activate_compile_cache(rv=coord.from_env())
     except Exception as e:  # ddl-lint: disable=broad-except
-        print(f"[ddl_tpu] compile cache unavailable ({e})")
+        print(f"[ddl_tpu] compile cache unavailable, compiling cold ({e})")
         return
-    if stats is not None:
-        state = "warm" if stats["warm"] else "cold"
-        print(
-            f"[ddl_tpu] compile cache {state}: {stats['dir']} "
-            f"({stats['entries_before']} entries)"
-        )
+    if stats is None:
+        print("[ddl_tpu] compile cache off (DDL_COMPILE_CACHE)")
+        return
+    state = "warm" if stats["warm"] else "cold"
+    print(
+        f"[ddl_tpu] compile cache {state}: {stats['dir']} "
+        f"({stats['entries_before']} entries)"
+    )
 
 
 def world_info() -> dict:

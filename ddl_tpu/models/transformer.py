@@ -733,15 +733,10 @@ _combine_gather.defvjp(_combine_gather_fwd, _combine_gather_bwd)
 
 def _ambient_mesh_shape() -> dict:
     """Axis-name -> size of the ambient (abstract) mesh; {} when tracing
-    without a mesh context.  Shared by the decode-kernel and MoE-dispatch
-    resolution below."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except Exception:
-        return {}
-    if mesh is None or getattr(mesh, "empty", False):
-        return {}
-    return dict(mesh.shape)
+    without a mesh context (jax answers with an empty mesh, it does not
+    raise).  Shared by the decode-kernel and MoE-dispatch resolution
+    below."""
+    return dict(jax.sharding.get_abstract_mesh().shape)
 
 
 def _ambient_mesh_size() -> int:

@@ -1,9 +1,9 @@
 """ctypes bindings for the native loader core (``loader.cpp``).
 
-Auto-builds ``libddl_loader.so`` with the repo's Makefile on first import if
-a toolchain is present; every caller must handle ``loader_lib() is None``
-and fall back to the pure-Python path (PIL), so the framework works with no
-compiler at all.
+``libddl_loader.so`` is not committed: it is built from ``loader.cpp``
+with the Makefile beside it on first use, if a toolchain is present.
+Every caller must handle ``loader_lib() is None`` and fall back to the
+pure-Python path (PIL), so the framework works with no compiler at all.
 """
 
 from __future__ import annotations

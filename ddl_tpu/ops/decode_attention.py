@@ -29,8 +29,8 @@ or linear positions), so rolling and full-cache decode share the kernel.
 
 Used automatically by ``models/transformer.Attention`` for single-device
 T=1 decode over the full cache (multi-device decode keeps the einsum
-path — GSPMD cannot partition a custom call); interpreter mode off-TPU,
-so CPU tests exercise the identical program.
+path — GSPMD cannot partition a custom call); interpreter mode on the
+CPU backend, so CPU tests exercise the identical program.
 """
 
 from __future__ import annotations
@@ -41,6 +41,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ddl_tpu.ops.interpret import interpret_default
 
 __all__ = ["decode_attention", "pick_block_l", "quant_decode_attention"]
 
@@ -78,7 +80,7 @@ def _kernel(
         m_sc[:] = jnp.full_like(m_sc, -1e30)
         l_sc[:] = jnp.zeros_like(l_sc)
 
-    bias = bias_ref[0].astype(jnp.float32)  # (block_l,)
+    bias = bias_ref[0, 0].astype(jnp.float32)  # (block_l,)
     for i in range(hkv):
         rows = slice(i * g, (i + 1) * g)
         qh = q_ref[0, rows, :].astype(jnp.float32)  # (G, D)
@@ -116,7 +118,7 @@ def _quant_kernel(
         m_sc[:] = jnp.full_like(m_sc, -1e30)
         l_sc[:] = jnp.zeros_like(l_sc)
 
-    bias = bias_ref[0].astype(jnp.float32)
+    bias = bias_ref[0, 0].astype(jnp.float32)
     for i in range(hkv):
         rows = slice(i * g, (i + 1) * g)
         qh = q_ref[0, rows, :].astype(jnp.float32)
@@ -144,26 +146,25 @@ def _quant_kernel(
     _finalize(o_ref, acc_sc, l_sc, j, nl)
 
 
-def _interpret_default() -> bool:
-    return jax.devices()[0].platform != "tpu"
-
-
 def _bias_spec(bias, b: int, bl: int) -> pl.BlockSpec:
-    """BlockSpec for the additive mask: one shared (1, L) row broadcast
-    to every batch program, or a (B, L) per-lane bias tiled along the
-    batch grid dimension (the serving engine's continuous decode batch,
-    where each lane's visible length differs)."""
+    """BlockSpec for the additive mask, passed as (rows, 1, L): one
+    shared row broadcast to every batch program, or B per-lane rows
+    tiled along the batch grid dimension (the serving engine's
+    continuous decode batch, where each lane's visible length differs).
+    The unit middle axis is what lets one row be a block: Mosaic wants a
+    block's second-to-last dim 8-divisible or equal to the array's, and
+    a (1, bl) block of a (B, L) bias is neither."""
     # bounded two-program dispatch (shared vs per-lane bias), both
     # variants precompiled by the serve engine's program grid — not an
     # unbounded per-shape specialization
     if bias.shape[0] == 1:  # ddl-lint: disable=recompile-shape-branch
-        return pl.BlockSpec((1, bl), lambda i, j: (0, j))
+        return pl.BlockSpec((1, 1, bl), lambda i, j: (0, 0, j))
     if bias.shape[0] != b:
         raise ValueError(
             f"bias batch dim {bias.shape[0]} must be 1 (shared) or match "
             f"the query batch {b} (per-lane)"
         )
-    return pl.BlockSpec((1, bl), lambda i, j: (i, j))
+    return pl.BlockSpec((1, 1, bl), lambda i, j: (i, 0, j))
 
 
 def pick_block_l(L: int, fused: int) -> int | None:
@@ -247,7 +248,7 @@ def decode_attention(q, ck, cv, bias, *, hkv: int, block_l=None,
     b, _, h, d = q.shape
     L = ck.shape[1]
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     bl = _block_l(L, block_l, hkv * d, ck.dtype.itemsize, interpret)
     out = pl.pallas_call(
         functools.partial(_kernel, hkv=hkv, scale=1.0 / (d ** 0.5)),
@@ -269,7 +270,7 @@ def decode_attention(q, ck, cv, bias, *, hkv: int, block_l=None,
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(q[:, 0], ck, cv, bias)
+    )(q[:, 0], ck, cv, bias[:, None])
     return out[:, None]
 
 
@@ -286,7 +287,7 @@ def quant_decode_attention(q, ck, ks, cv, vs, bias, *, hkv: int,
     b, _, h, d = q.shape
     L = ck.shape[1]
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_default()
     bl = _block_l(L, block_l, hkv * d, ck.dtype.itemsize, interpret)
     out = pl.pallas_call(
         functools.partial(_quant_kernel, hkv=hkv, scale=1.0 / (d ** 0.5)),
@@ -310,5 +311,5 @@ def quant_decode_attention(q, ck, ks, cv, vs, bias, *, hkv: int,
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(q[:, 0], ck, cv, ks, vs, bias)
+    )(q[:, 0], ck, cv, ks, vs, bias[:, None])
     return out[:, None]

@@ -63,6 +63,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ddl_tpu.ops.interpret import interpret_default
+
 __all__ = [
     "block_pad",
     "fused_dense_block",
@@ -73,6 +75,10 @@ __all__ = [
 
 _BN_EPS = 1e-5
 _LANE = 128
+# The backward keeps a block's whole strip resident in f32 next to the
+# recomputed intermediates: DenseNet121 block 1 at 56x56 asks for 42 MB
+# of scoped VMEM, past Mosaic's 16 MB default (a v5e core has 128 MiB).
+_BWD_VMEM_LIMIT = 96 * 1024 * 1024
 
 
 def pack_affines(layer_params, norm1_stats, norm2_stats, c0: int,
@@ -329,34 +335,32 @@ def _bwd_kernel(
     dstrip = strip_sc[:].astype(jnp.float32)  # (S, growth)
 
     # 3x3 transpose: nine shifted matmuls over zero halos
-    dsp = jnp.pad(
-        dstrip.astype(dtype).reshape(h, w, growth),
-        ((1, 1), (1, 1), (0, 0)),
-    )
+    # the halo and its windows stay f32 until after the reshape: Mosaic
+    # has no (7, 7, 32) <-> (49, 32) shape cast for a packed bf16 strip
+    dsp = jnp.pad(dstrip.reshape(h, w, growth), ((1, 1), (1, 1), (0, 0)))
     h2p = jnp.pad(h2.reshape(h, w, bn), ((1, 1), (1, 1), (0, 0)))
     dh2 = jnp.zeros((s, bn), jnp.float32)
-    dw2_acc = jnp.zeros((9, bn, growth), jnp.float32)
     for dy in range(3):
         for dx in range(3):
             # dL/dh2 gathers each tap's dstrip against the transposed tap
             win_g = dsp[dy:dy + h, dx:dx + w].reshape(s, growth)
             dh2 = dh2 + jax.lax.dot_general(
-                win_g,
+                win_g.astype(dtype),
                 w2_ref[0, (2 - dy) * 3 + (2 - dx)].astype(dtype),
                 (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-            # dW2[tap] = shifted(h2)^T @ dstrip
+            # dW2[tap] += shifted(h2)^T @ dstrip — accumulated straight
+            # into the resident output block, one tap per store (an
+            # .at[tap].set on a value is a scatter, which Mosaic has no
+            # lowering for)
             win_h = h2p[dy:dy + h, dx:dx + w].reshape(s, bn)
-            dw2_acc = dw2_acc.at[dy * 3 + dx].set(
-                jax.lax.dot_general(
-                    win_h, dstrip.astype(dtype),
-                    (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-            )
-    cur2 = dw2_ref[pl.dslice(li, 1)]
-    dw2_ref[pl.dslice(li, 1)] = cur2 + dw2_acc[None]
+            tap = (pl.dslice(li, 1), pl.dslice(dy * 3 + dx, 1))
+            dw2_ref[tap] = dw2_ref[tap] + jax.lax.dot_general(
+                win_h, dstrip.astype(dtype),
+                (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )[None, None]
 
     dz2 = jnp.where(z2 > 0.0, dh2, 0.0)  # (S, bn)
     da2_ref[pl.dslice(li, 1)] = da2_ref[pl.dslice(li, 1)] + jnp.sum(
@@ -452,6 +456,7 @@ def _backward_call(out, g, a1, b1, w1, a2, b2, w2, *, c0, growth,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_BWD_VMEM_LIMIT,
         ),
         interpret=interpret,
     )(out, g, a1, b1, w1, a2, b2, w2)
@@ -510,7 +515,7 @@ def fused_dense_block(x0, packed, *, c0: int, growth: int,
     if _LANE % growth:
         raise ValueError(f"growth {growth} must divide the lane width")
     if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+        interpret = interpret_default()
     x0p = jnp.pad(x0, ((0, 0), (0, 0), (0, 0), (pad0, 0)))
     f = _diff_block_fn(c0, growth, bool(interpret))
     return f(

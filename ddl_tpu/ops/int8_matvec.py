@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ddl_tpu.ops.interpret import interpret_default
+
 __all__ = ["MATVEC_MAX_ROWS", "int8_matmul_small_m"]
 
 MATVEC_MAX_ROWS = 8
@@ -76,7 +78,7 @@ def int8_matmul_small_m(x, w8, scale, *, contract_last: bool = False,
             [(0, 0), (0, o_pad - o)]
         w8 = jnp.pad(w8, pad)
     if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+        interpret = interpret_default()
     xp = jnp.zeros((MATVEC_MAX_ROWS, d), x.dtype).at[:m].set(x)
     s_row = jnp.pad(
         jnp.broadcast_to(scale.reshape(1, o), (1, o)),

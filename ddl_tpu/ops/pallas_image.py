@@ -18,18 +18,28 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ddl_tpu.ops.interpret import interpret_default
+
 __all__ = ["pallas_normalize_images"]
 
 _BLOCK_COLS = 1536  # 12 lanes of 128
 
 
 def _normalize_kernel(in_ref, out_ref):
+    # Mosaic has no direct uint8 -> bfloat16 cast: widen through int32
+    # and float32 (exact for 0..255), then scale in the output dtype as
+    # ops/image.normalize_images does
     inv = jnp.asarray(1.0 / 255.0, out_ref.dtype)
-    out_ref[:] = in_ref[:].astype(out_ref.dtype) * inv
+    x = in_ref[:].astype(jnp.int32).astype(jnp.float32)
+    out_ref[:] = x.astype(out_ref.dtype) * inv
 
 
-def pallas_normalize_images(images, dtype=jnp.bfloat16, interpret: bool = False):
+def pallas_normalize_images(
+    images, dtype=jnp.bfloat16, interpret: bool | None = None
+):
     """uint8 (B, H, W, C) -> [0,1] float (B, H, W, C) in ``dtype``."""
+    if interpret is None:
+        interpret = interpret_default()
     b = images.shape[0]
     flat = images.reshape(b, -1)
     f = flat.shape[1]

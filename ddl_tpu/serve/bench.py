@@ -181,6 +181,10 @@ def main(argv=None) -> None:
     import jax
     import numpy as np
 
+    from ddl_tpu.utils.compile_cache import activate_compile_cache
+
+    activate_compile_cache()
+
     from ddl_tpu.models.transformer import LMConfig, TransformerLM
     from ddl_tpu.obs.serving import ServingStats, render_percentiles
     from ddl_tpu.parallel.sharding import LMMeshSpec

@@ -164,6 +164,24 @@ def _constrain_pool(pool, on: bool):
     return tuple(c(a, ("act_seq", None, "act_heads")) for a in pool)
 
 
+def decode_attention_path(mesh_size: int) -> str:
+    """Which cached-attention path a decode program over a mesh of
+    ``mesh_size`` devices takes: ``"kernel"`` (the one-pass Pallas
+    kernel, ``ops/decode_attention.py``) or ``"einsum"``.  The kernel
+    only where it is a real kernel: on the CPU backend it would run
+    interpreted (orders of magnitude slower than the einsum), and the
+    CPU einsum path is also what keeps serve tokens bit-identical to the
+    sequential einsum reference (the pool's power-of-two width is
+    alignment-legal, so unlike the contiguous path ``pick_block_l``
+    would NOT bail us out here).  GSPMD cannot partition a custom call,
+    so any mesh larger than one device keeps the einsum too — a known
+    limit (ROADMAP S6), reported in ``ServeEngine.stats`` rather than
+    taken in silence."""
+    if mesh_size == 1 and jax.default_backend() == "tpu":
+        return "kernel"
+    return "einsum"
+
+
 class ServeAttention(nn.Module):
     """One cached-attention step over the paged pool for every lane.
 
@@ -239,13 +257,7 @@ class ServeAttention(nn.Module):
             mask &= key_pos[None, None, :] > (
                 lengths[:, None, None] - cfg.attn_window
             )
-        # one-pass Pallas kernel only where it's a real kernel: off-TPU
-        # it would run in interpret mode (orders of magnitude slower than
-        # the einsum), and the CPU einsum path is also what keeps serve
-        # tokens bit-identical to the sequential einsum reference (the
-        # pool's power-of-two width is alignment-legal, so unlike the
-        # contiguous path pick_block_l would NOT bail us out here)
-        use_kernel = not sharded and jax.default_backend() == "tpu"
+        use_kernel = decode_attention_path(_ambient_mesh_size()) == "kernel"
         o = kv_attend(q, cache, mask, use_kernel=use_kernel)
         if sharded:
             o = nn.with_logical_constraint(o, spec)
@@ -624,17 +636,15 @@ def make_serve_step_fns(
     )
 
 
-def _jit_compiles(prog) -> int | None:
+def _jit_compiles(prog) -> int:
     """How many executables this jitted program has compiled — the
-    ground truth for cold-marking (a program compiles once per operand-
-    commitment signature, not once per shape: the same program compiles
-    AGAIN when its pools go from fresh to committed); None when the
-    runtime doesn't expose the jit cache (callers fall back to the
-    first-build heuristic)."""
-    try:
-        return prog._cache_size()
-    except AttributeError:  # pragma: no cover - jit internals moved
-        return None
+    ground truth for cold-marking (a program compiles once per operand
+    signature, not once per shape: the same program compiles AGAIN when
+    its pools go from fresh to committed, or when an operand was made
+    another way — see ``ServeEngine._args``).  Leans on the private
+    ``PjitFunction._cache_size`` of the one supported installation;
+    ``tests/test_serve.py`` pins that it exists."""
+    return prog._cache_size()
 
 
 class ServeEngine:
@@ -728,7 +738,13 @@ class ServeEngine:
                 )
         self.prefill_chunk = prefill_chunk
         self.scenario = scenario
-        self.pools = self.fns.init_pools()
+        # made under the mesh like every program OUTPUT that replaces
+        # them: an array created outside ``set_mesh`` has another type
+        # (no mesh in its aval) than one a program returned under it, and
+        # jit would compile each program a second time for the change
+        with jax.set_mesh(self.fns.mesh):
+            self.pools = self.fns.init_pools()
+            self._rngs = jnp.zeros((max_batch, 2), jnp.uint32)
         self.allocator = BlockAllocator(num_blocks, block_size)
         # HBM ledger (obs/hbm.py): per-shard byte sizes, computed once
         # on first pool-stats emission.  The pool's logical footprint
@@ -762,7 +778,6 @@ class ServeEngine:
         # caller's to drain via pop_result() — a server that never pops
         # grows by one token array per request forever
         self.request_log: deque = deque(maxlen=65536)
-        self._rngs = jnp.zeros((max_batch, 2), jnp.uint32)
         self._req_counter = 0
         self._cow_prog = None  # lazily-jitted pool_copy_block
         self.stats = {
@@ -771,6 +786,7 @@ class ServeEngine:
             "decode_steps": 0, "decode_dispatches": 0, "peak_blocks": 0,
             "prefix_hits": 0, "prefix_hit_tokens": 0, "prefix_inserts": 0,
             "prefill_tokens": 0, "prefill_chunks": 0, "cow_copies": 0,
+            "decode_attention": decode_attention_path(self.fns.mesh.size),
         }
         self._compiled_buckets: set[int] = set()
         # preempt-drain: ``guard`` is a utils/preemption.PreemptionGuard
@@ -1058,6 +1074,21 @@ class ServeEngine:
             )
             self._emit_pool_stats()
 
+    def _args(self, *operands) -> tuple:
+        """The full argument tuple of a serving program: params, pools,
+        then the per-call operands.  Host operands arrive as numpy
+        arrays/scalars and are converted HERE, for the live loop and for
+        ``precompile()``'s dummies alike: jit keys its cache on how an
+        operand was made, not only on its shape (an array from a
+        ``jnp`` creation op under ``set_mesh`` carries the mesh in its
+        type, a converted numpy array does not), so a dummy made another
+        way leaves one compile inside the first live request."""
+        return (self.params, self.pools, *map(jnp.asarray, operands))
+
+    def _dispatch(self, prog, *operands):
+        with jax.set_mesh(self.fns.mesh):
+            return prog(*self._args(*operands))
+
     def _prefill_rng(self, req: Request):
         """The rng a prefill program seeds its lane with.  An ordinary
         request derives it from ``rng_seed``; a resumed one restores the
@@ -1076,7 +1107,6 @@ class ServeEngine:
         req = state.request
         fns = self.fns
         bucket = prompt_bucket(req.prompt_len, fns.block_size)
-        first_use = bucket not in self._compiled_buckets
         prog = fns.prefill_for(bucket)
         prompt = np.zeros((1, bucket), np.int32)
         prompt[0, : req.prompt_len] = req.prompt
@@ -1085,27 +1115,19 @@ class ServeEngine:
         ids[:n] = state.block_ids[:n]
         rng = self._prefill_rng(req)
         before = _jit_compiles(prog)
-        with jax.set_mesh(fns.mesh):
-            tok0, rng, self.pools = prog(
-                self.params, self.pools, jnp.asarray(prompt),
-                jnp.asarray(ids), jnp.int32(req.prompt_len), rng,
-            )
+        operands = (prompt, ids, np.int32(req.prompt_len), rng)
+        tok0, rng, self.pools = self._dispatch(prog, *operands)
         tok0 = int(tok0)  # fences the first token: a REAL TTFT
         # compile detection by executable count, not first-build: the
         # same program compiles AGAIN on its second call when the pools
         # go from fresh to committed (precompile's two-pass rationale) —
         # that hidden compile must cold-mark and count too
-        compiled = (
-            _jit_compiles(prog) != before if before is not None
-            else first_use
-        )
+        compiled = _jit_compiles(prog) != before
         self._compiled_buckets.add(bucket)
         if compiled:
             self.stats["prefill_compiles"] += 1
             self._emit_hbm_plan(
-                f"serve_prefill_b{bucket}", prog,
-                (self.params, self.pools, jnp.asarray(prompt),
-                 jnp.asarray(ids), jnp.int32(req.prompt_len), rng),
+                f"serve_prefill_b{bucket}", prog, self._args(*operands)
             )
         self.stats["prefill_tokens"] += req.prompt_len
         self._emit_trace_span(
@@ -1259,15 +1281,13 @@ class ServeEngine:
         n = min(nmax, len(state.block_ids))
         table[:n] = state.block_ids[:n]
         t0 = perf_counter()
-        prog, built = fns.chunk_for(cb, nmax, mode)
+        prog, _ = fns.chunk_for(cb, nmax, mode)
         before = _jit_compiles(prog)
-        rng = self._prefill_rng(req)
-        with jax.set_mesh(fns.mesh):
-            out = prog(
-                self.params, self.pools, jnp.asarray(tokens),
-                jnp.asarray(table), jnp.int32(off),
-                jnp.int32(c - 1), rng,
-            )
+        operands = (
+            tokens, table, np.int32(off), np.int32(c - 1),
+            self._prefill_rng(req),
+        )
+        out = self._dispatch(prog, *operands)
         if final:
             tok0, rng, self.pools = out
             tok0 = int(tok0)  # fences the first token: a REAL TTFT
@@ -1277,17 +1297,13 @@ class ServeEngine:
                 self.pools[0].kq
                 if isinstance(self.pools[0], QuantKV) else self.pools[0][0]
             )
-        compiled = (
-            _jit_compiles(prog) != before if before is not None else built
-        )
+        compiled = _jit_compiles(prog) != before
         if compiled:
             self.stats["prefill_compiles"] += 1
             state.cold = True
             self._emit_hbm_plan(
                 f"serve_chunk_c{cb}_n{nmax}_{mode}", prog,
-                (self.params, self.pools, jnp.asarray(tokens),
-                 jnp.asarray(table), jnp.int32(off), jnp.int32(c - 1),
-                 rng),
+                self._args(*operands),
             )
         self.stats["prefill_tokens"] += c
         self.stats["prefill_chunks"] += 1
@@ -1345,25 +1361,21 @@ class ServeEngine:
             pending[s.lane] = s.pending_tok
         seq = self.stats["decode_dispatches"]  # this dispatch's number
         t0 = perf_counter()
-        prog, built = fns.decode_for(k, nmax)
+        prog, _ = fns.decode_for(k, nmax)
         before = _jit_compiles(prog)
-        with jax.set_mesh(fns.mesh):
-            toks, self._rngs, self.pools = prog(
-                self.params, self.pools, jnp.asarray(tables),
-                jnp.asarray(lengths), jnp.asarray(pending), self._rngs,
-            )
-        # executable-count detection (see _admit_one): the second call
-        # of a program recompiles for the committed-pools signature —
-        # first-build `built` alone would warm-mark that dispatch
-        if (_jit_compiles(prog) != before if before is not None
-                else built):
+        toks, self._rngs, self.pools = self._dispatch(
+            prog, tables, lengths, pending, self._rngs
+        )
+        # executable-count detection (see _full_prefill): the second
+        # call of a program recompiles for the committed-pools signature
+        # — a first-build flag alone would warm-mark that dispatch
+        if _jit_compiles(prog) != before:
             self.stats["decode_compiles"] += 1
             for s in active:
                 s.cold = True
             self._emit_hbm_plan(
                 f"serve_decode_k{k}_n{nmax}", prog,
-                (self.params, self.pools, jnp.asarray(tables),
-                 jnp.asarray(lengths), jnp.asarray(pending), self._rngs),
+                self._args(tables, lengths, pending, self._rngs),
             )
         self.stats["decode_steps"] += k
         self.stats["decode_dispatches"] += 1
@@ -1596,7 +1608,10 @@ class ServeEngine:
         so the first call compiles the fresh-input signature and the
         second the steady-state one where pools/rngs are prior program
         outputs — the signature every loop iteration after the first
-        actually hits.  Every dummy block id is out of range, so pool
+        actually hits.  The dummies go through the same ``_dispatch`` as
+        live requests (numpy operands, a PRNGKey made outside the mesh
+        context), so their signature IS the live one.  Every dummy block
+        id is out of range, so pool
         writes drop and the pool CONTENT is untouched (the committed
         arrays are kept, matching the steady-state signature).
         Returns ``{"prefill": n, "decode": m, "chunk": c}``
@@ -1639,44 +1654,43 @@ class ServeEngine:
             for i in range(pow2_at_most(self.max_steps_per_dispatch)
                            .bit_length())
         ]
-        zeros = jnp.zeros((fns.max_batch,), jnp.int32)
-        # ONE rng state threaded across the whole grid: committed after
-        # the first program's feedback pass, so every later program's
-        # first call already carries the steady-state signature
-        rngs = jnp.zeros((fns.max_batch, 2), jnp.uint32)
+        zeros = np.zeros((fns.max_batch,), np.int32)
+        # like every live admission's key: made outside the mesh context
+        key0 = jax.random.PRNGKey(0)
+        # ONE rng state threaded across the whole grid, starting from
+        # the live one (never written back): committed after the first
+        # program's feedback pass, so every later program's first call
+        # already carries the steady-state signature
+        rngs = self._rngs
         for nmax in nmaxes:
-            t = jnp.full((fns.max_batch, nmax), fns.num_blocks, jnp.int32)
+            t = np.full((fns.max_batch, nmax), fns.num_blocks, np.int32)
             for k in ks:
                 prog, built = fns.decode_for(k, nmax)
                 if not built:
                     continue
                 for _ in range(2):
-                    with jax.set_mesh(fns.mesh):
-                        out = prog(
-                            self.params, self.pools, t, zeros, zeros, rngs,
-                        )
+                    out = self._dispatch(prog, t, zeros, zeros, rngs)
                     jax.block_until_ready(out[0])
                     rngs, self.pools = out[1], out[2]
                 compiled["decode"] += 1
                 self._emit_hbm_plan(
                     f"serve_decode_k{k}_n{nmax}", prog,
-                    (self.params, self.pools, t, zeros, zeros, rngs),
+                    self._args(t, zeros, zeros, rngs),
                 )
         for bucket in buckets:
             if bucket in self._compiled_buckets:
                 continue
             prog = fns.prefill_for(bucket)
-            ids = np.full(
-                (bucket // fns.block_size,), fns.num_blocks, np.int32
+            operands = (
+                np.zeros((1, bucket), np.int32),
+                np.full(
+                    (bucket // fns.block_size,), fns.num_blocks, np.int32
+                ),
+                np.int32(1),
+                key0,
             )
             for _ in range(2):
-                with jax.set_mesh(fns.mesh):
-                    out = prog(
-                        self.params, self.pools,
-                        jnp.zeros((1, bucket), jnp.int32),
-                        jnp.asarray(ids), jnp.int32(1),
-                        jax.random.PRNGKey(0),
-                    )
+                out = self._dispatch(prog, *operands)
                 jax.block_until_ready(out[0])
                 self.pools = out[2]
             # mimic the admit path's eager ops (int() fence, per-lane
@@ -1688,9 +1702,7 @@ class ServeEngine:
             self._compiled_buckets.add(bucket)
             compiled["prefill"] += 1
             self._emit_hbm_plan(
-                f"serve_prefill_b{bucket}", prog,
-                (self.params, self.pools, jnp.zeros((1, bucket), jnp.int32),
-                 jnp.asarray(ids), jnp.int32(1), jax.random.PRNGKey(0)),
+                f"serve_prefill_b{bucket}", prog, self._args(*operands)
             )
         # chunk-prefill programs: reachable when prompts can continue a
         # cached prefix (prefix cache on) or exceed the chunk bound.
@@ -1712,7 +1724,7 @@ class ServeEngine:
             )
             cbs = [b for b in buckets if b <= cap] or [fns.block_size]
             for nmax in vmaxes:
-                t = jnp.full((nmax,), fns.num_blocks, jnp.int32)
+                t = np.full((nmax,), fns.num_blocks, np.int32)
                 for mode in modes:
                     for cb in cbs:
                         if cb > nmax * fns.block_size:
@@ -1723,19 +1735,17 @@ class ServeEngine:
                         prog, built = fns.chunk_for(cb, nmax, mode)
                         if not built:
                             continue
+                        # the same fresh key on both passes, like the
+                        # real chunk dispatches (threading the rng
+                        # output back in would precompile a
+                        # committed-rng signature the runtime never
+                        # presents)
+                        operands = (
+                            np.zeros((1, cb), np.int32), t,
+                            np.int32(0), np.int32(0), key0,
+                        )
                         for _ in range(2):
-                            with jax.set_mesh(fns.mesh):
-                                # a FRESH PRNGKey per call, like the real
-                                # chunk dispatches (threading the rng
-                                # output back in would precompile a
-                                # committed-rng signature the runtime
-                                # never presents)
-                                out = prog(
-                                    self.params, self.pools,
-                                    jnp.zeros((1, cb), jnp.int32), t,
-                                    jnp.int32(0), jnp.int32(0),
-                                    jax.random.PRNGKey(0),
-                                )
+                            out = self._dispatch(prog, *operands)
                             if mode == "mid":
                                 self.pools = out
                                 jax.block_until_ready(
@@ -1749,10 +1759,7 @@ class ServeEngine:
                         compiled["chunk"] += 1
                         self._emit_hbm_plan(
                             f"serve_chunk_c{cb}_n{nmax}_{mode}", prog,
-                            (self.params, self.pools,
-                             jnp.zeros((1, cb), jnp.int32), t,
-                             jnp.int32(0), jnp.int32(0),
-                             jax.random.PRNGKey(0)),
+                            self._args(*operands),
                         )
             if self.prefix is not None and self._cow_prog is None:
                 # the CoW copy program: src == dst is a content no-op

@@ -1,11 +1,24 @@
-"""Persistent XLA compile cache: warm restarts for supervised pods.
+"""Persistent XLA compile cache: one directory per checkout, warm
+restarts for supervised pods.
 
-Grown from the bench-only stub into a launch-path subsystem (ROADMAP
-"elastic pod scale-down, warm restarts, and a persistent compile
-cache").  A relaunched incarnation pays the full XLA compile again —
-the goodput ledger prices it as the ``recompile`` bucket and the
-``restart_latency`` obs event times it — unless the persistent cache
-survives the process.  Three pieces make that safe and observable:
+A relaunched incarnation — or the next run of the same entry point —
+pays the full XLA compile again (the goodput ledger prices it as the
+``recompile`` bucket, the ``restart_latency`` obs event times it) unless
+the persistent cache survives the process.  Where it lives:
+
+* **Placed from outside**: with ``JAX_COMPILATION_CACHE_DIR`` set, JAX
+  itself points at that directory and this module changes nothing about
+  it — no ``jax.config.update`` of the directory, no sub-directory, no
+  ``DDL_COMPILE_CACHE`` or pod agreement on top, no eviction.  It only
+  counts the entries and the hits and misses there.
+* **Otherwise** every entry point (trainer CLI, LM trainer, serve,
+  ``bench.py``, ``chip_smoke.py``) shares one fixed directory inside the
+  checkout, :func:`default_cache_root` (``<repo>/.jax_cache``): the path
+  is part of JAX's cache key, so a directory that moves (``/tmp``, a
+  pid, a time) never hits.  ``DDL_COMPILE_CACHE=<dir>`` moves that root;
+  pod mode agrees one on the NAS.
+
+Under a root this module chose, three pieces keep it safe and observable:
 
 * **Topology keying** (:func:`topology_key`): executables are only
   reusable on the mesh they were built for, so the cache root is
@@ -34,10 +47,8 @@ survives the process.  Three pieces make that safe and observable:
   are never evicted, so the bound cannot cost this incarnation its
   warm restart.  Eviction counts ride the same ``compile_cache`` event.
 
-Activation is opt-in: ``DDL_COMPILE_CACHE=<dir>`` (any run) or pod mode
-(where the rendezvous supplies the agreed default).  ``DDL_COMPILE_CACHE=off``
-disables even in pod mode.  Bench entry points keep their historical
-:func:`enable_compile_cache` always-on behavior.
+``DDL_COMPILE_CACHE=off`` leaves the cache as JAX finds it: nothing is
+activated, counted or evicted.
 """
 
 from __future__ import annotations
@@ -52,13 +63,16 @@ __all__ = [
     "activate_compile_cache",
     "cache_entries",
     "cache_stats",
+    "default_cache_root",
     "emit_cache_event",
-    "enable_compile_cache",
     "evict_to_byte_bound",
     "topology_key",
 ]
 
 ENV_CACHE = "DDL_COMPILE_CACHE"
+# JAX's own variable.  Set, it places the cache from outside the program
+# and nothing here overrides it (module docstring).
+ENV_JAX_CACHE = "JAX_COMPILATION_CACHE_DIR"
 # Minimum compile seconds before XLA persists an executable (JAX's
 # jax_persistent_cache_min_compile_time_secs).  1s skips trivial CPU
 # kernels in production; tests/sims set 0 so every compile is cached.
@@ -79,6 +93,12 @@ ENV_CACHE_MAX_BYTES = "DDL_COMPILE_CACHE_MAX_BYTES"
 _active: dict | None = None
 _counters = {"hits": 0, "misses": 0, "evicted": 0, "evicted_bytes": 0}
 _listener_installed = False
+
+
+def default_cache_root() -> Path:
+    """``<checkout>/.jax_cache``, resolved from this file: the same path
+    for every entry point, process and run of one checkout."""
+    return Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
 def topology_key() -> str:
@@ -106,42 +126,21 @@ def cache_entries(cache_dir: str | os.PathLike) -> int:
 
 def _install_counters() -> None:
     """Count persistent-cache hits/misses via ``jax.monitoring`` —
-    the same listener surface steptrace's compile timer uses.  Best
-    effort: older JAX exposes different event names; the entry counts
-    in the activation stats are the load-bearing warm/cold signal."""
+    the same listener surface steptrace's compile timer uses."""
     global _listener_installed
     if _listener_installed:
         return
     _listener_installed = True
-    try:
-        from jax import monitoring
+    from jax import monitoring
 
-        def _on_event(event: str, **kw) -> None:
-            if "compilation_cache" in event:
-                if "hit" in event:
-                    _counters["hits"] += 1
-                elif "miss" in event:
-                    _counters["misses"] += 1
+    def _on_event(event: str, **kw) -> None:
+        if "compilation_cache" in event:
+            if "hit" in event:
+                _counters["hits"] += 1
+            elif "miss" in event:
+                _counters["misses"] += 1
 
-        monitoring.register_event_listener(_on_event)
-    except Exception:  # ddl-lint: disable=broad-except — telemetry only
-        pass
-
-
-def _point_jax_at(cache_dir: Path, min_compile_s: float) -> bool:
-    import jax
-
-    try:
-        jax.config.update("jax_compilation_cache_dir", str(cache_dir))
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs",
-            float(min_compile_s),
-        )
-        return True
-    except Exception:  # ddl-lint: disable=broad-except
-        # a backend/jax version without persistent-cache support: warm
-        # restarts degrade to cold ones, never to a failed launch
-        return False
+    monitoring.register_event_listener(_on_event)
 
 
 def _cache_max_bytes() -> int:
@@ -231,41 +230,29 @@ def activate_compile_cache(
     cache_root: str | os.PathLike | None = None,
     events=None,
 ) -> dict | None:
-    """Arm the persistent compile cache for this process's launch path.
+    """Arm the persistent compile cache for this process.
 
-    Root precedence: explicit ``cache_root`` arg > ``DDL_COMPILE_CACHE``
-    env > the pod-agreed default (``<pod>/compile_cache``, published by
-    the rendezvous leader so every host uses the same NAS directory).
-    Without any of those (bare local run) the cache stays off —
-    activation is opt-in.  ``DDL_COMPILE_CACHE=off|0`` force-disables.
+    ``JAX_COMPILATION_CACHE_DIR`` set: that directory, exactly, as JAX
+    already has it — this call only reads it (module docstring).
+    Otherwise the root is, in order: the ``cache_root`` arg, the
+    ``DDL_COMPILE_CACHE`` env, the pod-agreed default
+    (``<pod>/compile_cache``, published by the rendezvous leader so
+    every host uses the same NAS directory), :func:`default_cache_root`
+    — and JAX is pointed at its ``<root>/<topology key>`` sub-directory.
+    ``DDL_COMPILE_CACHE=off|0`` force-disables.
 
     Returns the activation stats (also kept for :func:`cache_stats`):
-    ``{"dir", "key", "entries_before", "warm", "agreed"}`` — ``warm``
-    is True when the keyed subdir already holds entries, i.e. this
-    incarnation's compiles should be hits.  Emits one ``compile_cache``
-    event when ``events`` is given.
+    ``{"dir", "key", "entries_before", "warm", "agreed", "placed"}`` —
+    ``warm`` is True when the directory already holds entries, i.e. this
+    incarnation's compiles should be hits; ``placed`` when the directory
+    came from ``JAX_COMPILATION_CACHE_DIR``.  Emits one
+    ``compile_cache`` event when ``events`` is given.
     """
     global _active
+    import jax
+
     env_root = os.environ.get(ENV_CACHE)
     if env_root is not None and env_root.strip().lower() in ("", "0", "off"):
-        return None
-    root = cache_root or env_root
-    agreed = False
-    if rv is not None:
-        # one pod, one cache dir: the leader publishes (its env wins so
-        # an operator override propagates), everyone else adopts.  The
-        # default sits beside the launches/ subdirs, so it survives
-        # relaunches AND later launches of the same pod directory.
-        default = str(Path(rv.root).parent.parent / "compile_cache")
-        local = str(root) if root else default
-        try:
-            root = rv.agree("compile-cache", lambda: local)
-            agreed = True
-        except Exception:  # ddl-lint: disable=broad-except
-            # agreement is an optimization (identical envs agree
-            # trivially); a coord hiccup must not fail the launch
-            root = local
-    if not root:
         return None
     try:
         min_s = float(
@@ -273,25 +260,51 @@ def activate_compile_cache(
         )
     except ValueError:
         min_s = DEFAULT_MIN_COMPILE_S
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", min_s)
+    placed = os.environ.get(ENV_JAX_CACHE)
     key = topology_key()
-    cache_dir = Path(root) / key
-    try:
+    agreed = False
+    if placed:
+        cache_dir = Path(placed)
+    else:
+        root = cache_root or env_root
+        if rv is not None:
+            # one pod, one cache dir: the leader publishes (its env wins
+            # so an operator override propagates), everyone else adopts.
+            # The default sits beside the launches/ subdirs, so it
+            # survives relaunches AND later launches of the same pod
+            # directory.
+            default = str(Path(rv.root).parent.parent / "compile_cache")
+            local = str(root) if root else default
+            try:
+                root = rv.agree("compile-cache", lambda: local)
+                agreed = True
+            except Exception:  # ddl-lint: disable=broad-except
+                # agreement is an optimization (identical envs agree
+                # trivially); a coord hiccup must not fail the launch
+                root = local
+        root = root or default_cache_root()
+        cache_dir = Path(root) / key
         cache_dir.mkdir(parents=True, exist_ok=True)
-    except OSError:
-        return None
-    # bound the shared root BEFORE counting entries, so `warm` and
-    # `entries_before` describe what actually survived the byte bound
-    evict_to_byte_bound(root, active_key=key)
-    entries = cache_entries(cache_dir)
-    if not _point_jax_at(cache_dir, min_s):
-        return None
+        # bound the shared root BEFORE counting entries, so `warm` and
+        # `entries_before` describe what actually survived the byte bound
+        evict_to_byte_bound(root, active_key=key)
+        if jax.config.jax_compilation_cache_dir != str(cache_dir):
+            from jax.experimental.compilation_cache import compilation_cache
+
+            jax.config.update("jax_compilation_cache_dir", str(cache_dir))
+            # a process that already compiled keeps its first directory
+            # open until the cache object is rebuilt
+            compilation_cache.reset_cache()
     _install_counters()
+    entries = cache_entries(cache_dir)
     _active = {
         "dir": str(cache_dir),
         "key": key,
         "entries_before": entries,
         "warm": entries > 0,
         "agreed": agreed,
+        "placed": bool(placed),
     }
     if events is not None:
         emit_cache_event(events)
@@ -316,9 +329,3 @@ def emit_cache_event(events) -> None:
         return
     events.emit("compile_cache", **stats)
 
-
-def enable_compile_cache(default_dir: str = "/tmp/ddl_tpu_xla_cache") -> None:
-    """Bench entry points' historical always-on activation: point the
-    cache at ``$DDL_COMPILE_CACHE`` (or ``default_dir``), topology-keyed
-    like the launch path; a no-op on backends without cache support."""
-    activate_compile_cache(cache_root=os.environ.get(ENV_CACHE, default_dir))

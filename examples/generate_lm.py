@@ -79,6 +79,10 @@ def main() -> None:
     import numpy as np
     import optax
 
+    from ddl_tpu.utils.compile_cache import activate_compile_cache
+
+    activate_compile_cache()
+
     from ddl_tpu.checkpoint import load_snapshot, snapshot_metadata
     from ddl_tpu.infer import make_lm_generator
     from ddl_tpu.models.transformer import LMConfig

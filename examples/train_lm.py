@@ -160,6 +160,10 @@ def main() -> None:
         force_cpu_devices(args.cpu_devices)
     import jax
 
+    from ddl_tpu.utils.compile_cache import activate_compile_cache
+
+    activate_compile_cache()
+
     from ddl_tpu.models.transformer import REMAT_POLICIES, LMConfig
     from ddl_tpu.parallel.sharding import LMMeshSpec
     from ddl_tpu.train.lm_trainer import LMRunConfig, LMTrainer

@@ -95,6 +95,10 @@ def main() -> None:
         force_cpu_devices(args.cpu_devices)
     import jax
 
+    from ddl_tpu.utils.compile_cache import activate_compile_cache
+
+    activate_compile_cache()
+
     from ddl_tpu.config import DataConfig
     from ddl_tpu.models.vit import ViTConfig
     from ddl_tpu.parallel.sharding import LMMeshSpec

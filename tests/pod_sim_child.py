@@ -26,15 +26,11 @@ force_cpu_devices(1)
 
 import jax  # noqa: E402
 
-# share the suite's persistent compile cache: generation-0 children must
-# not spend longer compiling than the watchdog deadline
-_cache = os.environ.get("DDL_TEST_COMPILE_CACHE")
-if _cache:
-    try:
-        jax.config.update("jax_compilation_cache_dir", _cache)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    except Exception:
-        pass
+# generation-0 children must not spend longer compiling than the watchdog
+# deadline: the suite hands them its persistent compile cache through
+# JAX_COMPILATION_CACHE_DIR (test_coord._suite_cache_env), which JAX reads
+# itself
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
 import optax  # noqa: E402
 

@@ -37,3 +37,40 @@ def test_cli_single_end_to_end(tmp_path, monkeypatch):
         tmp_path / "logs" / "by_job_id" / "single-clitest" / "steps_per_sec.csv"
     )
     assert sps[0]["value"] > 0
+
+
+def test_chip_smoke_refuses_the_cpu(tmp_path):
+    """``chip_smoke.py`` is the proof that the system starts on the chip:
+    on the CPU backend it must exit non-zero with ``ok`` false in its
+    last line — at full size at once, and after a whole tiny-size
+    rehearsal too — and its parent must stay off JAX."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env["JAX_PLATFORMS"] = "cpu"
+    out = subprocess.run(
+        [sys.executable, os.path.join(repo, "chip_smoke.py")],
+        env=env, capture_output=True, text=True, timeout=120, cwd=tmp_path,
+    )
+    assert out.returncode != 0
+    last = json.loads(out.stdout.strip().splitlines()[-1])
+    assert last["ok"] is False
+    assert last["device"]["platform"] == "cpu"
+    phase = json.loads(out.stdout.strip().splitlines()[0])
+    assert phase["phase"] == "cnn" and phase["ok"] is False
+    assert "needs a TPU" in phase["error"]
+    # the parent imports neither jax nor the package
+    probe = (
+        "import sys, runpy; sys.argv = ['chip_smoke.py', '--help']\n"
+        "try: runpy.run_path(%r, run_name='__main__')\n"
+        "except SystemExit: pass\n"
+        "assert 'jax' not in sys.modules and 'ddl_tpu' not in sys.modules"
+    ) % os.path.join(repo, "chip_smoke.py")
+    subprocess.run(
+        [sys.executable, "-c", probe], env=env, check=True, timeout=60,
+        capture_output=True, cwd=tmp_path,
+    )

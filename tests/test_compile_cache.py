@@ -1,18 +1,28 @@
 """Persistent compile cache (utils/compile_cache.py): warm restarts.
 
-Host-tier tests of the activation logic — root precedence (arg > env >
-pod-agreed default), the off switch, topology keying, warm/cold entry
-counting, hit/miss counters via jax.monitoring, and the compile_cache
-obs event.  XLA's own persistence is not under test here (the pod-sim
-e2e exercises it via the suite cache); what is under test is that the
-launch path points JAX at one agreed, keyed directory and reports the
-truth about it.
+Host-tier tests of the activation logic — a directory placed through
+JAX_COMPILATION_CACHE_DIR is used as it stands, otherwise root
+precedence (arg > env > pod-agreed default > the checkout's fixed
+directory), the off switch, topology keying, warm/cold entry counting,
+hit/miss counters via jax.monitoring, and the compile_cache obs event.
+XLA's own persistence is not under test here (the pod-sim e2e exercises
+it via the suite cache); what is under test is that the launch path
+points JAX at one agreed, keyed directory and reports the truth about
+it.
 """
+
+import json
+import os
+import subprocess
+import sys
 
 import jax
 import pytest
+from jax.experimental.compilation_cache import compilation_cache
 
 from ddl_tpu.utils import compile_cache as cc
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
@@ -24,6 +34,8 @@ def _isolate(monkeypatch):
         cc, "_counters",
         {"hits": 0, "misses": 0, "evicted": 0, "evicted_bytes": 0},
     )
+    # the tests below hold whatever the caller's environment places
+    monkeypatch.delenv(cc.ENV_JAX_CACHE, raising=False)
     monkeypatch.delenv(cc.ENV_CACHE, raising=False)
     monkeypatch.delenv(cc.ENV_CACHE_MIN_S, raising=False)
     monkeypatch.delenv(cc.ENV_CACHE_MAX_BYTES, raising=False)
@@ -32,16 +44,81 @@ def _isolate(monkeypatch):
     yield
     jax.config.update("jax_compilation_cache_dir", prev_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", prev_min)
+    compilation_cache.reset_cache()  # back onto the suite's directory
 
 
-def test_activation_is_opt_in_and_off_wins(tmp_path, monkeypatch):
-    # bare local run: no env, no rendezvous -> stays off
-    assert cc.activate_compile_cache() is None
-    assert cc.cache_stats() is None
+def test_default_is_the_fixed_checkout_dir_and_off_wins(tmp_path, monkeypatch):
+    # bare local run: no env, no rendezvous -> the checkout's directory,
+    # the same on every call
+    assert str(cc.default_cache_root()) == os.path.join(REPO, ".jax_cache")
+    first = cc.activate_compile_cache()
+    second = cc.activate_compile_cache()
+    want = str(cc.default_cache_root() / cc.topology_key())
+    assert first["dir"] == second["dir"] == want
+    assert first["placed"] is False
+    assert jax.config.jax_compilation_cache_dir == want
     # the force-disable beats even an explicit root
     for off in ("off", "0", ""):
         monkeypatch.setenv(cc.ENV_CACHE, off)
         assert cc.activate_compile_cache(cache_root=tmp_path) is None
+
+
+_CHILD = """
+import json, os
+from ddl_tpu.utils import compile_cache as cc
+stats = cc.activate_compile_cache()
+import jax
+print(json.dumps({"pid": os.getpid(), "dir": stats["dir"],
+                  "placed": stats["placed"],
+                  "config": jax.config.jax_compilation_cache_dir}))
+"""
+
+
+def _activate_in_child(env_overrides: dict) -> dict:
+    env = {
+        k: v for k, v in os.environ.items()
+        if k not in (cc.ENV_JAX_CACHE, cc.ENV_CACHE, "XLA_FLAGS")
+    }
+    env.update(JAX_PLATFORMS="cpu", **env_overrides)
+    out = subprocess.run(
+        [sys.executable, "-c", _CHILD], env=env, check=True, timeout=120,
+        capture_output=True, text=True, cwd=REPO,
+    ).stdout
+    return json.loads(out.strip().splitlines()[-1])
+
+
+def test_default_dir_is_identical_across_processes():
+    a, b = _activate_in_child({}), _activate_in_child({})
+    assert a["pid"] != b["pid"]
+    assert a["dir"] == b["dir"] == a["config"] == b["config"]
+    assert a["dir"].startswith(str(cc.default_cache_root()))
+
+
+def test_placed_dir_is_used_as_it_stands(tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: exactly that directory — nothing
+    appended, DDL_COMPILE_CACHE and an explicit root do not override it,
+    and the byte bound never evicts from it."""
+    placed = tmp_path / "placed"
+    placed.mkdir()
+    entry = _entry(tmp_path, "placed", "kept", size=1000, age_s=9000)
+    monkeypatch.setenv(cc.ENV_JAX_CACHE, str(placed))
+    monkeypatch.setenv(cc.ENV_CACHE, str(tmp_path / "ddl"))
+    monkeypatch.setenv(cc.ENV_CACHE_MAX_BYTES, "10")
+    before = jax.config.jax_compilation_cache_dir
+    stats = cc.activate_compile_cache(cache_root=tmp_path / "arg")
+    assert stats["dir"] == str(placed) and stats["placed"] is True
+    assert stats["entries_before"] == 1 and stats["warm"] is True
+    # the program set no directory: jax.config is as JAX made it from
+    # the environment (this process started without the variable)
+    assert jax.config.jax_compilation_cache_dir == before
+    assert entry.exists() and cc.cache_stats()["evicted"] == 0
+    assert [p.name for p in placed.iterdir()] == ["kept"]
+    assert not (tmp_path / "ddl").exists() and not (tmp_path / "arg").exists()
+    # a process STARTED with the variable: jax.config equals it
+    child = _activate_in_child({cc.ENV_JAX_CACHE: str(placed)})
+    assert child["dir"] == child["config"] == str(placed)
+    assert child["placed"] is True
+    assert [p.name for p in placed.iterdir()] == ["kept"]
 
 
 def test_env_activation_keys_by_topology_and_counts_entries(
@@ -216,11 +293,19 @@ def test_activation_applies_byte_bound_and_reports_evictions(
     assert ev.emitted[0][1]["evicted"] == 3
 
 
-def test_bench_enable_stays_always_on(tmp_path, monkeypatch):
-    # the historical bench entry point: no env -> default dir, still
-    # topology-keyed
-    monkeypatch.delenv(cc.ENV_CACHE, raising=False)
-    cc.enable_compile_cache(default_dir=str(tmp_path / "bench"))
-    stats = cc.cache_stats()
-    assert stats is not None
-    assert stats["dir"].startswith(str(tmp_path / "bench"))
+def test_no_unguarded_cache_dir_site_in_the_program():
+    """The acceptance grep, kept as a test: the only code that sets the
+    cache directory is the branch that runs with the variable unset."""
+    sites = []
+    for root in ("ddl_tpu", "bench.py", "chip_smoke.py", "examples"):
+        path = os.path.join(REPO, root)
+        files = [path] if path.endswith(".py") else [
+            os.path.join(d, f) for d, _, fs in os.walk(path)
+            for f in fs if f.endswith(".py")
+        ]
+        for f in files:
+            with open(f) as fh:
+                for n, line in enumerate(fh, 1):
+                    if '"jax_compilation_cache_dir"' in line:
+                        sites.append((os.path.relpath(f, REPO), n))
+    assert [f for f, _ in sites] == ["ddl_tpu/utils/compile_cache.py"], sites

@@ -604,6 +604,14 @@ def _read_consumed(sim: Path, host: int) -> list[tuple[int, int]]:
     return out
 
 
+def _suite_cache_env() -> dict:
+    """The suite's persistent compile cache (tests/conftest.py), placed
+    for a sim's children the way any caller places it."""
+    import jax
+
+    return {"JAX_COMPILATION_CACHE_DIR": jax.config.jax_compilation_cache_dir}
+
+
 def _warm_compile_cache(sim_env: dict, tmp_path: Path) -> None:
     """One plain 1-step child run to seed the persistent XLA cache, so
     generation-0 children compile in far less than the watchdog
@@ -641,9 +649,7 @@ def test_three_host_pod_sim_stall_escalation_and_exact_resume(tmp_path):
         DDL_JOB_ID="podsim",
         DDL_LOG_DIR=str(sim / "suplogs"),
         DDL_WATCHDOG_S="4",
-        DDL_TEST_COMPILE_CACHE=os.environ.get(
-            "DDL_TEST_COMPILE_CACHE", "/tmp/ddl_tpu_test_xla_cache"
-        ),
+        **_suite_cache_env(),
     )
     _warm_compile_cache(base_env, tmp_path)
 
@@ -833,9 +839,7 @@ def test_three_host_pod_sim_permanent_host_loss_elastic_continue(tmp_path):
         DDL_JOB_ID="podelastic",
         DDL_LOG_DIR=str(sim / "suplogs"),
         DDL_WATCHDOG_S="30",
-        DDL_TEST_COMPILE_CACHE=os.environ.get(
-            "DDL_TEST_COMPILE_CACHE", "/tmp/ddl_tpu_test_xla_cache"
-        ),
+        **_suite_cache_env(),
     )
     _warm_compile_cache(base_env, tmp_path)
 
@@ -950,9 +954,7 @@ def test_three_host_pod_sim_host_loss_then_rejoin(tmp_path):
         DDL_JOB_ID="podrejoin",
         DDL_LOG_DIR=str(sim / "suplogs"),
         DDL_WATCHDOG_S="30",
-        DDL_TEST_COMPILE_CACHE=os.environ.get(
-            "DDL_TEST_COMPILE_CACHE", "/tmp/ddl_tpu_test_xla_cache"
-        ),
+        **_suite_cache_env(),
     )
     _warm_compile_cache(base_env, tmp_path)
 

@@ -216,13 +216,31 @@ class TestMfu:
         from ddl_tpu.bench.mfu import PEAK_BF16_FLOPS, device_peak_flops
 
         class FakeDev:
-            def __init__(self, kind):
+            def __init__(self, kind, platform="tpu"):
                 self.device_kind = kind
+                self.platform = platform
 
         assert device_peak_flops(FakeDev("TPU v5 lite")) == PEAK_BF16_FLOPS["TPU v5 lite"]
         assert device_peak_flops(FakeDev("TPU v5p")) == PEAK_BF16_FLOPS["TPU v5p"]
         assert device_peak_flops(FakeDev("TPU v4")) == PEAK_BF16_FLOPS["TPU v4"]
-        assert device_peak_flops(FakeDev("cpu")) is None
+        assert device_peak_flops(FakeDev("cpu", platform="cpu")) is None
+
+    def test_unlisted_tpu_kind_raises(self):
+        """A device that is not in the table is an error, not a default:
+        no peak is assumed for a bare or future kind string."""
+        from ddl_tpu.bench.mfu import device_peak_flops, mfu
+
+        class FakeDev:
+            platform = "tpu"
+
+            def __init__(self, kind):
+                self.device_kind = kind
+
+        for kind in ("TPU v5", "TPU v9x", ""):
+            with pytest.raises(KeyError, match="PEAK_BF16_FLOPS"):
+                device_peak_flops(FakeDev(kind))
+        with pytest.raises(KeyError):
+            mfu(1e12, 0.01, FakeDev("TPU v5"))
 
     def test_mfu_on_cpu_is_none(self):
         from ddl_tpu.bench.mfu import mfu
@@ -262,10 +280,12 @@ class TestMemoryStats:
         from ddl_tpu.utils.memory import hbm_stats
 
         class NoStats:
+            platform = "cpu"
+
             def memory_stats(self):
                 return None
 
-        class Raises:
+        class Raises(NoStats):
             def memory_stats(self):
                 raise RuntimeError("unsupported")
 
@@ -278,6 +298,8 @@ class TestMemoryStats:
         from ddl_tpu.utils.memory import hbm_stats
 
         class FakeDev:
+            platform = "tpu"
+
             def memory_stats(self):
                 return {"bytes_in_use": 10, "peak_bytes_in_use": 99,
                         "bytes_limit": 1000}
@@ -285,6 +307,25 @@ class TestMemoryStats:
         out = hbm_stats(FakeDev())
         assert out == {"bytes_in_use": 10, "peak_bytes_in_use": 99,
                        "bytes_limit": 1000}
+
+    def test_tpu_without_stats_raises(self):
+        """On the chip a missing watermark is a fault, not a None."""
+        from ddl_tpu.utils.memory import hbm_stats
+
+        class NoStats:
+            platform = "tpu"
+
+            def memory_stats(self):
+                return None
+
+        class Raises(NoStats):
+            def memory_stats(self):
+                raise RuntimeError("unsupported")
+
+        with pytest.raises(RuntimeError, match="no memory stats"):
+            hbm_stats(NoStats())
+        with pytest.raises(RuntimeError, match="unsupported"):
+            hbm_stats(Raises())
 
 
 def test_flash_attention_train_flops_band_closed_form():

@@ -220,3 +220,25 @@ class TestFusedVocabChunkedCE:
         np.testing.assert_allclose(
             np.asarray(g3), 3 * np.asarray(g1), rtol=1e-5
         )
+
+
+def test_interpret_default_is_one_rule_for_every_kernel():
+    """Interpreted on the CPU backend, compiled on a TPU, and an error —
+    never a silent interpreter — on anything else; every kernel module
+    resolves ``interpret=None`` through the same helper."""
+    import importlib
+
+    import pytest
+
+    from ddl_tpu.ops.interpret import interpret_default
+
+    assert interpret_default("cpu") is True
+    assert interpret_default("tpu") is False
+    assert interpret_default() is True  # this suite runs on the CPU backend
+    for platform in ("gpu", "some-plugin", ""):
+        with pytest.raises(RuntimeError, match="neither"):
+            interpret_default(platform)
+    for name in ("flash_attention", "decode_attention", "fused_dense_block",
+                 "int8_matvec", "pallas_image"):
+        mod = importlib.import_module(f"ddl_tpu.ops.{name}")
+        assert mod.interpret_default is interpret_default

@@ -8,6 +8,8 @@ simulated 8-device CPU mesh: one optimizer step must produce (near-)identical
 parameters, loss, and predictions.
 """
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -50,39 +52,55 @@ def _clone(state):
     return jax.tree.map(jnp.copy, state)
 
 
-def sequential_reference_step(stages, tx, state, images, labels, M, D):
-    """Ground truth: loop over D data shards x M microbatches, grad of the
-    averaged loss, single Adam update — pure jax.numpy, no mesh."""
+def microbatch_loss_and_grads(stages, params, stats, images, labels):
+    """One microbatch through every stage on one device: its mean CE, the
+    gradient of that, the logits and the stages' new BN statistics.  Pure
+    jax.numpy, no mesh — a caller at real widths wraps it in ``jax.jit``
+    (``chip_smoke.py --chips 4``), where op-by-op dispatch of a whole
+    DenseNet121 would take minutes."""
+
+    def loss_fn(params):
+        x = images.astype(jnp.float32) / 255.0
+        new_stats = []
+        for i, st in enumerate(stages):
+            x, ns = apply_stage(st, params[i], stats[i], x, train=True)
+            new_stats.append(ns)
+        return softmax_cross_entropy(x, labels).mean(), (x, tuple(new_stats))
+
+    (loss, (logits, new_stats)), grads = jax.value_and_grad(
+        loss_fn, has_aux=True
+    )(params)
+    return loss, grads, logits, new_stats
+
+
+def sequential_reference_step(stages, tx, state, images, labels, M, D,
+                              micro=None):
+    """Ground truth: loop over D data shards x M microbatches, gradient of
+    the averaged loss (the average of the microbatch gradients), single
+    optimizer update — no mesh.  ``micro(params, stats, images, labels)``
+    defaults to ``microbatch_loss_and_grads`` over ``stages``."""
+    if micro is None:
+        micro = partial(microbatch_loss_and_grads, stages)
     shard = images.shape[0] // D
     mb = shard // M
-
-    def total_loss(params):
-        shard_losses, shard_stats, logits_cat = [], [], []
-        for d in range(D):
-            stats = state.batch_stats
-            loss_d = 0.0
-            for m in range(M):
-                lo = d * shard + m * mb
-                x = images[lo : lo + mb].astype(jnp.float32) / 255.0
-                new_stats = []
-                for i, st in enumerate(stages):
-                    x, ns = apply_stage(st, params[i], stats[i], x, train=True)
-                    new_stats.append(ns)
-                stats = tuple(new_stats)
-                loss_d = loss_d + softmax_cross_entropy(x, labels[lo : lo + mb]).mean()
-                logits_cat.append(x)
-            shard_losses.append(loss_d / M)
-            shard_stats.append(stats)
-        loss = sum(shard_losses) / D
-        return loss, (jnp.concatenate(logits_cat), shard_stats)
-
-    (loss, (logits, shard_stats)), grads = jax.value_and_grad(
-        total_loss, has_aux=True
-    )(state.params)
-    updates, new_opt = tx.update(grads, state.opt_state, state.params)
+    loss, grads, logits_cat, shard_stats = 0.0, None, [], []
+    for d in range(D):
+        stats = state.batch_stats
+        for m in range(M):
+            lo = d * shard + m * mb
+            loss_dm, g, logits, stats = micro(
+                state.params, stats, images[lo : lo + mb], labels[lo : lo + mb]
+            )
+            loss = loss + loss_dm / (M * D)
+            grads = g if grads is None else jax.tree.map(jnp.add, grads, g)
+            logits_cat.append(logits)
+        shard_stats.append(stats)
+    grads = jax.tree.map(lambda g: g / (M * D), grads)
+    updates, _ = tx.update(grads, state.opt_state, state.params)
     new_params = optax.apply_updates(state.params, updates)
     mean_stats = jax.tree.map(lambda *xs: jnp.mean(jnp.stack(xs), 0), *shard_stats)
-    return new_params, mean_stats, float(loss), np.argmax(np.asarray(logits), -1)
+    preds = np.argmax(np.asarray(jnp.concatenate(logits_cat)), -1)
+    return new_params, mean_stats, float(loss), preds
 
 
 def _assert_tree_close(a, b, atol):

@@ -788,6 +788,25 @@ def test_defrag_compacts_and_preserves_tokens(lm):
         np.testing.assert_array_equal(eng.results[cid], want[cid])
 
 
+def test_jit_cache_size_is_there_to_count_compiles():
+    """The engine's compile counters lean on the private
+    ``PjitFunction._cache_size`` of the one supported installation: pin
+    that it exists and counts executables, so an upgrade that moves it
+    fails here and not as a silent zero in serving stats."""
+    import jax
+    import jax.numpy as jnp
+
+    from ddl_tpu.serve.engine import _jit_compiles
+
+    prog = jax.jit(lambda x: x + 1)
+    assert _jit_compiles(prog) == 0
+    prog(jnp.zeros((2,)))
+    prog(jnp.ones((2,)))
+    assert _jit_compiles(prog) == 1
+    prog(jnp.zeros((3,)))
+    assert _jit_compiles(prog) == 2
+
+
 def test_engine_precompile_covers_grid(lm):
     from ddl_tpu.serve.engine import ServeEngine
 

@@ -1,0 +1,189 @@
+"""Every Pallas kernel compiles for the chip, at the widths it serves.
+
+Interpret mode (the rest of the suite) cannot see what Mosaic refuses:
+a block that breaks the (8, 128) tiling rule, a cast or reshape with no
+lowering, a working set past the scoped-VMEM limit.  The TPU compiler is
+installed beside the CPU backend and compiles for a chip that is
+described, not attached, so these cases ask it — shapes only, nothing
+runs, no chip time.  This is the only file that describes a topology
+(one process may hold libtpu; see the fixtures).
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+BF16 = jnp.bfloat16
+F32 = jnp.float32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # whatever the missing compiler raises
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one: the next run would warn and
+    compile again.  Keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def compile_for_chip(one_chip, no_persistent_cache):
+    def compile_(fn, *shapes):
+        args = jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+            shapes,
+        )
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        assert "tpu_custom_call" in text
+        return text
+
+    return compile_
+
+
+def _s(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _sum_f32(x):
+    return x.astype(F32).sum()
+
+
+@pytest.mark.parametrize(
+    "batch,kv_heads,seq,window",
+    [
+        pytest.param(8, 12, 1024, 0, id="gpt2s-mha"),
+        pytest.param(8, 4, 1024, 0, id="gpt2s-gqa4"),
+        pytest.param(1, 12, 8192, 1024, id="t8192-window1024"),
+    ],
+)
+def test_flash_attention_fwd_and_grad(
+    compile_for_chip, batch, kv_heads, seq, window
+):
+    from ddl_tpu.ops.flash_attention import flash_attention
+
+    def attend(q, k, v):
+        return flash_attention(
+            q, k, v, causal=True, window=window, interpret=False
+        )
+
+    q = _s((batch, seq, 12, 64), BF16)
+    kv = _s((batch, seq, kv_heads, 64), BF16)
+    compile_for_chip(attend, q, kv, kv)
+    compile_for_chip(
+        jax.grad(lambda q, k, v: _sum_f32(attend(q, k, v)), argnums=(0, 1, 2)),
+        q, kv, kv,
+    )
+
+
+@pytest.mark.parametrize("kv_heads", [12, 4], ids=["fused768", "fused256"])
+@pytest.mark.parametrize("cache_len", [1024, 8192])
+def test_decode_attention_bf16_and_int8(compile_for_chip, cache_len, kv_heads):
+    """Both entry points, with the per-lane (B, L) bias the serving
+    engine's continuous batch passes."""
+    from ddl_tpu.ops.decode_attention import (
+        decode_attention,
+        quant_decode_attention,
+    )
+
+    b, fused = 32, kv_heads * 64
+    q = _s((b, 1, 12, 64), BF16)
+    bias = _s((b, cache_len), F32)
+    cache = _s((b, cache_len, fused), BF16)
+    compile_for_chip(
+        lambda q, ck, cv, bias: decode_attention(
+            q, ck, cv, bias, hkv=kv_heads, interpret=False
+        ),
+        q, cache, cache, bias,
+    )
+    cache8 = _s((b, cache_len, fused), jnp.int8)
+    scales = _s((b, kv_heads, cache_len), F32)
+    compile_for_chip(
+        lambda q, ck, ks, cv, vs, bias: quant_decode_attention(
+            q, ck, ks, cv, vs, bias, hkv=kv_heads, interpret=False
+        ),
+        q, cache8, scales, cache8, scales, bias,
+    )
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+@pytest.mark.parametrize(
+    "hw,c0,layers",
+    [
+        pytest.param(56, 64, 6, id="densenet121-block1"),
+        pytest.param(7, 512, 16, id="densenet121-block4"),
+    ],
+)
+def test_fused_dense_block(compile_for_chip, hw, c0, layers, grad):
+    from ddl_tpu.ops.fused_dense_block import block_pad, fused_dense_block
+
+    growth, bn = 32, 128
+    _, p_total = block_pad(c0, layers, growth)
+    x0 = _s((30, hw, hw, c0), BF16)
+    packed = {
+        "a1": _s((layers, 1, p_total), F32),
+        "b1": _s((layers, 1, p_total), F32),
+        "w1": _s((layers, p_total, bn), F32),
+        "a2": _s((layers, 1, bn), F32),
+        "b2": _s((layers, 1, bn), F32),
+        "w2": _s((layers, 9, bn, growth), F32),
+    }
+
+    def block(x0, packed):
+        return fused_dense_block(
+            x0, packed, c0=c0, growth=growth, interpret=False
+        )
+
+    if grad:
+        compile_for_chip(
+            jax.grad(lambda x0, p: _sum_f32(block(x0, p)), argnums=(0, 1)),
+            x0, packed,
+        )
+    else:
+        compile_for_chip(block, x0, packed)
+
+
+@pytest.mark.parametrize("out_features", [3072, 50304], ids=["mlp", "lm-head"])
+def test_int8_matvec(compile_for_chip, out_features):
+    from ddl_tpu.ops.int8_matvec import int8_matmul_small_m
+
+    compile_for_chip(
+        lambda x, w8, scale: int8_matmul_small_m(
+            x, w8, scale, interpret=False
+        ),
+        _s((1, 768), BF16),
+        _s((768, out_features), jnp.int8),
+        _s((out_features,), F32),
+    )
+
+
+def test_pallas_normalize_images(compile_for_chip):
+    from ddl_tpu.ops.pallas_image import pallas_normalize_images
+
+    compile_for_chip(
+        lambda images: pallas_normalize_images(images, BF16, interpret=False),
+        _s((30, 224, 224, 3), jnp.uint8),
+    )

@@ -10,45 +10,34 @@ PERF.md round 4), ``profile_lm``, and the anomaly-triggered capture path
 ``op_digest`` summary so a regression is explainable without opening
 TensorBoard.
 
-Traces are read with ``jax.profiler.ProfileData``.  A log-analysis
-host without JAX (``ddl_tpu bench digest`` over a mounted trace dir)
-gets a minimal protobuf *wire-format* reader for the stable
-XSpace/XPlane schema instead — no TensorFlow/xprof import, just the
-handful of field numbers the analysis needs.  CPU traces have no
+Traces are read with ``jax.profiler.ProfileData``.  CPU traces have no
 ``/device:`` plane at all (XLA ops land on ``/host:CPU`` thread-pool
-lines named ``tf_XLA*``), so the readers fall back to those when no
+lines named ``tf_XLA*``), so the reader falls back to those when no
 device plane exists — the same digest, host-sided, which is exactly what
 a CPU-JAX CI run can check.
+
+``op_digest`` groups device time by the run's scope tables
+(``obs/scope.py``: ``fwd`` / ``bwd`` / ``kernel/<name>`` / ``update``),
+each op joined with the table of the module it ran in: the tables this
+process planned (``obs/hbm.plan_program``), or the ``scope-h*.json`` files
+of the run the capture lies under.  With no table at hand, or a trace that
+names no modules (CPU), it groups by opcode category.
 """
 
 from __future__ import annotations
 
+import bisect
 import collections
 import glob
 import os
 import re
 
+from ddl_tpu.obs.scope import opcode_of, own_name
+
 __all__ = [
-    "analyze", "op_digest", "opcode_of", "print_report", "read_trace",
-    "CATEGORY",
+    "analyze", "op_digest", "opcode_of", "own_name", "print_report",
+    "read_trace", "CATEGORY",
 ]
-
-# HLO text looks like "%fusion.123 = bf16[...] fusion(...), kind=kLoop ..."
-_OPCODE_RX = re.compile(r"=\s*(?:\([^)]*\)|[^ ]+)\s+([a-z][a-z0-9-]*)\(")
-
-
-def opcode_of(name: str) -> str:
-    """Pull the HLO opcode out of a profiler op-event name."""
-    m = _OPCODE_RX.search(name)
-    if m:
-        op = m.group(1)
-    else:
-        # bare names like "fusion.123" / "copy-start.4"
-        op = name.split(" ")[0].lstrip("%").split(".")[0]
-    if "fusion" in name and (kind := re.search(r"kind=k(\w+)", name)):
-        return f"fusion:{kind.group(1)}"
-    return op
-
 
 CATEGORY = {
     "convolution": "conv",
@@ -78,93 +67,9 @@ CATEGORY = {
 
 
 # ---------------------------------------------------------------------------
-# Trace readers.  Both normalize to the same shape:
-#     [(plane_name, line_name, [(event_name, dur_ms), ...]), ...]
+# Trace reader:
+#     [(plane_name, [(line_name, [(event_name, start_ms, dur_ms), ...]), ...])]
 # ---------------------------------------------------------------------------
-
-
-def _pb_varint(buf: bytes, i: int) -> tuple[int, int]:
-    shift = val = 0
-    while True:
-        b = buf[i]
-        i += 1
-        val |= (b & 0x7F) << shift
-        if not b & 0x80:
-            return val, i
-        shift += 7
-
-
-def _pb_fields(buf: bytes):
-    """Iterate (field_number, value) over one serialized proto message —
-    the minimal wire-format walk (varint + length-delimited + fixed)."""
-    i, n = 0, len(buf)
-    while i < n:
-        key, i = _pb_varint(buf, i)
-        fnum, wt = key >> 3, key & 7
-        if wt == 0:  # varint
-            val, i = _pb_varint(buf, i)
-        elif wt == 1:  # fixed64
-            val, i = buf[i:i + 8], i + 8
-        elif wt == 2:  # length-delimited
-            ln, i2 = _pb_varint(buf, i)
-            val, i = buf[i2:i2 + ln], i2 + ln
-        elif wt == 5:  # fixed32
-            val, i = buf[i:i + 4], i + 4
-        else:
-            raise ValueError(f"unsupported wire type {wt}")
-        yield fnum, val
-
-
-def _read_xplane_wire(path: str):
-    """Parse an ``*.xplane.pb`` without ``ProfileData``: XSpace.planes=1;
-    XPlane{name=2, lines=3, event_metadata=4}; XLine{name=2,
-    display_name=11, events=4}; XEvent{metadata_id=1, duration_ps=3};
-    XEventMetadata{name=2, display_name=4} — the stable subset of the
-    schema this analysis needs."""
-    with open(path, "rb") as fh:
-        space = fh.read()
-    planes = []
-    for fnum, plane_buf in _pb_fields(space):
-        if fnum != 1:
-            continue
-        pname, line_bufs, meta = "", [], {}
-        for f2, v2 in _pb_fields(plane_buf):
-            if f2 == 2:
-                pname = v2.decode("utf-8", "replace")
-            elif f2 == 3:
-                line_bufs.append(v2)
-            elif f2 == 4:  # map<int64, XEventMetadata>
-                key, name = None, ""
-                for f3, v3 in _pb_fields(v2):
-                    if f3 == 1:
-                        key = v3
-                    elif f3 == 2:
-                        for f4, v4 in _pb_fields(v3):
-                            if f4 == 2 and not name:
-                                name = v4.decode("utf-8", "replace")
-                            elif f4 == 4:  # display_name wins
-                                name = v4.decode("utf-8", "replace")
-                if key is not None:
-                    meta[key] = name
-        lines = []
-        for lb in line_bufs:
-            lname, ldisp, events = "", "", []
-            for f3, v3 in _pb_fields(lb):
-                if f3 == 2:
-                    lname = v3.decode("utf-8", "replace")
-                elif f3 == 11:
-                    ldisp = v3.decode("utf-8", "replace")
-                elif f3 == 4:
-                    mid = dur_ps = 0
-                    for f4, v4 in _pb_fields(v3):
-                        if f4 == 1:
-                            mid = v4
-                        elif f4 == 3:
-                            dur_ps = v4
-                    events.append((meta.get(mid, f"op-{mid}"), dur_ps / 1e9))
-            lines.append((ldisp or lname, events))
-        planes.append((pname, lines))
-    return planes
 
 
 def _read_xplane_profiledata(path: str):
@@ -178,7 +83,7 @@ def _read_xplane_profiledata(path: str):
                 (
                     line.name,
                     [
-                        (ev.name, (ev.end_ns - ev.start_ns) / 1e6)
+                        (ev.name, ev.start_ns / 1e6, (ev.end_ns - ev.start_ns) / 1e6)
                         for ev in line.events
                     ],
                 )
@@ -191,19 +96,14 @@ def _read_xplane_profiledata(path: str):
 
 def read_trace(trace_dir: str):
     """Read the newest ``*.xplane.pb`` under ``trace_dir`` into
-    ``[(plane_name, [(line_name, [(event_name, dur_ms), ...]), ...])]``,
-    via ``ProfileData``, or the wire reader on a host without JAX."""
+    ``[(plane_name, [(line_name, [(event_name, start_ms, dur_ms), ...]),
+    ...])]``."""
     paths = glob.glob(
         os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True
     )
     if not paths:
         raise FileNotFoundError(f"no xplane.pb under {trace_dir}")
-    path = max(paths, key=os.path.getmtime)
-    try:
-        from jax.profiler import ProfileData  # noqa: F401
-    except ImportError:
-        return _read_xplane_wire(path)
-    return _read_xplane_profiledata(path)
+    return _read_xplane_profiledata(max(paths, key=os.path.getmtime))
 
 
 # Host-plane lines that carry XLA op execution when there is no device
@@ -216,17 +116,32 @@ _HOST_NOISE = re.compile(
 )
 
 
+def _module_of(modules, start_ms: float) -> str | None:
+    """The name of the module event (sorted ``(start, end, name)``) that
+    holds ``start_ms``, without its ``(<program id>)``; None outside all."""
+    i = bisect.bisect_right(modules, (start_ms, float("inf"), "")) - 1
+    if i < 0 or start_ms >= modules[i][1]:
+        return None
+    return modules[i][2].split("(", 1)[0]
+
+
 def _op_events(planes):
-    """(event_name, dur_ms) pairs of executed XLA ops: the device planes'
-    sync-op line, or the host XLA thread-pool lines when no device plane
-    exists (CPU traces)."""
+    """(module, event_name, dur_ms) of executed XLA ops: the device planes'
+    sync-op line, each op with the module of that plane's ``XLA Modules``
+    line it started in; or the host XLA thread-pool lines when no device
+    plane exists (CPU traces), which name no module (None)."""
     out = []
     for pname, lines in planes:
         if not pname.startswith("/device:"):
             continue
-        for lname, events in lines:
-            if lname == "XLA Ops":
-                out.extend(events)
+        by_line = dict(lines)
+        modules = sorted(
+            (s, s + d, n) for n, s, d in by_line.get("XLA Modules", ())
+        )
+        out.extend(
+            (_module_of(modules, s), n, d)
+            for n, s, d in by_line.get("XLA Ops", ())
+        )
     if out:
         return out
     for pname, lines in planes:
@@ -235,17 +150,16 @@ def _op_events(planes):
         for lname, events in lines:
             if _HOST_XLA_LINE.search(lname):
                 out.extend(
-                    (n, d) for n, d in events if not _HOST_NOISE.search(n)
+                    (None, n, d) for n, _, d in events
+                    if not _HOST_NOISE.search(n)
                 )
     return out
 
 
-def analyze(trace_dir: str):
-    """Aggregate a captured trace.  Returns (per_op ms, per_op counts,
-    async-DMA busy ms, XLA-module ms) — all totals over the traced steps."""
-    planes = read_trace(trace_dir)
-
-    per_op: dict[str, float] = collections.defaultdict(float)
+def _aggregate(planes):
+    """({(module, event name): ms}, {event name: count}, async-DMA busy
+    ms, XLA-module ms) of a read trace."""
+    by_module: dict[tuple, float] = collections.defaultdict(float)
     per_op_count: dict[str, int] = collections.defaultdict(int)
     async_ms = 0.0
     module_ms = 0.0
@@ -254,26 +168,64 @@ def analyze(trace_dir: str):
             continue
         for lname, events in lines:
             if lname == "XLA Modules":
-                module_ms += sum(d for _, d in events)
+                module_ms += sum(d for _, _, d in events)
             if lname == "Async XLA Ops":
-                async_ms += sum(d for _, d in events)
-    for name, dur in _op_events(planes):
-        per_op[name] += dur
+                async_ms += sum(d for _, _, d in events)
+    for module, name, dur in _op_events(planes):
+        by_module[module, name] += dur
         per_op_count[name] += 1
+    return by_module, per_op_count, async_ms, module_ms
+
+
+def analyze(trace_dir: str):
+    """Aggregate a captured trace.  Returns (per_op ms, per_op counts,
+    async-DMA busy ms, XLA-module ms) — all totals over the traced steps."""
+    by_module, per_op_count, async_ms, module_ms = _aggregate(read_trace(trace_dir))
+    per_op: dict[str, float] = collections.defaultdict(float)
+    for (_, name), ms in by_module.items():
+        per_op[name] += ms
     return per_op, per_op_count, async_ms, module_ms
 
 
-def op_digest(trace_dir: str, top: int = 8) -> dict:
-    """Compact per-op-category device-time summary of a captured trace —
-    the payload ``profile_capture`` events carry so a throughput anomaly
-    is explainable from the event stream alone.  ``{"total_ms", "ops":
-    {category: ms (top N)}, "top_op": name}``; ms totals are over the
-    whole traced window."""
-    per_op, _counts, _async_ms, module_ms = analyze(trace_dir)
+def _scope_tables(trace_dir: str) -> dict:
+    """The scope tables a digest of ``trace_dir`` joins with: this
+    process's own, or the files of the run the capture lies under."""
+    from ddl_tpu.obs.hbm import scope_tables
+    from ddl_tpu.obs.scope import load_tables
+
+    return scope_tables() or load_tables(trace_dir)
+
+
+def op_digest(trace_dir: str, top: int = 8, scope: dict | None = None) -> dict:
+    """Compact device-time summary of a captured trace — the payload
+    ``profile_capture`` events carry so a throughput anomaly is
+    explainable from the event stream alone.  ``{"total_ms", "ops":
+    {group: ms (top N)}, "top_op": name, "by": "scope" | "opcode"}``; ms
+    totals are over the whole traced window.
+
+    ``scope`` is ``{module name: {instruction name: tag}}``; left out,
+    ``_scope_tables``.  An op joins the table of the module it ran in and
+    groups by its tag — ``bwd``, ``kernel/flash_bwd_dkv``, ``update`` —
+    and an op of a module without a table, or one its table does not
+    know, by ``other (<opcode>)``.  When no op finds a tag (no table, or
+    a CPU trace, which names no module) all group by opcode category as
+    before."""
+    by_module, _counts, _async_ms, module_ms = _aggregate(read_trace(trace_dir))
+    if scope is None:
+        scope = _scope_tables(trace_dir)
+    tags = {
+        key: scope.get(key[0], {}).get(own_name(key[1])) for key in by_module
+    }
+    by_scope = any(tag is not None for tag in tags.values())
     cats: dict[str, float] = collections.defaultdict(float)
-    for name, ms in per_op.items():
-        op = opcode_of(name)
-        cats[CATEGORY.get(op, f"other ({op})")] += ms
+    per_op: dict[str, float] = collections.defaultdict(float)
+    for key, ms in by_module.items():
+        per_op[key[1]] += ms
+        tag = tags[key]
+        if tag is None:
+            op = opcode_of(key[1])
+            tag = f"other ({op})" if by_scope else CATEGORY.get(op, f"other ({op})")
+        cats[tag] += ms
     ranked = sorted(cats.items(), key=lambda kv: -kv[1])
     top_op = max(per_op.items(), key=lambda kv: kv[1])[0] if per_op else None
     return {
@@ -281,6 +233,7 @@ def op_digest(trace_dir: str, top: int = 8) -> dict:
         "module_ms": round(module_ms, 3),
         "ops": {k: round(v, 3) for k, v in ranked[:top]},
         "top_op": top_op[:140] if top_op else None,
+        "by": "scope" if by_scope else "opcode",
     }
 
 

@@ -45,6 +45,7 @@ class DataLoader:
         pad_last_batch: bool = False,
         io_retries: int = 2,
         on_retry=None,
+        on_collate=None,
     ) -> None:
         self.dataset = dataset
         self.batch_size = batch_size
@@ -66,6 +67,10 @@ class DataLoader:
         self.io_retries = max(0, io_retries)
         self.on_retry = on_retry
         self.retry_count = 0
+        # observability hook: ``on_collate(batch index in the epoch)``
+        # returns a context manager the producer thread builds the batch
+        # under (the trainers' ``collate`` span).  No hook, no cost.
+        self.on_collate = on_collate
         # one policy object for the loader's lifetime — _fetch runs once
         # per sample in the hot path, and Backoff construction seeds an
         # RNG from OS entropy
@@ -160,20 +165,21 @@ class DataLoader:
         # the native decoder reads the same NAS files — same retry policy
         return self._retry_io(lambda: native.load_batch(paths, h, w))
 
-    def _batches(self) -> Iterator[np.ndarray]:
+    def _batches(self) -> Iterator[Tuple[int, np.ndarray]]:
+        """(batch index in the epoch, its sample indices)."""
         idxs = np.asarray(list(self.sampler.indices()))
         n_full = len(idxs) // self.batch_size
         skip = getattr(self, "_start_batch", 0)
         self._start_batch = 0
         for b in range(skip, n_full):
-            yield idxs[b * self.batch_size : (b + 1) * self.batch_size]
+            yield b, idxs[b * self.batch_size : (b + 1) * self.batch_size]
         if not self.drop_last and n_full * self.batch_size < len(idxs):
             tail = idxs[n_full * self.batch_size :]
             if self.pad_last_batch:
                 tail = np.concatenate(
                     [tail, np.full(self.batch_size - len(tail), -1, tail.dtype)]
                 )
-            yield tail
+            yield n_full, tail
 
     def __iter__(self) -> Iterator[Tuple[np.ndarray, np.ndarray]]:
         """Yield collated (uint8 images, int32 labels), prefetching ahead."""
@@ -184,10 +190,17 @@ class DataLoader:
         # would train on a shorter epoch and report nothing)
         error: list[BaseException] = []
 
+        on_collate = self.on_collate
+
         def producer():
             try:
-                for batch_idxs in self._batches():
-                    q.put(self._collate(batch_idxs))
+                for b, batch_idxs in self._batches():
+                    if on_collate is None:
+                        batch = self._collate(batch_idxs)
+                    else:
+                        with on_collate(b):
+                            batch = self._collate(batch_idxs)
+                    q.put(batch)
             except BaseException as e:
                 error.append(e)
             finally:

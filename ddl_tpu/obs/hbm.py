@@ -18,7 +18,12 @@ the raw material (obs/events.py):
     compiled executable's memory analysis (argument/output/temp/code
     bytes — the run-time continuation of ``analysis/hlolint.py``'s
     lint-time memory inventory), degrading to pure aval arithmetic when
-    the runtime exposes no analysis.
+    the runtime exposes no analysis.  The same executable's text gives
+    the program's scope table (``obs/scope.py``: which instruction is
+    forward, backward, a kernel, the update); the event carries its
+    per-tag counts (``scope_counts``) and the name of the table's file
+    beside the event file (``scope_file``), which ``obs hbm`` prints with
+    the plan and ``bench digest`` loads.
 
 ``hbm_oom_dump``
     The forensic snapshot an allocation failure emits before the
@@ -48,6 +53,8 @@ __all__ = [
     "plan_program",
     "render_hbm",
     "sample_categories",
+    "scope_table",
+    "scope_tables",
     "summary_from_fold",
     "top_consumers",
     "tree_shard_bytes",
@@ -82,6 +89,8 @@ PLAN_FIELDS = (
     "temp_bytes",
     "alias_bytes",
     "code_bytes",
+    "scope_counts",
+    "scope_file",
 )
 
 # buffers retained in an OOM dump / plans retained per repoch cell —
@@ -226,6 +235,7 @@ def plan_program(
     analysis = "aval"
     arg_b = out_b = None
     temp_b = alias_b = code_b = None
+    compiled = None
     try:
         import jax
 
@@ -258,9 +268,56 @@ def plan_program(
         "alias_bytes": alias_b,
         "code_bytes": code_b,
     }
+    scope = _scope_of(writer, label, compiled) if compiled is not None else None
     if len(_recent_plans) < MAX_PLANS or label in _recent_plans:
-        _recent_plans[label] = plan
-    return writer.emit("hbm_plan", step=step, label=str(label), **plan)
+        # the table stays in-process (and in its file): 14,000 rows for
+        # DenseNet121's step do not belong in a JSONL line
+        _recent_plans[label] = {**plan, **(scope or {})}
+    fields = {k: scope[k] for k in ("scope_counts", "scope_file")} if scope else {}
+    return writer.emit("hbm_plan", step=step, label=str(label), **plan, **fields)
+
+
+def _scope_of(writer, label: str, compiled) -> dict | None:
+    """The compiled program's scope table (``obs/scope.py``), written
+    beside the host's event file: ``{"scope": {...}, "scope_module",
+    "scope_counts", "scope_file"}``, or None — like the budget, a table
+    that cannot be made must not take the run down."""
+    from ddl_tpu.obs import scope as sc
+
+    try:
+        text = compiled.as_text()
+        module, table = sc.module_name(text), sc.scope_table(text)
+        del text
+        if not table:
+            return None
+        name = sc.write_table(writer.path.parent, writer.host, label, module, table)
+        return {
+            "scope": table,
+            "scope_module": module,
+            "scope_counts": sc.tag_counts(table),
+            "scope_file": name,
+        }
+    except Exception:
+        return None
+
+
+def scope_table(label: str) -> dict | None:
+    """``{instruction name: tag}`` of the program this process last
+    planned under ``label``, or None (``mode="aval"``/``off``, no
+    executable text, no plan yet)."""
+    return _recent_plans.get(label, {}).get("scope")
+
+
+def scope_tables() -> dict[str, dict]:
+    """``{HLO module name: table}`` of every program this process
+    planned: what a digest of a profile of this process joins op events
+    with, module by module (instruction names repeat from one program to
+    the next).  Empty when no table was made."""
+    return {
+        plan["scope_module"]: plan["scope"]
+        for plan in _recent_plans.values()
+        if plan.get("scope")
+    }
 
 
 # OOM signatures across backends/versions; matched case-insensitively
@@ -332,7 +389,10 @@ def dump_oom(
             params_bytes=params_bytes,
             opt_bytes=opt_bytes,
             buffers=buffers,
-            plans=dict(_recent_plans),
+            plans={
+                label: {k: v for k, v in plan.items() if k in PLAN_FIELDS}
+                for label, plan in _recent_plans.items()
+            },
         )
     except Exception:
         return None
@@ -558,6 +618,9 @@ def render_hbm(account: dict, job_id: str = "") -> str:
                 f"{fmt_bytes(p.get('code_bytes')):>10}"
                 f"  {p.get('analysis', '?')}"
             )
+            if p.get("scope_counts"):
+                counts = ", ".join(f"{t} {n}" for t, n in p["scope_counts"].items())
+                lines.append(f"    scope ({p.get('scope_file')}): {counts}")
         if dropped:
             lines.append(f"  (+{dropped} plan(s) beyond the retained cap)")
 

@@ -19,6 +19,36 @@ device time it hides surfaces in ``fence`` — the two together bound the
 compiled program; ``utils/timing.fence`` is the true-completion fence
 behind the ``fence`` phase.
 
+Beside the phases the trace keeps the program's own account of the idle
+device, as child spans that are NOT phases (they go through
+``writer.span`` alone: no period total, so ``obs goodput`` still sums):
+
+    data_wait.idle  a ``data_wait`` / ``h2d`` phase that began with the
+    h2d.idle        device known idle: the trainer hands ``note_dispatch``
+                    one non-donated output of each step, and at a phase's
+                    start its non-blocking ``is_ready()`` says whether the
+                    device has finished all it was given.  Nothing is
+                    dispatched inside these phases, so the device is idle
+                    to their end.  A lower bound, exact to a phase: a
+                    device that runs dry inside a phase shows at the next
+                    (a ``step`` phase gets no child: a dispatch onto an
+                    idle device launches the program somewhere inside the
+                    call, and no host clock says where)
+    fence.drain     the period-end copies up to the one whose return says
+                    the device has drained (``device_drained``); its tail,
+                    from the device's last op to that return, is idle time
+                    no host clock can see
+    fence.d2h       the copies after that, all with the device idle
+    collate         one batch built on the loader's producer thread
+                    (``collate_hook``, ``DataLoader(on_collate=)``)
+
+A child is written when its parent phase's span is (``DDL_OBS_STEP_SPANS``:
+0 turns every per-step span off, children and ``collate`` included, and
+with them the ``is_ready()`` calls).  Every phase and child also enters a
+``jax.profiler.TraceAnnotation`` (``step`` a ``StepTraceAnnotation``
+besides), a flag test while no profiler session runs: a profile then holds
+the program's phases and the device's ops on one clock.
+
 ``AnomalyMonitor`` rides along: every ``end_period`` feeds the rolling
 detectors, and ``finish()`` surfaces everything they caught.
 """
@@ -28,7 +58,7 @@ from __future__ import annotations
 import os
 import time
 from collections import defaultdict
-from contextlib import contextmanager
+from contextlib import ExitStack, contextmanager, nullcontext
 
 from ddl_tpu.obs.anomaly import AnomalyMonitor
 from ddl_tpu.obs.events import EventWriter
@@ -68,6 +98,12 @@ def _consume_relaunch_ts() -> float | None:
 # boundary (one write each, and a preemption's blocking checkpoint span
 # is exactly what an incident review needs), so they always emit.
 PER_STEP_PHASES = frozenset({"data_wait", "h2d", "step", "fence"})
+# What the sampler thins: those phases, their children (``fence.drain``
+# goes with ``fence``), and the loader thread's ``collate``.
+PER_STEP_SPANS = PER_STEP_PHASES | {"collate"}
+# The phases whose body dispatches nothing: one that begins with the
+# device known idle keeps it idle to its end, and says so in a child.
+IDLE_PHASES = frozenset({"data_wait", "h2d"})
 
 
 class _CompileCounter:
@@ -153,6 +189,16 @@ class StepTrace:
         # gateable number.  Consumed once per process, not per
         # StepTrace: a second train() segment is not a restart.
         self._relaunch_ts = _consume_relaunch_ts()
+        # the idle account: the last dispatched step's output (not
+        # donated) and whether the device is known to have finished
+        # everything it was given
+        self._last_out = None
+        self._device_idle = False
+        # only trainers build a StepTrace, so JAX is there; imported here
+        # because the obs read path imports this module and no JAX
+        from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+        self._annotate, self._annotate_step = TraceAnnotation, StepTraceAnnotation
 
     @classmethod
     def create(
@@ -197,15 +243,71 @@ class StepTrace:
 
     def _span_due(self, name: str, step: int | None) -> bool:
         """The 1-in-N step-span sampler.  Only per-step phases are
-        thinned; period-boundary phases (eval/checkpoint/logging — one
+        thinned (a child span goes with its parent, ``collate`` with the
+        steps); period-boundary phases (eval/checkpoint/logging — one
         write per period, not the per-step cost the sampler bounds)
         follow the all-or-nothing setting regardless of their step tag."""
         n = self.emit_step_spans
         if n <= 0:
             return False
-        if n == 1 or step is None or name not in PER_STEP_PHASES:
+        if n == 1 or step is None or name.partition(".")[0] not in PER_STEP_SPANS:
             return True
         return step % n == 0
+
+    # ------------------------------------------------------------------
+    # the idle account
+
+    def note_dispatch(self, out) -> None:
+        """The trainer's call right after it dispatched a train step:
+        ``out`` is one output of that step that is not donated (the
+        loss).  The device has work again."""
+        self._last_out = out
+        self._device_idle = False
+
+    def device_drained(self) -> None:
+        """The trainer's call when a blocking copy of the last step's
+        output has returned: the device is idle, exactly."""
+        self._last_out = None
+        self._device_idle = True
+
+    def _idle_now(self) -> bool:
+        """Whether the device is known idle, asked without blocking.
+        ``is_ready`` is a method of the concrete ``ArrayImpl``, not of
+        ``jax.Array``: an output without it reads as unknown, never as
+        idle."""
+        if not self._device_idle and self._last_out is not None:
+            ready = getattr(self._last_out, "is_ready", None)
+            if ready is not None and ready():
+                self.device_drained()
+        return self._device_idle
+
+    def _enter(self, stack: ExitStack, name: str, step, write: bool, **fields):
+        """Enter ``name`` into the profiler's trace (a flag test while no
+        session runs) and, if ``write``, into the event stream."""
+        stack.enter_context(self._annotate(name, step=step))
+        if write:
+            stack.enter_context(
+                self.writer.span(name, step=step, period=self._period, **fields)
+            )
+
+    def child(self, name: str, step: int | None = None):
+        """A span under the open phase (``fence.drain``, ``fence.d2h``)
+        that is not a phase: no period total, no watchdog beat.  Written
+        when its parent's span is."""
+        if not self._span_due(name, step):
+            return nullcontext()
+        stack = ExitStack()
+        self._enter(stack, name, step, True)
+        return stack
+
+    def collate_hook(self, step_base: int):
+        """The ``DataLoader(on_collate=)`` hook for one period: a
+        ``collate`` span a batch from the producer thread, its step the
+        period's first plus the batch's index.  None when per-step spans
+        are off: no hook, no cost."""
+        if self.emit_step_spans <= 0:
+            return None
+        return lambda batch: self.child("collate", step=step_base + batch)
 
     @contextmanager
     def phase(self, name: str, step: int | None = None, **fields):
@@ -220,12 +322,13 @@ class StepTrace:
         t0 = time.perf_counter()
         completed = False
         try:
-            if self._span_due(name, step):
-                with self.writer.span(
-                    name, step=step, period=self._period, **fields
-                ):
-                    yield
-            else:
+            with ExitStack() as stack:
+                if name == "step" and step is not None:
+                    stack.enter_context(self._annotate_step("train", step_num=step))
+                due = self._span_due(name, step)
+                self._enter(stack, name, step, due, **fields)
+                if due and name in IDLE_PHASES and self._idle_now():
+                    self._enter(stack, name + ".idle", step, True)
                 yield
             completed = True
         finally:
@@ -250,14 +353,6 @@ class StepTrace:
                 )
             if self.watchdog is not None:
                 self.watchdog.beat(step)
-
-    def fence(self, tree, step: int | None = None) -> None:
-        """Block until ``tree``'s device values exist, attributed to the
-        ``fence`` phase (``utils/timing.fence`` — block + readback)."""
-        from ddl_tpu.utils.timing import fence
-
-        with self.phase("fence", step=step):
-            fence(tree)
 
     def begin_period(self, period: int) -> None:
         if self._needs_run_start:

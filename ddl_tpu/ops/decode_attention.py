@@ -270,6 +270,7 @@ def decode_attention(q, ck, cv, bias, *, hkv: int, block_l=None,
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="decode_attn",
     )(q[:, 0], ck, cv, bias[:, None])
     return out[:, None]
 
@@ -311,5 +312,6 @@ def quant_decode_attention(q, ck, ks, cv, vs, bias, *, hkv: int,
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="decode_attn_int8",
     )(q[:, 0], ck, cv, ks, vs, bias[:, None])
     return out[:, None]

@@ -262,6 +262,7 @@ def _flash_fwd_impl(
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return out, lse
 
@@ -308,6 +309,7 @@ def _flash_bwd_kernels(q, k, v, out, lse, do, dlse, causal, window,
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
 
     # grid (bkv, k_blocks, g * q_blocks): outermost at K/V-head
@@ -341,6 +343,7 @@ def _flash_bwd_kernels(q, k, v, out, lse, do, dlse, causal, window,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

@@ -253,6 +253,7 @@ def _forward_call(x0p, a1, b1, w1, a2, b2, w2, *, c0, growth, interpret):
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="dense_block_fwd",
     )(x0p, a1, b1, w1, a2, b2, w2)
 
 
@@ -459,6 +460,7 @@ def _backward_call(out, g, a1, b1, w1, a2, b2, w2, *, c0, growth,
             vmem_limit_bytes=_BWD_VMEM_LIMIT,
         ),
         interpret=interpret,
+        name="dense_block_bwd",
     )(out, g, a1, b1, w1, a2, b2, w2)
 
 

@@ -100,5 +100,6 @@ def int8_matmul_small_m(x, w8, scale, *, contract_last: bool = False,
         out_specs=pl.BlockSpec((MATVEC_MAX_ROWS, bo), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((MATVEC_MAX_ROWS, o_pad), x.dtype),
         interpret=interpret,
+        name="int8_matvec",
     )(xp, w8, s_row)
     return out[:m, :o]

@@ -53,5 +53,6 @@ def pallas_normalize_images(
         in_specs=[pl.BlockSpec((b, block), lambda j: (0, j))],
         out_specs=pl.BlockSpec((b, block), lambda j: (0, j)),
         interpret=interpret,
+        name="normalize_images",
     )(flat)
     return out.reshape(images.shape)

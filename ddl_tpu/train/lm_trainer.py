@@ -37,7 +37,7 @@ from ddl_tpu import checkpoint as ckpt
 from ddl_tpu.models.transformer import LMConfig
 from ddl_tpu.parallel.sharding import LMMeshSpec
 from ddl_tpu.train.lm_steps import make_lm_step_fns
-from ddl_tpu.train.loop import BaseTrainer, _phase
+from ddl_tpu.train.loop import BaseTrainer, _child, _phase
 from ddl_tpu.utils import MetricLogger, faultinject
 
 __all__ = ["LMRunConfig", "LMTrainer"]
@@ -495,6 +495,9 @@ class LMTrainer(BaseTrainer):
                 inp, tgt = self._sample_batch(i)
             with _phase(self.obs, "step", step=i):
                 self.state, m = self.fns.train(self.state, inp, tgt)
+            if self.obs is not None:
+                # one output that is not donated, for the idle account
+                self.obs.note_dispatch(next(iter(m.values())))
             # HBM ledger: stamp the train step's static memory budget
             # once, after its first dispatch (obs/hbm.py hbm_plan)
             self.emit_hbm_plan("train_step", self.fns.train,
@@ -505,7 +508,16 @@ class LMTrainer(BaseTrainer):
                 break
         if steps:
             with _phase(self.obs, "fence", step=p0 + steps - 1):
-                metrics = {k: float(v) for k, v in m.items()}
+                # the first metric's copy returns when the device has
+                # drained; the others' copies (a round trip each) are host
+                # time with the device idle.  Same copies, same order.
+                items = iter(m.items())
+                with _child(self.obs, "fence.drain", step=p0 + steps - 1):
+                    metrics = {k: float(v) for k, v in [next(items)]}
+                if self.obs is not None:
+                    self.obs.device_drained()
+                with _child(self.obs, "fence.d2h", step=p0 + steps - 1):
+                    metrics.update((k, float(v)) for k, v in items)
             self._maybe_anneal_capacity(metrics)
         return metrics, steps
 

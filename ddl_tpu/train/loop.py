@@ -50,6 +50,12 @@ def _phase(obs, name: str, step: int | None = None):
     return obs.phase(name, step=step) if obs is not None else nullcontext()
 
 
+def _child(obs, name: str, step: int | None = None):
+    """A span under the open phase that is not a phase itself
+    (``fence.drain``, ``fence.d2h``), or a no-op when untraced."""
+    return obs.child(name, step=step) if obs is not None else nullcontext()
+
+
 class BaseTrainer:
     """Template-method training loop.
 

@@ -32,7 +32,7 @@ from ddl_tpu.config import Config
 from ddl_tpu.data import DataLoader, ShardedEpochSampler, build_datasets, shard_batch
 from ddl_tpu.models import build_stages, stage_boundary_shapes
 from ddl_tpu.parallel.mesh import MeshSpec, build_mesh
-from ddl_tpu.train.loop import BaseTrainer, _phase
+from ddl_tpu.train.loop import BaseTrainer, _child, _phase
 from ddl_tpu.train.state import create_train_state, make_optimizer
 from ddl_tpu.train.steps import make_dp_step_fns
 from ddl_tpu.utils import MetricLogger, faultinject, masked_classification_eval
@@ -346,11 +346,18 @@ class Trainer(BaseTrainer):
         # liveness/straggler comparison sees one monotone counter per
         # host, the same unit the LM family's global step gives it
         step_base = epoch * len(self.train_loader) + skip
+        if self.obs is not None:
+            # one ``collate`` span a batch from the loader's thread
+            self.train_loader.on_collate = self.obs.collate_hook(
+                step_base - skip
+            )
         it = iter(self.train_loader)
         while True:
             # data_wait = host-side batch production (the loader), h2d =
             # device placement, step = compiled-step dispatch; the device
-            # time dispatch hides surfaces in the period-end fence phase
+            # time dispatch hides surfaces in the period-end fence phase.
+            # A phase that begins with the device known idle carries a
+            # child that says so (obs/steptrace.py)
             with _phase(self.obs, "data_wait", step=step_base + steps):
                 batch = next(it, None)
             if batch is None:
@@ -364,6 +371,8 @@ class Trainer(BaseTrainer):
                 self.logger.log_gradient_stats(stats, step=steps)
             with _phase(self.obs, "step", step=step_base + steps):
                 self.state, loss, pred = self.step_fns.train(self.state, gi, gl)
+            if self.obs is not None:
+                self.obs.note_dispatch(loss)
             # HBM ledger: stamp the train step's static memory budget
             # once, after its first dispatch (obs/hbm.py hbm_plan)
             self.emit_hbm_plan("train_step", self.step_fns.train,
@@ -378,9 +387,16 @@ class Trainer(BaseTrainer):
         if steps == 0:
             raise RuntimeError("empty epoch: dataset smaller than one batch")
         with _phase(self.obs, "fence", step=step_base + steps):
-            mean_loss = float(np.mean([_to_host(l) for l in losses]))
-            y_pred = np.concatenate([_to_host(p) for p in preds])
-            y_true = np.concatenate([_to_host(t) for t in targets])
+            # the early losses are copied while the device still runs; when
+            # the last one is here the device has drained, and the copies
+            # after it are host time with the device idle
+            with _child(self.obs, "fence.drain", step=step_base + steps):
+                mean_loss = float(np.mean([_to_host(l) for l in losses]))
+            if self.obs is not None:
+                self.obs.device_drained()
+            with _child(self.obs, "fence.d2h", step=step_base + steps):
+                y_pred = np.concatenate([_to_host(p) for p in preds])
+                y_true = np.concatenate([_to_host(t) for t in targets])
         accuracy = float(np.mean(y_pred == y_true))
         return {"loss": mean_loss, "train_accuracy": accuracy}, steps
 

@@ -25,7 +25,7 @@ from ddl_tpu.data import (
 )
 from ddl_tpu.models.vit import ViTConfig
 from ddl_tpu.parallel.sharding import LMMeshSpec
-from ddl_tpu.train.loop import BaseTrainer, _phase
+from ddl_tpu.train.loop import BaseTrainer, _child, _phase
 from ddl_tpu.train.vit_steps import make_vit_step_fns
 from ddl_tpu.utils import MetricLogger, faultinject, masked_classification_eval
 
@@ -218,6 +218,11 @@ class ViTTrainer(BaseTrainer):
         # global event steps (epoch * steps/epoch + i) — one monotone
         # counter per host for the obs liveness/straggler comparison
         step_base = epoch * len(self.train_loader) + skip
+        if self.obs is not None:
+            # one ``collate`` span a batch from the loader's thread
+            self.train_loader.on_collate = self.obs.collate_hook(
+                step_base - skip
+            )
         it = iter(self.train_loader)
         while True:
             with _phase(self.obs, "data_wait", step=step_base + steps):
@@ -229,6 +234,8 @@ class ViTTrainer(BaseTrainer):
                 gi, gl = shard_batch(self.fns.mesh, images, labels)
             with _phase(self.obs, "step", step=step_base + steps):
                 self.state, m = self.fns.train(self.state, gi, gl)
+            if self.obs is not None:
+                self.obs.note_dispatch(m["loss"])
             # HBM ledger: stamp the train step's static memory budget
             # once, after its first dispatch (obs/hbm.py hbm_plan)
             self.emit_hbm_plan("train_step", self.fns.train,
@@ -245,7 +252,10 @@ class ViTTrainer(BaseTrainer):
         if steps == 0:
             raise RuntimeError("empty epoch: dataset smaller than one batch")
         with _phase(self.obs, "fence", step=step_base + steps - 1):
-            loss = float(np.mean([np.asarray(l) for l in losses]))
+            with _child(self.obs, "fence.drain", step=step_base + steps - 1):
+                loss = float(np.mean([np.asarray(l) for l in losses]))
+            if self.obs is not None:
+                self.obs.device_drained()
         return {"loss": loss}, steps
 
     def evaluate_period(self, epoch: int) -> dict:

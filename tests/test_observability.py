@@ -58,6 +58,14 @@ def test_train_lm_writes_metric_csvs(tmp_path, capsys):
     # per-step phase spans exist for the in-loop phases
     span_names = {e["name"] for e in events if e["kind"] == "span"}
     assert {"data_wait", "step", "fence", "logging"} <= span_names
+    # the fence's children: the first metric's copy says the device has
+    # drained, the others' copies follow it; a later period's first phases
+    # begin with the device known idle
+    spans = [e for e in events if e["kind"] == "span"]
+    at = next(i for i, e in enumerate(spans) if e["name"] == "fence.drain")
+    assert [e["name"] for e in spans[at:at + 3]] == ["fence.drain", "fence.d2h", "fence"]
+    assert all(e["parent"] == "fence" for e in spans[at:at + 2])
+    assert "data_wait.idle" in span_names
 
     periods = [e for e in events if e["kind"] == "period"]
     assert sum(p["steps"] for p in periods) == 12

@@ -92,11 +92,15 @@ def flash_attention_train_flops(
     on v5e: an isolated `flash_attention` program reports none, and a
     flash train step's total equals the model's non-attention FLOPs
     exactly), so flash bench rows undercount MFU — increasingly with T.
-    This closed form restores the kernel's executed FLOPs, counting only
-    the visible (q, k) score pairs — the kernel really skips blocks
-    outside the causal/window band via predicated execution, so banded
-    rows are credited with banded FLOPs, not full causal ones (round-2's
-    windowed-MFU caveat, resolved analytically):
+    This closed form credits the visible (q, k) score pairs only, banded
+    rows with banded FLOPs and not full causal ones (round-2's
+    windowed-MFU caveat, resolved analytically).  It is what the
+    algorithm requires, not what the kernels run: they do the band's work
+    at sub-tile granularity (``ops/flash_attention.flash_tile_plan``:
+    nothing outside the band, whole 256 x 256 sub-tiles on its edge), so
+    at T=1024 they compute 62.5% of the square for the 50.05% credited
+    here, and before PR 26 they computed all of it whenever
+    ``T <= block_k``:
 
     * visible pairs: causal ``T(T+1)/2``; with a window W, the first W
       rows keep their triangle and the rest see W keys each —

@@ -91,6 +91,7 @@ PLAN_FIELDS = (
     "code_bytes",
     "scope_counts",
     "scope_file",
+    "kernel_tiles",
 )
 
 # buffers retained in an OOM dump / plans retained per repoch cell —
@@ -273,7 +274,10 @@ def plan_program(
         # the table stays in-process (and in its file): 14,000 rows for
         # DenseNet121's step do not belong in a JSONL line
         _recent_plans[label] = {**plan, **(scope or {})}
-    fields = {k: scope[k] for k in ("scope_counts", "scope_file")} if scope else {}
+    fields = {
+        k: scope[k] for k in ("scope_counts", "scope_file", "kernel_tiles")
+        if scope[k]
+    } if scope else {}
     return writer.emit("hbm_plan", step=step, label=str(label), **plan, **fields)
 
 
@@ -287,7 +291,6 @@ def _scope_of(writer, label: str, compiled) -> dict | None:
     try:
         text = compiled.as_text()
         module, table = sc.module_name(text), sc.scope_table(text)
-        del text
         if not table:
             return None
         name = sc.write_table(writer.path.parent, writer.host, label, module, table)
@@ -296,6 +299,7 @@ def _scope_of(writer, label: str, compiled) -> dict | None:
             "scope_module": module,
             "scope_counts": sc.tag_counts(table),
             "scope_file": name,
+            "kernel_tiles": sc.kernel_tiles(text),
         }
     except Exception:
         return None
@@ -621,6 +625,13 @@ def render_hbm(account: dict, job_id: str = "") -> str:
             if p.get("scope_counts"):
                 counts = ", ".join(f"{t} {n}" for t, n in p["scope_counts"].items())
                 lines.append(f"    scope ({p.get('scope_file')}): {counts}")
+            for kernel, n in (p.get("kernel_tiles") or {}).items():
+                share = 100.0 * n.get("computed", 0) / max(n.get("total", 0), 1)
+                lines.append(
+                    f"    tiles {kernel}: {n.get('calls')} call(s), "
+                    f"{n.get('computed')} of {n.get('total')} sub-tiles "
+                    f"computed ({share:.1f}%), {n.get('masked')} masked"
+                )
         if dropped:
             lines.append(f"  (+{dropped} plan(s) beyond the retained cap)")
 

@@ -46,8 +46,8 @@ import re
 from pathlib import Path
 
 __all__ = [
-    "load_tables", "module_name", "opcode_of", "own_name", "scope_table",
-    "tag_counts", "write_table",
+    "kernel_tiles", "load_tables", "module_name", "opcode_of", "own_name",
+    "scope_table", "tag_counts", "write_table",
 ]
 
 # ``<opcode>(`` after the result type; types hold ``T(8,128)`` and ``S(1)``,
@@ -57,6 +57,8 @@ _OPERAND_RX = re.compile(r"%([\w.\-]+)")
 # the innermost scope around ``pallas_call``: ``jvp(flash_fwd)/pallas_call``
 _KERNEL_RX = re.compile(r"([A-Za-z_][\w.\-]*)\)*/pallas_call")
 # instructions that never run as a device op of their own
+# a kernel's ``metadata`` as XLA prints it: a flat JSON object of strings
+_TILES_RX = re.compile(r"kernel_metadata=\{([^{}]*)\}")
 _NO_EVENT = frozenset(
     {"parameter", "constant", "get-tuple-element", "tuple", "bitcast"}
 )
@@ -93,22 +95,39 @@ def module_name(text: str) -> str:
 
 
 def _entry_lines(text: str):
-    """The ENTRY computation's instruction lines, walked in place (the
-    text of a large step runs to hundreds of MB: no ``splitlines``)."""
+    """The ENTRY computation's instructions, one string each, walked in
+    place (the text of a large step runs to hundreds of MB: no
+    ``splitlines``).  An instruction's line is indented; a kernel's
+    ``metadata`` is printed as JSON over several lines that are not, and
+    they are joined back onto their instruction."""
     at = text.find("\nENTRY ")
     if at < 0:
         at = 0 if text.startswith("ENTRY ") else -1
     if at < 0:
         return
     pos = text.find("\n", at + 1) + 1
+    pending = None
     while 0 < pos < len(text):
         end = text.find("\n", pos)
         if end < 0:
             end = len(text)
-        if text.startswith("}", pos):
-            return
-        yield text[pos:end]
+        line = text[pos:end]
+        if line == "}":
+            break
+        if line.startswith(" ") or pending is None:
+            if pending is not None:
+                yield pending
+            pending = line
+        else:
+            pending += line
         pos = end + 1
+    if pending is not None:
+        yield pending
+
+
+def _instruction_name(line: str) -> str:
+    """``  ROOT %fusion.3 = ...`` -> ``fusion.3``."""
+    return line.partition(" = ")[0].split()[-1].lstrip("%")
 
 
 def _direction(op_name: str) -> str | None:
@@ -144,10 +163,10 @@ def scope_table(text: str) -> dict[str, str]:
     optimized HLO module's text; empty when the text has no ENTRY."""
     rows = []  # (name, own tag, tag handed down, operand names), in program order
     for line in _entry_lines(text):
-        head, sep, rest = line.partition(" = ")
+        _, sep, rest = line.partition(" = ")
         if not sep:
             continue
-        name = head.split()[-1].lstrip("%")
+        name = _instruction_name(line)
         body = " " + rest
         m = _OPCODE_RX.search(body)
         if m is None or m.group(1) in _NO_EVENT:
@@ -180,6 +199,39 @@ def tag_counts(table: dict[str, str]) -> dict[str, int]:
     for tag in table.values():
         counts[tag] = counts.get(tag, 0) + 1
     return dict(sorted(counts.items()))
+
+
+def kernel_tiles(text: str) -> dict[str, dict[str, int]]:
+    """What the Pallas kernels of an optimized module's ENTRY say they
+    compute, summed by kernel: ``{kernel name: {"calls", "total",
+    "computed", "masked"}}`` from each custom call's ``kernel_metadata``
+    (``pallas_call(metadata={"tiles_total": ...})``: the flash kernels'
+    sub-tiles in the square, visited, and masked,
+    ``ops/flash_attention.flash_tile_plan``).  Kernels that carry none,
+    and programs without kernels (or interpreted ones), give ``{}``."""
+    out: dict[str, dict[str, int]] = {}
+    for line in _entry_lines(text):
+        if "kernel_metadata=" not in line:
+            continue
+        m = _TILES_RX.search(line)
+        if m is None or 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        try:
+            meta = json.loads("{" + m.group(1) + "}")
+            tiles = {
+                k[len("tiles_"):]: int(v)
+                for k, v in meta.items() if k.startswith("tiles_")
+            }
+        except ValueError:
+            continue
+        if not tiles:
+            continue
+        tag, _ = _own_tags(line, _instruction_name(line), "custom-call")
+        row = out.setdefault(tag.removeprefix("kernel/"), {"calls": 0})
+        row["calls"] += 1
+        for k, v in tiles.items():
+            row[k] = row.get(k, 0) + v
+    return dict(sorted(out.items()))
 
 
 def write_table(directory, host: int, label: str, module: str, table: dict) -> str:

@@ -15,9 +15,18 @@ saved per-row logsumexp: one grid accumulates dQ over K/V blocks, one
 accumulates dK/dV over Q blocks, both recomputing probabilities from the
 residuals instead of storing them (rematerialisation in kernel form).
 
-Causal masking skips the compute of strictly-future blocks via predicated
-execution (``pl.when``), halving the causal FLOPs — the block-level analog
-of the ring schedule masking future blocks.
+A causal (or windowed) call does the band's work and little more, at two
+granularities.  The grid skips whole (block_q x block_k) tiles outside the
+band via predicated execution (``pl.when``), the block-level analog of the
+ring schedule masking future blocks; that alone halves the causal FLOPs
+only at long T, and never engages while ``T <= block_k`` (one K block:
+every tile touches the band).  So inside a live tile each kernel walks Q
+sub-blocks and computes, for each, only the run of K sub-blocks it can
+see, in one pass, masking only the sub-blocks the band's edge crosses
+(``_visible``, ``_scores``).  With the defaults at T=1024 that is 10 of
+the square's 16 sub-tiles of 256 x 256 (62.5%), 4 of them masked;
+``flash_tile_plan`` counts it for any call, and every kernel carries its
+count into the compiled program (``obs hbm`` prints it).
 
 Layout: (B, T, H, D) public API; internally heads fold into the grid's
 leading dimension so each program works on one (head, Q-block, K-block)
@@ -35,6 +44,7 @@ smaller in HBM than a repeat-then-attend lowering.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -44,9 +54,12 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ddl_tpu.ops.interpret import interpret_default
 
-__all__ = ["flash_attention", "flash_attention_with_lse"]
+__all__ = ["flash_attention", "flash_attention_with_lse", "flash_tile_plan"]
 
 _NEG_INF = -1e30
+# default grid blocks (``flash_attention``'s docstring has the sweep)
+_BLOCK_Q = 1024
+_BLOCK_K = 1024
 
 
 def _pick_block(t: int, requested: int) -> int:
@@ -56,36 +69,290 @@ def _pick_block(t: int, requested: int) -> int:
     return max(block, 1)
 
 
-def _causal_mask(i, j, bq, bk, s, window=0, kv_offset=0):
-    """Causal (and, with ``window > 0``, sliding-window) score mask: row
-    q attends keys in ``(q - window, q]`` — ``window = 0`` means
-    unbounded history (plain causal).  ``kv_offset`` shifts the K/V
-    coordinates ``kv_offset`` positions EARLIER than the queries (the
-    ring schedule's off-diagonal hops, where the K/V block originated
-    ``hop * T_local`` positions back)."""
-    q_pos = i * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    k_pos = j * bk - kv_offset + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    keep = k_pos <= q_pos
+def _band(r0, nr, k0, nk, causal, window):
+    """The visible band against one tile, at any granularity: rows
+    ``[r0, r0 + nr)`` and key positions ``[k0, k0 + nk)`` (a grid tile, a
+    sub-tile, one (row, key) pair).  Row q attends keys in
+    ``(q - window, q]``; ``window = 0`` means unbounded history (plain
+    causal).  Returns ``(live, full)``: the tile holds a visible pair /
+    every pair of it is visible.  The one statement of the rule: the grid's
+    tile skip, the run of sub-tiles a kernel walks, which of them take a
+    mask and ``flash_tile_plan`` all ask it.  Works on Python ints, numpy
+    arrays and traced scalars alike."""
+    if not causal:
+        return True, True
+    live = k0 <= r0 + nr - 1
+    full = k0 + nk - 1 <= r0
     if window:
-        keep &= k_pos > q_pos - window
-    return jnp.where(keep, s, _NEG_INF)
+        live = live & (k0 + nk - 1 > r0 - window)
+        full = full & (k0 > r0 + nr - 1 - window)
+    return live, full
 
 
 def _qk_live(i, j, bq, bk, causal, window, kv_offset=0):
     """Whether the (q block i, k block j) tile intersects the visible band
     (the block-skip predicate; window extends causal's future-skip with a
-    past-skip; ``kv_offset`` as in ``_causal_mask``)."""
-    if not causal:
-        return True
-    live = j * bk - kv_offset <= i * bq + bq - 1
+    past-skip).  ``kv_offset`` shifts the K/V coordinates ``kv_offset``
+    positions EARLIER than the queries (the ring schedule's off-diagonal
+    hops, where the K/V block originated ``hop * T_local`` positions
+    back)."""
+    return _band(i * bq, bq, j * bk - kv_offset, bk, causal, window)[0]
+
+
+def _band_mask(r0, k0, s, window):
+    """Mask the (rows from r0) x (keys from k0) score tile ``s`` to the
+    band: the two iotas, compare and select that only a sub-tile the
+    band's edge crosses needs.  Masked scores are ``-inf`` against row
+    maxima floored at ``_NEG_INF``: their probability is exactly 0 even in
+    a row whose whole visible set is masked (possible in a live tile when
+    ``kv_offset`` or a window pushes the band off the row), so such a
+    row's output is 0 and its lse stays at the floor, not mean-of-V
+    garbage, with no second select on the probabilities."""
+    q_pos = r0 + lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    k_pos = k0 + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    keep = k_pos <= q_pos
     if window:
-        live &= j * bk + bk - 1 - kv_offset > i * bq - window
-    return live
+        keep &= k_pos > q_pos - window
+    return jnp.where(keep, s, -jnp.inf)
+
+
+# Edges of the square sub-tile a causal call's resident tile is walked in:
+# the finer one where the band is a large share of the square and its
+# branches stay few, the coarser one elsewhere (``_sub_tile``).
+_SUB_TILE = 256
+_SUB_TILE_LONG = 512
+_SHORT_T = 1024
+
+
+def _sub_tile(t, block_q, block_k, causal, window):
+    """The (rows, keys) sub-tile each kernel walks its resident
+    (block_q x block_k) tile in: the one place the rule is stated, a
+    function of what the call shows (v5e, head_dim 64, bf16; the sweep is
+    in PERF.md section 6, PR 26).
+
+    * A non-causal call has no band, and takes the whole tile: exactly
+      the pre-walk computation.
+    * A causal call takes squares.  Finer squares follow the band more
+      closely (T=1024: 62.5% of the square at 256, 75% at 512) and feed
+      the MXU shorter runs.  Up to ``_SHORT_T`` the band is most of what
+      is computed and 256 wins (forward + backward 2.73 ms against 2.87
+      at b16 h12 T1024); beyond it most tiles lie wholly inside the band,
+      and 512 wins (T=8192: 9.33 ms against 10.86).
+    * A window adds a branch for every run that starts inside the block:
+      at 256 their code outgrows what the core holds (T=8192 W=1024
+      backward: 11.5 ms against 3.8), so a windowed call takes 512.
+    """
+    if not causal:
+        return block_q, block_k
+    edge = _SUB_TILE if t <= _SHORT_T and not window else _SUB_TILE_LONG
+    return _pick_block(block_q, edge), _pick_block(block_k, edge)
+
+
+_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+
+
+@functools.lru_cache(maxsize=256)
+def _grid_runs(t, block_q, block_k, sub_q, sub_k, window, kv_offset):
+    """Every run a causal call's grid reaches, as ``(a, c_lo, c_hi,
+    diagonal)``: for each (Q block, K block) tile and each Q sub-block
+    ``a`` of it that sees any of the K block, the run ``[c_lo, c_hi)`` of
+    K sub-blocks it sees and whether the run's end takes the mask (it
+    ends inside the block, cut short by the diagonal, or the diagonal
+    crosses one of its sub-blocks).  ``_band`` on Python ints, in grid
+    order, one entry a meeting: ``flash_tile_plan`` sums them, and the
+    kernels take their branches from the distinct ones, so a run no tile
+    of this grid can reach costs no code."""
+    n = block_k // sub_k
+    runs = []
+    for i in range(t // block_q):
+        for a in range(block_q // sub_q):
+            r0 = i * block_q + a * sub_q
+            for j in range(t // block_k):
+                keys = [j * block_k - kv_offset + c * sub_k for c in range(n)]
+                lives = [_band(r0, sub_q, k, sub_k, True, window)[0] for k in keys]
+                if not any(lives):
+                    continue
+                c_lo = lives.index(True)
+                c_hi = c_lo + sum(lives)
+                crossed = any(
+                    live and not full for live, full in
+                    (_band(r0, sub_q, k, sub_k, True, 0) for k in keys)
+                )
+                runs.append((a, c_lo, c_hi, c_hi < n or crossed))
+    return tuple(runs)
+
+
+def flash_tile_plan(
+    t: int,
+    block_q: int = _BLOCK_Q,
+    block_k: int = _BLOCK_K,
+    causal: bool = False,
+    window: int = 0,
+    kv_offset: int = 0,
+) -> dict:
+    """What one (batch, head) row of a ``flash_attention`` call at these
+    arguments computes of its T x T square, per kernel: sub-tiles in the
+    square (``total``), sub-tiles the kernel computes (``computed``: those
+    ``_band`` calls live, the runs it walks) and how many of those it
+    masks (``masked``: those the band's edge crosses, and where sub-tiles
+    and band are not aligned the neighbour an edge can reach), with the
+    sub-tile's shape.  Pure arithmetic on the arguments, by the predicate
+    and the mask rule the kernels' walk uses; each ``pallas_call`` carries
+    its kernel's numbers (times its rows) as ``metadata``, which the
+    compiled step's ``hbm_plan`` record sums
+    (``obs/scope.kernel_tiles``)."""
+    bq, bk = _pick_block(t, block_q), _pick_block(t, block_k)
+    sub_q, sub_k = _sub_tile(t, bq, bk, causal, window)
+    tiles = {"total": (t // sub_q) * (t // sub_k), "computed": 0, "masked": 0}
+    if not causal:
+        tiles["computed"] = tiles["total"]
+    else:
+        reach = _edge_reach(sub_q, sub_k, bq, bk, kv_offset, window)
+        for _, c_lo, c_hi, diagonal in _grid_runs(
+            t, bq, bk, sub_q, sub_k, window, kv_offset
+        ):
+            tiles["computed"] += c_hi - c_lo
+            tiles["masked"] += len(_masked(c_hi - c_lo, reach, diagonal))
+    # the three kernels walk the same sub-tiles today; the record is per
+    # kernel so that a kernel with a walk of its own can say so
+    return {"sub_tile": [sub_q, sub_k], **{name: dict(tiles) for name in _KERNELS}}
+
+
+def _walk(name_rows, t, block_q, block_k, causal, window, kv_offset):
+    """What a launcher hands its ``pallas_call``s for one call's band:
+    the kernels' static walk arguments, and for each ``(kernel name,
+    grid rows)`` its ``metadata=``, the tile plan over those rows as
+    strings."""
+    sub_q, sub_k = _sub_tile(t, block_q, block_k, causal, window)
+    walk = dict(sub_q=sub_q, sub_k=sub_k, runs=None, reach=(0, 0))
+    if causal:
+        walk.update(
+            runs=_grid_runs(t, block_q, block_k, sub_q, sub_k, window, kv_offset),
+            reach=_edge_reach(sub_q, sub_k, block_q, block_k, kv_offset, window),
+        )
+    plan = flash_tile_plan(t, block_q, block_k, causal, window, kv_offset)
+    metadata = {
+        name: {f"tiles_{key}": str(rows * n) for key, n in plan[name].items()}
+        for name, rows in name_rows
+    }
+    return walk, metadata
+
+
+def _run(flags):
+    """``(first, end)`` of the single run of true flags in a short static
+    list of traced scalars (``(n, n)`` when none is true)."""
+    first = n_true = 0
+    seen = False
+    for f in flags:
+        seen = seen | f
+        first = first + jnp.where(seen, 0, 1)
+        n_true = n_true + jnp.where(f, 1, 0)
+    return first, first + n_true
+
+
+def _visible_run(r0, sub_q, k0, sub_k, n, window):
+    """Which of the resident K block's ``n`` sub-blocks rows
+    ``[r0, r0 + sub_q)`` of a causal call see: ``(lo, hi, future)``, the
+    one run ``[lo, hi)`` of sub-blocks that hold a visible pair, and
+    whether the band's future edge (the diagonal) crosses any of them.
+    Counted from ``_band`` on program-id scalars."""
+    bands = [_band(r0, sub_q, k0 + c * sub_k, sub_k, True, 0)
+             for c in range(n)]
+    future = functools.reduce(
+        lambda x, y: x | y, [live & ~full for live, full in bands]
+    )
+    if window:
+        bands = [_band(r0, sub_q, k0 + c * sub_k, sub_k, True, window)
+                 for c in range(n)]
+    lo, hi = _run([live for live, _ in bands])
+    return lo, hi, future
+
+
+def _edge_reach(sub_q, sub_k, block_q, block_k, kv_offset, window):
+    """``(past, future)``: the most sub-blocks either edge of the band
+    crosses in one Q sub-block's run, from how row and key sub-blocks can
+    be aligned (their starts differ by ``kv_offset`` plus multiples of
+    what divides every block): 1 for the diagonal of aligned squares, 2
+    for a window that is no multiple of the sub-tile."""
+    g = math.gcd(sub_q, block_q, block_k)
+    phases = {(kv_offset + m * g) % sub_k for m in range(sub_k // math.gcd(g, sub_k))}
+
+    def crossed(shift):  # the edge ``k == q - shift`` against rows [0, sub_q)
+        return max(
+            sum(
+                1 for c in range(-2 - shift // sub_k, sub_q // sub_k + 2)
+                if c * sub_k - phase + shift <= sub_q - 1
+                and c * sub_k - phase + shift + sub_k - 1 > 0
+            )
+            for phase in phases
+        )
+
+    # the past edge is the diagonal moved ``window`` keys back
+    return (crossed(window) if window else 0), crossed(0)
+
+
+def _masked(n_run, reach, diagonal):
+    """Which of a run's ``n_run`` sub-blocks take the mask: the first
+    ``reach[0]`` (the past edge; none without a window) and, where the
+    diagonal crosses the run, the last ``reach[1]``."""
+    past, future = reach
+    return sorted(
+        set(range(min(past, n_run)))
+        | (set(range(max(n_run - future, 0), n_run)) if diagonal else set())
+    )
+
+
+def _visible(step, branches, r0, sub_q, k0, sub_k, n, window):
+    """Run ``step(c_lo, c_hi, diagonal)`` once, on the run ``[c_lo, c_hi)``
+    of the resident K block's sub-blocks that rows ``[r0, r0 + sub_q)``
+    can see, in ONE pass over the whole run (a K step costs the forward
+    more in per-row bookkeeping than in area: PERF.md section 6, PR 26),
+    and not at all when they see none.  The run's bounds are program-id
+    arithmetic and a score tile's shape is static, so each run in
+    ``branches`` (the distinct runs this Q sub-block meets anywhere in
+    the grid, ``_grid_runs``) is its own ``pl.when`` branch, of which at
+    most one executes.  ``diagonal`` says whether the run's end takes the
+    mask: always when it ends inside the block (what cut it short is the
+    diagonal), and by ``_visible_run`` when it reaches the block's end (a
+    block wholly in the past has a branch without).  ``branches=None``: a
+    non-causal call, which has no band and takes the whole tile,
+    unmasked: the pre-walk computation."""
+    if branches is None:
+        return step(0, n, False)
+    lo, hi, future = _visible_run(r0, sub_q, k0, sub_k, n, window)
+    diagonal = (hi < n) | future
+    for c_lo, c_hi, masked_end in branches:
+        pl.when(
+            (lo == c_lo) & (hi == c_hi)
+            & (diagonal if masked_end else ~diagonal)
+        )(functools.partial(step, c_lo, c_hi, masked_end))
+
+
+def _branches(runs, a):
+    """Q sub-block ``a``'s distinct runs out of ``_grid_runs``' (None
+    stays None: a non-causal call)."""
+    if runs is None:
+        return None
+    return sorted({run[1:] for run in runs if run[0] == a})
+
+
+def _scores(q, k_blk, r0, k_first, sub_k, masked, window):
+    """``q k^T`` over a run of K sub-blocks (keys from ``k_first``), with
+    the band's mask on the sub-blocks ``masked`` names and on no other."""
+    s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32)
+    if not masked:
+        return s
+    parts = [
+        s[:, e * sub_k:(e + 1) * sub_k] for e in range(s.shape[1] // sub_k)
+    ]
+    for e in masked:
+        parts[e] = _band_mask(r0, k_first + e * sub_k, parts[e], window)
+    return jnp.concatenate(parts, axis=1) if len(parts) > 1 else parts[0]
 
 
 def _fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, acc_sc, m_sc, l_sc, *, scale,
-    causal, window=0, kv_offset=0,
+    causal, window=0, kv_offset=0, sub_q, sub_k, runs, reach,
 ):
     i, j = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
@@ -103,27 +370,31 @@ def _fwd_kernel(
 
     @pl.when(live)
     def _():
-        q = q_ref[0].astype(jnp.float32) * scale
-        k_blk = k_ref[0].astype(jnp.float32)
-        v_blk = v_ref[0].astype(jnp.float32)
-        s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32)
-        if causal:
-            s = _causal_mask(i, j, bq, bk, s, window, kv_offset)
-        m = m_sc[:]
-        blk_max = s.max(axis=-1, keepdims=True)
-        new_m = jnp.maximum(m, blk_max)
-        p = jnp.exp(s - new_m)
-        # rows whose whole visible set is masked (possible in a live tile
-        # when kv_offset pushes the band off the row): new_m == mask value
-        # makes p = exp(0) = 1 — zero those entries so the row's output is
-        # 0 and its lse stays at the -inf floor, not mean-of-V garbage
-        p = jnp.where(s > _NEG_INF / 2, p, 0.0)
-        corr = jnp.exp(m - new_m)
-        l_sc[:] = l_sc[:] * corr + p.sum(axis=-1, keepdims=True)
-        acc_sc[:] = acc_sc[:] * corr + jnp.dot(
-            p, v_blk, preferred_element_type=jnp.float32
-        )
-        m_sc[:] = new_m
+        k0 = j * bk - kv_offset
+        for a in range(bq // sub_q):  # each Q sub-block has its own band
+            rows = slice(a * sub_q, (a + 1) * sub_q)
+            r0 = i * bq + a * sub_q
+
+            def step(c_lo, c_hi, diagonal, rows=rows, r0=r0):
+                keys = slice(c_lo * sub_k, c_hi * sub_k)
+                masked = _masked(c_hi - c_lo, reach, diagonal)
+                q = q_ref[0, rows, :].astype(jnp.float32) * scale
+                k_blk = k_ref[0, keys, :].astype(jnp.float32)
+                v_blk = v_ref[0, keys, :].astype(jnp.float32)
+                s = _scores(q, k_blk, r0, k0 + c_lo * sub_k, sub_k, masked,
+                            window)
+                m = m_sc[rows]
+                new_m = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+                p = jnp.exp(s - new_m)
+                corr = jnp.exp(m - new_m)
+                l_sc[rows] = l_sc[rows] * corr + p.sum(axis=-1, keepdims=True)
+                acc_sc[rows] = acc_sc[rows] * corr + jnp.dot(
+                    p, v_blk, preferred_element_type=jnp.float32
+                )
+                m_sc[rows] = new_m
+
+            _visible(step, _branches(runs, a), r0, sub_q, k0, sub_k,
+                     bk // sub_k, window)
 
     @pl.when(j == nk - 1)
     def _():
@@ -133,8 +404,8 @@ def _fwd_kernel(
 
 
 def _dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_sc, *, scale,
-    causal, window=0, kv_offset=0,
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_sc, *,
+    scale, causal, window=0, kv_offset=0, sub_q, sub_k, runs, reach,
 ):
     i, j = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
@@ -149,22 +420,29 @@ def _dq_kernel(
 
     @pl.when(live)
     def _():
-        q = q_ref[0].astype(jnp.float32) * scale
-        k_blk = k_ref[0].astype(jnp.float32)
-        v_blk = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0, 0][:, None]
-        delta = delta_ref[0, 0][:, None]
-        s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32)
-        if causal:
-            s = _causal_mask(i, j, bq, bk, s, window, kv_offset)
-        p = jnp.exp(s - lse)
-        p = jnp.where(s > _NEG_INF / 2, p, 0.0)  # empty-band rows (fwd note)
-        dp = jnp.dot(do, v_blk.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        dq_sc[:] = dq_sc[:] + jnp.dot(
-            ds, k_blk, preferred_element_type=jnp.float32
-        )
+        k0 = j * bk - kv_offset
+        for a in range(bq // sub_q):
+            rows = slice(a * sub_q, (a + 1) * sub_q)
+            r0 = i * bq + a * sub_q
+
+            def step(c_lo, c_hi, diagonal, rows=rows, r0=r0):
+                keys = slice(c_lo * sub_k, c_hi * sub_k)
+                masked = _masked(c_hi - c_lo, reach, diagonal)
+                q = q_ref[0, rows, :].astype(jnp.float32) * scale
+                k_blk = k_ref[0, keys, :].astype(jnp.float32)
+                v_blk = v_ref[0, keys, :].astype(jnp.float32)
+                do = do_ref[0, rows, :].astype(jnp.float32)
+                s = _scores(q, k_blk, r0, k0 + c_lo * sub_k, sub_k, masked,
+                            window)
+                p = jnp.exp(s - lse_ref[0, 0, rows][:, None])
+                dp = jnp.dot(do, v_blk.T, preferred_element_type=jnp.float32)
+                ds = p * (dp - delta_ref[0, 0, rows][:, None])
+                dq_sc[rows] += jnp.dot(
+                    ds, k_blk, preferred_element_type=jnp.float32
+                )
+
+            _visible(step, _branches(runs, a), r0, sub_q, k0, sub_k,
+                     bk // sub_k, window)
 
     @pl.when(j == nk - 1)
     def _():
@@ -174,6 +452,7 @@ def _dq_kernel(
 def _dkdv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
     dk_sc, dv_sc, *, scale, causal, window=0, kv_offset=0, q_blocks=1,
+    sub_q, sub_k, runs, reach,
 ):
     # grid: (b*kv_heads, k_blocks, group*q_blocks) — the innermost
     # dimension walks every (query head in the group, Q block) pair, so
@@ -194,32 +473,42 @@ def _dkdv_kernel(
 
     @pl.when(live)
     def _():
-        q_blk = q_ref[0].astype(jnp.float32) * scale
-        k_blk = k_ref[0].astype(jnp.float32)
-        v_blk = v_ref[0].astype(jnp.float32)
-        do_blk = do_ref[0].astype(jnp.float32)
-        lse_blk = lse_ref[0, 0][:, None]
-        delta_blk = delta_ref[0, 0][:, None]
-        s = jnp.dot(q_blk, k_blk.T, preferred_element_type=jnp.float32)
-        if causal:
-            s = _causal_mask(i, j, bq, bk, s, window, kv_offset)
-        p = jnp.exp(s - lse_blk)
-        p = jnp.where(s > _NEG_INF / 2, p, 0.0)  # empty-band rows (fwd note)
-        dv_sc[:] = dv_sc[:] + jnp.dot(
-            p.T, do_blk, preferred_element_type=jnp.float32
-        )
-        dp = jnp.dot(do_blk, v_blk.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_blk)
-        dk_sc[:] = dk_sc[:] + jnp.dot(
-            ds.T, q_blk, preferred_element_type=jnp.float32
-        )
+        k0 = j * bk - kv_offset
+        # the same walk as the forward and dQ (the K sub-blocks each Q
+        # sub-block sees), so one bound serves all three kernels; dK/dV
+        # accumulate in their scratch, the run's rows at a time
+        for a in range(bq // sub_q):
+            rows = slice(a * sub_q, (a + 1) * sub_q)
+            r0 = i * bq + a * sub_q
+
+            def step(c_lo, c_hi, diagonal, rows=rows, r0=r0):
+                keys = slice(c_lo * sub_k, c_hi * sub_k)
+                masked = _masked(c_hi - c_lo, reach, diagonal)
+                q_blk = q_ref[0, rows, :].astype(jnp.float32) * scale
+                k_blk = k_ref[0, keys, :].astype(jnp.float32)
+                v_blk = v_ref[0, keys, :].astype(jnp.float32)
+                do_blk = do_ref[0, rows, :].astype(jnp.float32)
+                s = _scores(q_blk, k_blk, r0, k0 + c_lo * sub_k, sub_k,
+                            masked, window)
+                p = jnp.exp(s - lse_ref[0, 0, rows][:, None])
+                dv_sc[keys, :] += jnp.dot(
+                    p.T, do_blk, preferred_element_type=jnp.float32
+                )
+                dp = jnp.dot(
+                    do_blk, v_blk.T, preferred_element_type=jnp.float32
+                )
+                ds = p * (dp - delta_ref[0, 0, rows][:, None])
+                dk_sc[keys, :] += jnp.dot(
+                    ds.T, q_blk, preferred_element_type=jnp.float32
+                )
+
+            _visible(step, _branches(runs, a), r0, sub_q, k0, sub_k,
+                     bk // sub_k, window)
 
     @pl.when(iz == nz - 1)
     def _():
         dk_ref[0] = dk_sc[:].astype(dk_ref.dtype)  # scale folded into q_blk
         dv_ref[0] = dv_sc[:].astype(dv_ref.dtype)
-
-
 
 
 def _kv_row(b, q_heads, kv_heads):
@@ -229,6 +518,11 @@ def _kv_row(b, q_heads, kv_heads):
     return (b // q_heads) * kv_heads + (b % q_heads) // g
 
 
+# The two launchers are jitted so that a program with many attention layers
+# traces and lowers each kernel once, not once a layer: the walk's branches
+# are several times the pre-walk body, and a 12-layer step paid for them 36
+# times at every start, cached executable or not (PERF.md section 6, PR 26).
+@functools.partial(jax.jit, static_argnums=tuple(range(3, 11)))
 def _flash_fwd_impl(
     q, k, v, causal, window, kv_offset, block_q, block_k, interpret,
     q_heads, kv_heads,
@@ -236,9 +530,12 @@ def _flash_fwd_impl(
     bh, t, d = q.shape
     scale = 1.0 / (d ** 0.5)
     kv_idx = lambda b, i, j: (_kv_row(b, q_heads, kv_heads), j, 0)
+    walk, metadata = _walk(
+        [("flash_fwd", bh)], t, block_q, block_k, causal, window, kv_offset
+    )
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                          window=window, kv_offset=kv_offset),
+                          window=window, kv_offset=kv_offset, **walk),
         out_shape=(
             jax.ShapeDtypeStruct((bh, t, d), q.dtype),
             # row stats ride in a (bh, 1, t) layout: the (1, 1, block_q)
@@ -263,10 +560,12 @@ def _flash_fwd_impl(
         ],
         interpret=interpret,
         name="flash_fwd",
+        metadata=metadata["flash_fwd"],
     )(q, k, v)
     return out, lse
 
 
+@functools.partial(jax.jit, static_argnums=tuple(range(7, 15)))
 def _flash_bwd_kernels(q, k, v, out, lse, do, dlse, causal, window,
                        kv_offset, block_q, block_k, interpret, q_heads,
                        kv_heads):
@@ -299,10 +598,14 @@ def _flash_bwd_kernels(q, k, v, out, lse, do, dlse, causal, window,
     q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
     kv_spec = pl.BlockSpec((1, block_k, d), kv_idx)
     row_spec = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i))
+    walk, metadata = _walk(
+        [("flash_bwd_dq", bh), ("flash_bwd_dkv", bh)], t, block_q, block_k,
+        causal, window, kv_offset,
+    )
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          window=window, kv_offset=kv_offset),
+                          window=window, kv_offset=kv_offset, **walk),
         out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
         grid=(bh, t // block_q, t // block_k),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
@@ -310,6 +613,7 @@ def _flash_bwd_kernels(q, k, v, out, lse, do, dlse, causal, window,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dq",
+        metadata=metadata["flash_bwd_dq"],
     )(q, k, v, do, lse, delta)
 
     # grid (bkv, k_blocks, g * q_blocks): outermost at K/V-head
@@ -329,7 +633,7 @@ def _flash_bwd_kernels(q, k, v, out, lse, do, dlse, causal, window,
     dk, dv = pl.pallas_call(
         functools.partial(
             _dkdv_kernel, scale=scale, causal=causal, window=window,
-            kv_offset=kv_offset, q_blocks=nq,
+            kv_offset=kv_offset, q_blocks=nq, **walk,
         ),
         out_shape=(
             jax.ShapeDtypeStruct((bkv, t, d), k.dtype),
@@ -344,6 +648,7 @@ def _flash_bwd_kernels(q, k, v, out, lse, do, dlse, causal, window,
         ],
         interpret=interpret,
         name="flash_bwd_dkv",
+        metadata=metadata["flash_bwd_dkv"],
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -415,8 +720,8 @@ def flash_attention(
     v,
     causal: bool = False,
     window: int = 0,
-    block_q: int = 512,
-    block_k: int = 1024,
+    block_q: int = _BLOCK_Q,
+    block_k: int = _BLOCK_K,
     interpret: bool | None = None,
     kv_offset: int = 0,
 ):
@@ -433,12 +738,18 @@ def flash_attention(
 
     Differentiable (custom VJP, flash backward).  Block sizes are clamped to
     the sequence length and halved until they divide it; pick powers of two.
-    Defaults (512x1024) come from a v5e device-only sweep
-    (``bench/kernels.py`` slope method; B=2, H=8, D=64, causal, bf16):
-    ``block_k=1024`` beats 512 in both directions at every measured T —
-    fwd 2.59 vs 4.14 ms and bwd 10.9 vs 13.3 at T=8192 (dense lowering:
-    8.77 / 28.7) — and also with a sliding window (W=1024: fwd 1.32 vs
-    1.46, bwd 7.01 vs 7.97), while keeping the T^2 score tile out of HBM.
+    Defaults (1024x1024) come from a v5e device-only sweep of these
+    kernels (PR 26's own, JAX 0.9.0; ``bench/kernels.py`` slope method;
+    D=64, causal, bf16; forward / forward+backward ms a call).  At B=16,
+    H=12, T=1024 (the benchmark cell's shape) ``block_q=1024`` gives 0.92 /
+    2.73 against 1.20 / 3.36 at 512: one grid step a head where there were
+    two (the pre-walk kernels gained the same in the forward, 0.85 against
+    1.16).  At B=2, H=8, T=8192 it gives 2.41 / 9.33 against 2.48 / 10.25,
+    and with a window of 1024 1.19 / 4.97 (pre-walk, 512x1024: 1.32 /
+    7.03); ``block_q=2048`` loses (16.3).  ``block_k`` was not swept
+    again: the older sweep that chose 1024 (PERF_HISTORY.md) found 512
+    slower at every T, and a K step costs what it did.  The T^2 score
+    tile stays out of HBM either way.
     ``interpret=None`` interprets on the CPU backend (tests on the
     simulated mesh) and compiles on a TPU (``ops/interpret.py``).
     """
@@ -463,8 +774,8 @@ def flash_attention_with_lse(
     v,
     causal: bool = False,
     window: int = 0,
-    block_q: int = 512,
-    block_k: int = 1024,
+    block_q: int = _BLOCK_Q,
+    block_k: int = _BLOCK_K,
     interpret: bool | None = None,
     kv_offset: int = 0,
 ):

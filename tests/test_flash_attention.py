@@ -3,6 +3,8 @@
 Runs in interpreter mode on CPU; the identical kernel compiles on TPU.
 """
 
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,6 +12,33 @@ import pytest
 
 from ddl_tpu.ops.attention import dense_attention
 from ddl_tpu.ops.flash_attention import flash_attention
+
+# the module itself: ``ddl_tpu.ops`` re-exports the function under its name
+fa = sys.modules["ddl_tpu.ops.flash_attention"]
+
+# (block_q, block_k, sub-tile edge): None leaves the kernel's own rule, under
+# which a tile this small is one sub-tile; an edge walks the resident tile in
+# edge x edge sub-tiles.  CELL is the benchmark cell's shape class (T ==
+# block_q == block_k, 4 x 4 sub-tiles in the one tile) scaled down by 16,
+# TWO_Q the same with two Q blocks (the default before PR 26).
+CELL = (64, 64, 16)
+TWO_Q = (32, 64, 16)
+
+
+def _set_edge(monkeypatch, edge):
+    monkeypatch.setattr(fa, "_SUB_TILE", edge)
+    monkeypatch.setattr(fa, "_SUB_TILE_LONG", edge)
+
+
+@pytest.fixture
+def blocks(request, monkeypatch):
+    """Block arguments of one case; sets the sub-tile edge it asks for."""
+    if isinstance(request.param, int):
+        return dict(block_q=request.param, block_k=request.param)
+    bq, bk, edge = request.param
+    if edge is not None:
+        _set_edge(monkeypatch, edge)
+    return dict(block_q=bq, block_k=bk)
 
 
 @pytest.fixture(scope="module")
@@ -22,22 +51,27 @@ def qkv():
 
 
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("block", [16, 32, 64])
-def test_flash_matches_dense_forward(qkv, causal, block):
+@pytest.mark.parametrize(
+    "blocks", [16, 32, 64, CELL, TWO_Q, (32, 64, 8), (64, 32, 16)], indirect=True
+)
+def test_flash_matches_dense_forward(qkv, causal, blocks):
     q, k, v = qkv
-    out = flash_attention(q, k, v, causal=causal, block_q=block, block_k=block)
+    out = flash_attention(q, k, v, causal=causal, **blocks)
     want = dense_attention(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5, rtol=1e-4)
 
 
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_matches_dense_grads(qkv, causal):
+@pytest.mark.parametrize(
+    "blocks", [(16, 32, None), CELL, TWO_Q, (64, 32, 8)], indirect=True
+)
+def test_flash_matches_dense_grads(qkv, causal, blocks):
     q, k, v = qkv
     rng = np.random.default_rng(1)
     cot = jnp.asarray(rng.normal(size=q.shape), jnp.float32)
 
     def loss_flash(q, k, v):
-        return (flash_attention(q, k, v, causal=causal, block_q=16, block_k=32) * cot).sum()
+        return (flash_attention(q, k, v, causal=causal, **blocks) * cot).sum()
 
     def loss_dense(q, k, v):
         return (dense_attention(q, k, v, causal=causal) * cot).sum()
@@ -319,10 +353,12 @@ def test_lm_ring_flash_matches_dense():
     assert err < 1e-4
 
 
-def test_flash_gqa_matches_dense_and_repeated():
+@pytest.mark.parametrize("blocks", [32, (64, 128, 32)], indirect=True)
+def test_flash_gqa_matches_dense_and_repeated(blocks):
     """Grouped K/V through the Pallas kernel: forward equals the grouped
     dense core; gradients equal the repeat-then-attend formulation with
-    dK/dV accumulated over the query-head group at Hkv granularity."""
+    dK/dV accumulated over the query-head group at Hkv granularity (the
+    second case walks sub-tiles inside dK/dV's group walk)."""
     from ddl_tpu.ops.attention import dense_attention
 
     rng = np.random.default_rng(12)
@@ -332,9 +368,7 @@ def test_flash_gqa_matches_dense_and_repeated():
     k = jnp.asarray(rng.normal(size=(b, t, hkv, d)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(b, t, hkv, d)), jnp.float32)
     for window in (0, 32):
-        out = flash_attention(
-            q, k, v, causal=True, window=window, block_q=32, block_k=32
-        )
+        out = flash_attention(q, k, v, causal=True, window=window, **blocks)
         ref = dense_attention(q, k, v, causal=True, window=window)
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref), atol=2e-5, rtol=1e-4
@@ -342,7 +376,7 @@ def test_flash_gqa_matches_dense_and_repeated():
 
         def loss(a, bb, c):
             return flash_attention(
-                a, bb, c, causal=True, window=window, block_q=32, block_k=32
+                a, bb, c, causal=True, window=window, **blocks
             ).astype(jnp.float32).sum()
 
         gq, gk, gv = jax.grad(loss, (0, 1, 2))(q, k, v)
@@ -381,10 +415,13 @@ def test_flash_rejects_bad_kv_heads():
         flash_attention(q, k, k, causal=True)
 
 
-def test_flash_kv_offset_empty_band_rows_are_zero():
+@pytest.mark.parametrize("blocks", [8, (16, 32, 8), (16, 16, 8)], indirect=True)
+def test_flash_kv_offset_empty_band_rows_are_zero(blocks):
     """With kv_offset a live tile can hold rows whose whole band is masked;
     those rows must output exactly zero (and a floor lse), not mean-of-V
-    garbage (round-3 review finding)."""
+    garbage (round-3 review finding).  The later cases hold such rows
+    inside a walked tile: in an edge sub-tile, and in sub-tiles the walk
+    never visits."""
     from ddl_tpu.ops.flash_attention import flash_attention_with_lse
 
     rng = np.random.default_rng(3)
@@ -396,7 +433,7 @@ def test_flash_kv_offset_empty_band_rows_are_zero():
     # offset t, window 8: row q sees k_loc > q + t - 8, so rows >= 7
     # see nothing in this block (empty band inside a live tile)
     out, lse = flash_attention_with_lse(
-        q, k, v, causal=True, window=8, kv_offset=t, block_q=8, block_k=8
+        q, k, v, causal=True, window=8, kv_offset=t, **blocks
     )
     np.testing.assert_array_equal(np.asarray(out[:, 7:]), 0.0)
     assert np.all(np.asarray(lse[:, :, 7:]) < -1e29)
@@ -412,9 +449,112 @@ def test_flash_kv_offset_empty_band_rows_are_zero():
     # backward stays finite and zero for the empty rows
     g = jax.grad(
         lambda x: flash_attention_with_lse(
-            x, k, v, causal=True, window=8, kv_offset=t,
-            block_q=8, block_k=8,
+            x, k, v, causal=True, window=8, kv_offset=t, **blocks
         )[0].sum()
     )(q)
     assert bool(jnp.isfinite(g).all())
     np.testing.assert_array_equal(np.asarray(g[:, 7:]), 0.0)
+
+
+def _brute_plan(t, sub_q, sub_k, causal, window, kv_offset):
+    """Sub-tile counts from ``_qk_live`` over single (row, key) pairs."""
+    rows, keys = np.arange(t)[:, None], np.arange(t)[None, :]
+    pair = np.broadcast_to(
+        fa._qk_live(rows, keys, 1, 1, causal, window, kv_offset), (t, t)
+    )
+    tiles = pair.reshape(t // sub_q, sub_q, t // sub_k, sub_k)
+    live, full = tiles.any(axis=(1, 3)), tiles.all(axis=(1, 3))
+    return {
+        "total": live.size, "computed": int(live.sum()),
+        "masked": int((live & ~full).sum()),
+    }
+
+
+@pytest.mark.parametrize(
+    "t,bq,bk,edge,causal,window,kv_offset",
+    [
+        (1024, 1024, 1024, 256, True, 0, 0),    # the benchmark cell
+        (1024, 512, 1024, 256, True, 0, 0),
+        (1024, 512, 1024, 256, False, 0, 0),
+        (2048, 512, 1024, 256, True, 0, 0),
+        (2048, 512, 1024, 256, True, 300, 0),   # both edges, off the boundaries
+        (1024, 512, 1024, 256, True, 256, 0),   # the past edge on a boundary
+        (1024, 512, 1024, 256, True, 257, 0),
+        (1024, 512, 1024, 256, True, 255, 0),
+        (512, 512, 512, 128, True, 200, 512),   # a ring hop: offset, empty rows
+        (512, 512, 512, 128, True, 0, 1024),    # an old hop: wholly visible
+        (64, 32, 64, 16, True, 0, 0),
+        (64, 32, 64, 16, True, 17, 0),
+        (64, 32, 64, 16, True, 31, 0),          # three edges and nothing between
+        (64, 32, 64, 16, True, 45, 0),
+        (64, 64, 64, 16, True, 31, 64),
+        (48, 512, 1024, 256, True, 0, 0),       # blocks clamp to T: one sub-tile
+    ],
+)
+def test_flash_tile_plan_is_the_brute_force_count_and_the_kernels_walk(
+    monkeypatch, t, bq, bk, edge, causal, window, kv_offset
+):
+    """``flash_tile_plan`` equals a count of ``_qk_live`` over element
+    pairs (``masked`` may add the neighbour an unaligned edge can reach),
+    and the kernels' walk (``_visible_run``, ``_masked``: run here outside
+    a kernel on the same scalars) visits exactly the sub-tiles that hold a
+    visible pair and masks every one the band's edge crosses."""
+    _set_edge(monkeypatch, edge)
+    plan = fa.flash_tile_plan(t, bq, bk, causal, window, kv_offset)
+    bq, bk = fa._pick_block(t, bq), fa._pick_block(t, bk)
+    sub_q, sub_k = plan["sub_tile"]
+    assert (sub_q, sub_k) == fa._sub_tile(t, bq, bk, causal, window)
+    want = _brute_plan(t, sub_q, sub_k, causal, window, kv_offset)
+    aligned = not window and kv_offset % sub_k == 0 and sub_q == sub_k
+    for name in fa._KERNELS:
+        got = plan[name]
+        assert (got["total"], got["computed"]) == (want["total"], want["computed"])
+        assert want["masked"] <= got["masked"] <= got["computed"]
+        assert got["masked"] == want["masked"] or not aligned
+    if not causal:
+        return  # no band, no walk: whole tiles (test_..._engages below)
+
+    n = bk // sub_k
+    reach = fa._edge_reach(sub_q, sub_k, bq, bk, kv_offset, window)
+    run = jax.jit(
+        lambda r0, k0: fa._visible_run(r0, sub_q, k0, sub_k, n, window)
+    )
+    runs = fa._grid_runs(t, bq, bk, sub_q, sub_k, window, kv_offset)
+    visited = masked = 0
+    for r0 in range(0, t, sub_q):
+        for j in range(t // bk):
+            k0 = j * bk - kv_offset
+            lo, hi, future = map(int, run(jnp.int32(r0), jnp.int32(k0)))
+            # the branch _visible selects exists: the kernel has code for
+            # every run its grid reaches
+            branches = fa._branches(runs, r0 % bq // sub_q)
+            assert hi == lo or (lo, hi, hi < n or bool(future)) in branches
+            bands = [
+                fa._band(r0, sub_q, k0 + c * sub_k, sub_k, True, window)
+                for c in range(n)
+            ]
+            assert set(range(lo, hi)) == {c for c, (live, _) in enumerate(bands) if live}
+            # what _visible hands its step: the mask at the run's end
+            # always when the run stops inside the block
+            cols = {lo + e for e in fa._masked(hi - lo, reach, hi < n or bool(future))}
+            edges = {c for c, (live, full) in enumerate(bands) if live and not full}
+            assert edges <= cols and (cols == edges or not aligned)
+            visited, masked = visited + hi - lo, masked + len(cols)
+    assert (visited, masked) == (plan["flash_fwd"]["computed"], plan["flash_fwd"]["masked"])
+
+
+def test_flash_tile_plan_engages_in_the_benchmark_cell():
+    """Causal, T == block_k (the defaults at T=1024): the grid's tile skip
+    never fires there, the sub-tile walk must; and a non-causal call
+    computes the whole square unmasked, in whole tiles.  The rule's other
+    two arms: a long T and a window walk coarser squares."""
+    plan = fa.flash_tile_plan(1024, causal=True)
+    assert plan["sub_tile"] == [256, 256]
+    for name in fa._KERNELS:
+        assert plan[name] == {"total": 16, "computed": 10, "masked": 4}
+        assert plan[name]["computed"] <= 0.75 * plan[name]["total"]
+    full = fa.flash_tile_plan(1024, causal=False)
+    assert full["sub_tile"] == [1024, 1024]
+    assert full["flash_fwd"] == {"total": 1, "computed": 1, "masked": 0}
+    assert fa.flash_tile_plan(8192, causal=True)["sub_tile"] == [512, 512]
+    assert fa.flash_tile_plan(1024, causal=True, window=256)["sub_tile"] == [512, 512]

@@ -244,8 +244,10 @@ def test_collate_hook_runs_on_the_producer_thread():
 
 # An optimized module as the TPU compiler prints it, cut to what the
 # reducer reads: a fused computation before ENTRY, a forward fusion, a
-# tuple-typed Pallas custom call (named and not), a backward fusion, a
-# prefetch without metadata whose consumer is forward, and the update.
+# tuple-typed Pallas custom call (named and not; the first with the
+# ``metadata`` a kernel may carry, which the compiler prints as JSON over
+# several unindented lines), a backward fusion, a prefetch without
+# metadata whose consumer is forward, and the update.
 _HLO = '''HloModule jit_train_step, is_scheduled=true
 
 %fused_computation.1 (param_0: bf16[8,128]) -> bf16[8,128] {
@@ -259,7 +261,11 @@ ENTRY %main.7 (p.1: f32[128,128], x.1: bf16[8,128]) -> (f32[128,128], f32[]) {
   %copy-start.3 = (f32[128,128]{1,0:T(8,128)S(1)}, f32[128,128]{1,0:T(8,128)}, u32[]{:S(2)}) copy-start(%p.1)
   %copy-done.3 = f32[128,128]{1,0:T(8,128)S(1)} copy-done(%copy-start.3)
   %fusion.12 = bf16[8,128]{1,0:T(8,128)(2,1)S(1)} fusion(%x.1, %copy-done.3), kind=kOutput, calls=%fused_computation.1, metadata={op_name="jit(train_step)/jvp()/dot_general" stack_frame_id=7}
-  %jvp_flash_fwd_.2 = (bf16[8,128]{1,0:T(8,128)(2,1)S(1)}, f32[8,1]{1,0:T(1,128)}) custom-call(%fusion.12), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_step)/jvp(flash_fwd)/pallas_call" stack_frame_id=37}, backend_config={"custom_call_config":{"body":"TUzvUgFN(%notanoperand)"}}
+  %jvp_flash_fwd_.2 = (bf16[8,128]{1,0:T(8,128)(2,1)S(1)}, f32[8,1]{1,0:T(1,128)}) custom-call(%fusion.12), custom_call_target="tpu_custom_call", frontend_attributes={kernel_metadata={
+"tiles_computed":"1920",
+"tiles_masked":"768",
+"tiles_total":"3072"
+}}, metadata={op_name="jit(train_step)/jvp(flash_fwd)/pallas_call" stack_frame_id=37}, backend_config={"custom_call_config":{"body":"TUzvUgFN(%notanoperand)"}}
   %get-tuple-element.4 = bf16[8,128]{1,0:T(8,128)(2,1)S(1)} get-tuple-element(%jvp_flash_fwd_.2), index=0
   %attn.45 = (bf16[8,128]{1,0:T(8,128)(2,1)}, bf16[8,128]{1,0:T(8,128)(2,1)}) custom-call(%get-tuple-element.4), custom_call_target="tpu_custom_call", metadata={op_name="jit(train_step)/transpose(jvp(jit(_flash_lse)))/pallas_call" stack_frame_id=35}
   %get-tuple-element.5 = bf16[8,128]{1,0:T(8,128)(2,1)} get-tuple-element(%attn.45), index=0
@@ -295,6 +301,21 @@ def test_scope_table_on_a_recorded_text():
         "bwd": 2, "fwd": 3, "kernel/_flash_lse": 1, "kernel/flash_bwd_dkv": 1,
         "kernel/flash_fwd": 1, "update": 2}
     assert scope_table("no entry here") == {}
+
+
+def test_kernel_tiles_on_a_recorded_text():
+    """The kernels' own account of their sub-tiles, summed by kernel; a
+    kernel without metadata, and a text without kernels, give nothing."""
+    from ddl_tpu.obs.scope import kernel_tiles
+
+    one = {"calls": 1, "computed": 1920, "masked": 768, "total": 3072}
+    assert kernel_tiles(_HLO) == {"flash_fwd": one}
+    again = _HLO.replace("%jvp_flash_fwd_.2 = ", "%jvp_flash_fwd_.3 = ")
+    entry = _HLO.index("ENTRY")
+    kernel = again[again.index("  %jvp_flash_fwd_.3"):again.index("  %get-tuple-element.4")]
+    twice = _HLO[:entry] + _HLO[entry:].replace("  %get-tuple-element.4", kernel + "  %get-tuple-element.4", 1)
+    assert kernel_tiles(twice) == {"flash_fwd": {k: 2 * v for k, v in one.items()}}
+    assert kernel_tiles("no entry here") == {}
 
 
 def test_plan_program_keeps_the_table_and_writes_it_beside_the_events(tmp_path, monkeypatch):
@@ -450,7 +471,8 @@ def test_obs_hbm_prints_the_events_scope_counts_and_file(tmp_path):
     w.emit("hbm_plan", label="train_step", analysis="memory_analysis", argument_bytes=4096,
            output_bytes=4096, temp_bytes=512, alias_bytes=0, code_bytes=64,
            scope_counts={"bwd": 7, "fwd": 5, "kernel/flash_fwd": 1, "update": 2},
-           scope_file="scope-h000-train_step.json")
+           scope_file="scope-h000-train_step.json",
+           kernel_tiles={"flash_fwd": {"calls": 12, "computed": 23040, "masked": 9216, "total": 36864}})
     w.emit("hbm_plan", label="eval_step", analysis="aval", argument_bytes=64, output_bytes=8)
     w.emit("hbm_sample", params_bytes=600, watermark=2000, peak=2000, limit=4096, synthetic=True)
     w.close()
@@ -458,6 +480,9 @@ def test_obs_hbm_prints_the_events_scope_counts_and_file(tmp_path):
     assert ("scope (scope-h000-train_step.json): bwd 7, fwd 5, kernel/flash_fwd 1, update 2"
             in out)
     assert out.count("scope (") == 1  # a plan without a table gets no such line
+    assert ("tiles flash_fwd: 12 call(s), 23040 of 36864 sub-tiles computed (62.5%), 9216 masked"
+            in out)
+    assert out.count("    tiles ") == 1
 
 
 # the benchmark's cases (tests/benchmark: test_trace_reduction_on_a_hand_built_trace)

@@ -51,13 +51,32 @@ def test_dense_window_matches_explicit_band(qkv):
         )
 
 
-@pytest.mark.parametrize("window", [4, 8, 24])
-def test_flash_window_matches_dense(qkv, window):
+@pytest.mark.parametrize(
+    "window,bq,bk,edge",
+    [
+        (4, 16, 16, None), (8, 16, 16, None), (24, 16, 16, None),
+        # the benchmark cell's shape class (T == block_k, two Q blocks)
+        # walked in 16 x 16 sub-tiles: the band's past edge one before, on
+        # and one after a sub-tile boundary, inside one sub-tile, and
+        # across three
+        (15, 32, 64, 16), (16, 32, 64, 16), (17, 32, 64, 16),
+        (5, 32, 64, 16), (40, 32, 64, 16),
+        (31, 32, 64, 16),  # a run of three sub-tiles, each crossed by an edge
+        (17, 64, 64, 16),  # one Q block (the default's shape at T <= 1024)
+    ],
+)
+def test_flash_window_matches_dense(qkv, monkeypatch, window, bq, bk, edge):
     """Band-masked kernel (incl. block skipping: window 4 < block 16 skips
     whole past blocks) == dense band, forward and gradients."""
+    import sys
+
+    if edge is not None:  # a windowed call walks the coarser edge
+        monkeypatch.setattr(
+            sys.modules["ddl_tpu.ops.flash_attention"], "_SUB_TILE_LONG", edge
+        )
     q, k, v = qkv
     out = flash_attention(
-        q, k, v, causal=True, window=window, block_q=16, block_k=16
+        q, k, v, causal=True, window=window, block_q=bq, block_k=bk
     )
     want = _dense_banded(q, k, v, window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
@@ -65,7 +84,7 @@ def test_flash_window_matches_dense(qkv, window):
     cot = jnp.asarray(np.random.default_rng(1).normal(size=q.shape), jnp.float32)
     gf = jax.grad(
         lambda *a: (flash_attention(
-            *a, causal=True, window=window, block_q=16, block_k=16
+            *a, causal=True, window=window, block_q=bq, block_k=bk
         ) * cot).sum(),
         (0, 1, 2),
     )(q, k, v)

@@ -93,10 +93,21 @@ def test_flash_attention_fwd_and_grad(
     q = _s((batch, seq, 12, 64), BF16)
     kv = _s((batch, seq, kv_heads, 64), BF16)
     compile_for_chip(attend, q, kv, kv)
-    compile_for_chip(
+    text = compile_for_chip(
         jax.grad(lambda q, k, v: _sum_f32(attend(q, k, v)), argnums=(0, 1, 2)),
         q, kv, kv,
     )
+    # the compiled kernels say what they walk (what ``hbm_plan`` records):
+    # the pure plan, over the call's batch x heads rows
+    from ddl_tpu.obs.scope import kernel_tiles
+    from ddl_tpu.ops.flash_attention import flash_tile_plan
+
+    plan = flash_tile_plan(seq, causal=True, window=window)
+    assert kernel_tiles(text) == {
+        name: {"calls": 1, **{k: batch * 12 * n for k, n in plan[name].items()}}
+        for name in ("flash_bwd_dkv", "flash_bwd_dq", "flash_fwd")
+    }
+    assert plan["flash_fwd"]["computed"] < plan["flash_fwd"]["total"]
 
 
 @pytest.mark.parametrize("kv_heads", [12, 4], ids=["fused768", "fused256"])

@@ -83,7 +83,7 @@ class LMDecode(nn.Module):
         x = nn.with_logical_constraint(x, ("batch", "act_seq", "act_embed"))
         new_caches = []
         for i in range(cfg.n_layers):
-            x, _aux, c = Block(cfg, self.attn_core, name=f"block{i}")(
+            x, _aux, c = Block(cfg, self.attn_core, i, name=f"block{i}")(
                 x, caches[i], offset, rolling=self.rolling
             )
             new_caches.append(c)

@@ -16,6 +16,17 @@ capacity and a load-balancing auxiliary loss.  Params are float32 masters
 with bfloat16 compute (TPU MXU-native); the loss-side logits are returned in
 float32.
 
+What a layer is can also be said layer by layer (``LMConfig.layer_types``
+and the fields beside it): sliding-window and full-attention layers mixed,
+rotary positions only in the sliding ones, RMSNorm on each head's q and k,
+a sigmoid gate on the attention output, four norms a block, a gated
+three-matrix MLP, leading dense layers, and a dropless expert layer
+(sigmoid scores, biased top-k selection, a shared expert, the grouped
+product of ``ops/grouped_matmul.py``) that is told which experts it holds
+(``expert_share``), routes over all of them and computes its own experts'
+part.  That is the afmoe block of ``benchmark/configs/trinity-mini.json``;
+the defaults are the block above, unchanged.
+
 No torch/CUDA analog exists in the reference; parity citations therefore
 point at the subsystems this family plugs into: the mesh backbone
 (SURVEY.md §2 C10), the trainer (C3), and the checkpointing layout (C8).
@@ -46,6 +57,7 @@ __all__ = [
     "REMAT_POLICIES",
     "TransformerLM",
     "count_lm_params",
+    "dropless_plan",
     "make_embed",
     "make_lm_head",
     "apply_final_norm_and_head",
@@ -193,8 +205,66 @@ class LMConfig:
     # Mutually exclusive with ce_chunk; requires mesh model=1 (the scan
     # slices the head kernel over vocab).
     ce_vocab_chunk: int = 0
+    # --- what a layer is, layer by layer (defaults: the block above) ---
+    # Attention kind of each layer, "sliding_attention" (the last
+    # attn_window positions) or "full_attention" (all of the past);
+    # () = every layer alike, windowed iff attn_window.  With a pattern
+    # attn_window is the sliding layers' window, and only the sliding
+    # layers rotate q and k: a full_attention layer of a pattern has no
+    # positional signal of its own.
+    layer_types: tuple = ()
+    # RMSNorm over each head's head_dim on q and k (learned scale) before
+    # the rotation; a sigmoid gate on the attention output from a
+    # projection of the block's input (d_model -> n_heads * head_dim).
+    qk_norm: bool = False
+    attn_gate: bool = False
+    # Three-matrix gated MLP, (silu(x Wg) * (x Wi)) Wo, for the two-matrix
+    # GELU one; applies to the dense MLP and to every expert.
+    mlp_gated: bool = False
+    # Four norms a block: x + norm(attn(norm(x))), x + norm(mlp(norm(x))).
+    sandwich_norm: bool = False
+    norm_eps: float = 1e-6
+    # h0 = E[tokens] * sqrt(d_model)
+    embed_scale: bool = False
+    # With num_experts > 0, the leading layers that keep the dense MLP.
+    num_dense_layers: int = 0
+    # 'softmax': top-k of softmax gates under a token capacity (choices
+    # past it are dropped), the path above.  'sigmoid': dropless: sigmoid
+    # scores, selection by score + a bias that is not trained, the chosen
+    # scores normalised and scaled by route_scale, every choice computed
+    # (rows sorted by expert, ops/grouped_matmul.py), beside
+    # num_shared_experts experts that every token passes through.
+    moe_router: str = "softmax"
+    moe_d_ff: int = 0  # an expert's width (0 = d_ff)
+    num_shared_experts: int = 0
+    route_scale: float = 1.0
+    # (share index, shares): this program holds experts [i * E / n,
+    # (i + 1) * E / n) of each layer's num_experts, routes over all of
+    # them and computes its own experts' part of the result; choices of
+    # experts held elsewhere add nothing here (their owners' exchange is
+    # not this program's).  (0, 1) holds them all.  'sigmoid' only.
+    expert_share: tuple = (0, 1)
 
     def __post_init__(self):
+        if self.layer_types:
+            kinds = {"sliding_attention", "full_attention"}
+            if len(self.layer_types) != self.n_layers or set(self.layer_types) - kinds:
+                raise ValueError(
+                    f"layer_types must name each of the {self.n_layers} layers "
+                    f"as one of {sorted(kinds)}, got {self.layer_types!r}"
+                )
+        if self.moe_router not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"moe_router must be 'softmax' or 'sigmoid', got {self.moe_router!r}"
+            )
+        idx, shares = self.expert_share
+        if not (0 <= idx < shares) or (self.num_experts and self.num_experts % shares):
+            raise ValueError(
+                f"expert_share {self.expert_share!r} must be (index, shares) with "
+                f"index < shares and shares dividing num_experts {self.num_experts}"
+            )
+        if shares > 1 and self.moe_router != "sigmoid":
+            raise ValueError("expert_share is the dropless ('sigmoid') router's")
         if self.moe_ep not in ("auto", "gspmd", "alltoall"):
             raise ValueError(
                 f"moe_ep must be 'auto', 'gspmd' or 'alltoall', got "
@@ -242,6 +312,33 @@ class LMConfig:
     @property
     def dtype(self):
         return jnp.dtype(self.compute_dtype)
+
+    @property
+    def experts_held(self) -> int:
+        return self.num_experts // self.expert_share[1]
+
+    @property
+    def expert_width(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    @property
+    def layers_alike(self) -> bool:
+        """Every layer is the same block: what a path that stacks one
+        block's parameters for all layers (the pipeline) or builds its own
+        block (the serving engine) can run."""
+        return not self.layer_types and not (self.num_experts and self.num_dense_layers)
+
+    def layer_window(self, i: int) -> int:
+        """Layer ``i``'s attention window (0 = all of the past)."""
+        if self.layer_types and self.layer_types[i] == "full_attention":
+            return 0
+        return self.attn_window
+
+    def layer_rope(self, i: int) -> bool:
+        return not self.layer_types or self.layer_types[i] == "sliding_attention"
+
+    def layer_is_moe(self, i: int) -> bool:
+        return self.num_experts > 0 and i >= self.num_dense_layers
 
 
 REMAT_POLICIES = ("full", "dots", "dots_no_batch")
@@ -295,6 +392,7 @@ def _rope(x, theta: float, positions=None):
 
 class RMSNorm(nn.Module):
     dtype: Any = jnp.float32
+    eps: float = 1e-6
 
     @nn.compact
     def __call__(self, x):
@@ -305,7 +403,7 @@ class RMSNorm(nn.Module):
             jnp.float32,
         )
         x32 = x.astype(jnp.float32)
-        y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + 1e-6)
+        y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps)
         return (y * scale).astype(self.dtype)
 
 
@@ -376,10 +474,20 @@ class Attention(nn.Module):
 
     cfg: LMConfig
     attn_core: Optional[Callable] = None
+    # this layer's kind (LMConfig.layer_window / layer_rope); None = the
+    # model-wide attn_window
+    window: Optional[int] = None
+    rope: bool = True
 
     @nn.compact
     def __call__(self, x, kv_cache=None, offset=None, rolling=False):
         cfg = self.cfg
+        window = cfg.attn_window if self.window is None else self.window
+        if kv_cache is not None and cfg.layer_types:
+            raise NotImplementedError(
+                "a decode cache over mixed sliding and full layers is not "
+                "built (the cache paths read one model-wide attn_window)"
+            )
         b, t, _ = x.shape
         # kernels are flat (embed, heads*head_dim) with the fused dim sharded
         # over 'model' — identical placement to a per-head split, one matmul.
@@ -399,11 +507,15 @@ class Attention(nn.Module):
         q = proj("q", cfg.n_heads)
         k = proj("k", cfg.kv_heads)
         v = proj("v", cfg.kv_heads)
+        if cfg.qk_norm:
+            q = RMSNorm(cfg.dtype, cfg.norm_eps, name="q_norm")(q)
+            k = RMSNorm(cfg.dtype, cfg.norm_eps, name="k_norm")(k)
         positions = None
         if kv_cache is not None:
             positions = offset + jnp.arange(t)
-        q = _rope(q, cfg.rope_theta, positions)
-        k = _rope(k, cfg.rope_theta, positions)
+        if self.rope:
+            q = _rope(q, cfg.rope_theta, positions)
+            k = _rope(k, cfg.rope_theta, positions)
         spec = ("batch", "act_seq", "act_heads", None)
         # fused-storage cache leaves are 3-D (ops/quant.kv_fuse)
         cache_spec = ("batch", "act_seq", "act_heads")
@@ -416,9 +528,13 @@ class Attention(nn.Module):
             # ppermutes and Ulysses all-to-alls Hkv-head K/V) — K/V are
             # never broadcast to H heads, so the manual cores' HBM and
             # collective traffic keep GQA's Hkv/H savings.
-            core = self.attn_core or partial(
-                dense_attention, causal=cfg.causal, window=cfg.attn_window
-            )
+            if self.attn_core is None:
+                core = partial(dense_attention, causal=cfg.causal, window=window)
+            elif cfg.layer_types:
+                # a core built for a pattern takes the layer's window
+                core = partial(self.attn_core, window=window)
+            else:
+                core = self.attn_core
             o = nn.with_logical_constraint(core(q, k, v), spec)
             new_cache = None
         elif rolling:
@@ -503,6 +619,14 @@ class Attention(nn.Module):
             )
             o = nn.with_logical_constraint(o, spec)
             new_cache = kv_cache
+        o = o.reshape(b, t, cfg.n_heads * cfg.head_dim)
+        if cfg.attn_gate:
+            g = QDense(
+                cfg.n_heads * cfg.head_dim, dtype=cfg.dtype,
+                kernel_init=qkv_kernel, name="gate",
+            )(x)
+            o = (o.astype(jnp.float32)
+                 * jax.nn.sigmoid(g.astype(jnp.float32))).astype(cfg.dtype)
         out = QDense(
             cfg.d_model,
             dtype=cfg.dtype,
@@ -510,28 +634,35 @@ class Attention(nn.Module):
                 nn.initializers.lecun_normal(), ("heads", "embed")
             ),
             name="out",
-        )(o.reshape(b, t, cfg.n_heads * cfg.head_dim))
+        )(o)
         out = nn.with_logical_constraint(out, ("batch", "act_seq", "act_embed"))
         return out if kv_cache is None else (out, new_cache)
 
 
 class Mlp(nn.Module):
     cfg: LMConfig
+    d_ff: int = 0  # 0 = cfg.d_ff (a shared expert passes its own width)
 
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        h = QDense(
-            cfg.d_ff,
-            dtype=cfg.dtype,
-            kernel_init=nn.with_logical_partitioning(
-                nn.initializers.lecun_normal(), ("embed", "mlp")
-            ),
-            name="wi",
-        )(x)
-        h = nn.with_logical_constraint(
-            nn.gelu(h), ("batch", "act_seq", "act_mlp")
-        )
+
+        def up(name):
+            return QDense(
+                self.d_ff or cfg.d_ff,
+                dtype=cfg.dtype,
+                kernel_init=nn.with_logical_partitioning(
+                    nn.initializers.lecun_normal(), ("embed", "mlp")
+                ),
+                name=name,
+            )(x)
+
+        h = up("wi")
+        if cfg.mlp_gated:
+            h = _swiglu(up("wg"), h)
+        else:
+            h = nn.gelu(h)
+        h = nn.with_logical_constraint(h, ("batch", "act_seq", "act_mlp"))
         out = QDense(
             cfg.d_model,
             dtype=cfg.dtype,
@@ -541,6 +672,12 @@ class Mlp(nn.Module):
             name="wo",
         )(h)
         return nn.with_logical_constraint(out, ("batch", "act_seq", "act_embed"))
+
+
+def _swiglu(gate, up):
+    """``silu(gate) * up``, the product taken in float32."""
+    return (jax.nn.silu(gate.astype(jnp.float32))
+            * up.astype(jnp.float32)).astype(up.dtype)
 
 
 def _top_k_dispatch(gates, k: int, capacity: int):
@@ -731,6 +868,114 @@ def _combine_gather_bwd(res, g):
 _combine_gather.defvjp(_combine_gather_fwd, _combine_gather_bwd)
 
 
+def dropless_plan(expert_idx, lo: int, held: int, tile: int):
+    """Where every token-choice of a dropless layer goes, for the experts
+    ``[lo, lo + held)`` this program holds.
+
+    ``expert_idx`` (N, K) int32, each token's chosen experts over the
+    whole router.  The choices that land on held experts are sorted by
+    expert into a buffer whose runs start on row-tile boundaries
+    (``ops/grouped_matmul.align_groups``) and which is sized for the
+    worst routing, so nothing can be dropped.  Returns a dict of index
+    arrays: ``row_choice`` (R,) the flat choice (token * K + k) in each
+    buffer row and ``row_valid`` (R,) whether one is; ``choice_row``
+    (N, K) each choice's row and ``held`` (N, K) whether it has one;
+    ``counts`` (held,) rows an expert; the grouped product's
+    ``tile_group``, ``tile_src``, ``n_active``."""
+    from ddl_tpu.ops.grouped_matmul import align_groups, buffer_rows
+
+    n, k = expert_idx.shape
+    rows = buffer_rows(n * min(k, held), held, tile)
+    local = expert_idx.reshape(-1) - lo
+    is_held = (local >= 0) & (local < held)
+    key = jnp.where(is_held, local, held)  # elsewhere: sorted to the end
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    counts = (key[:, None] == jnp.arange(held)).sum(0, dtype=jnp.int32)
+    start, tile_group, tile_src, n_active = align_groups(counts, rows // tile, tile)
+    packed = jnp.cumsum(counts) - counts  # a run's start in sorted order
+    r = jnp.arange(rows, dtype=jnp.int32)
+    g = tile_group[r // tile]
+    pos = r - start[g]
+    row_valid = (pos < counts[g]) & (r // tile < n_active[0])
+    row_choice = jnp.where(
+        row_valid, order[jnp.minimum(packed[g] + pos, n * k - 1)], 0
+    )
+    rank = jnp.zeros(n * k, jnp.int32).at[order].set(
+        jnp.arange(n * k, dtype=jnp.int32)
+    )
+    kc = jnp.minimum(key, held - 1)
+    choice_row = jnp.where(is_held, start[kc] + rank - packed[kc], 0)
+    return {
+        "row_choice": row_choice, "row_valid": row_valid,
+        "choice_row": choice_row.reshape(n, k), "held": is_held.reshape(n, k),
+        "counts": counts, "tile_group": tile_group, "tile_src": tile_src,
+        "n_active": n_active,
+    }
+
+
+@jax.custom_vjp
+def _rows_gather(x, row_token, row_valid, choice_row, held):
+    """``xs[r] = x[row_token[r]]`` where the buffer row holds a choice, 0
+    where it pads a run.  The VJP is gathers too: a token's gradient is
+    the sum over its held choices' rows (a TPU scatter-add never
+    appears; rows of unwritten tiles are never read)."""
+    return jnp.where(row_valid[:, None], jnp.take(x, row_token, axis=0), 0)
+
+
+def _rows_gather_fwd(x, row_token, row_valid, choice_row, held):
+    return _rows_gather(x, row_token, row_valid, choice_row, held), (choice_row, held)
+
+
+def _rows_gather_bwd(res, g):
+    choice_row, held = res
+    dx = jnp.zeros((choice_row.shape[0], g.shape[1]), jnp.float32)
+    for j in range(choice_row.shape[1]):
+        picked = jnp.take(g, choice_row[:, j], axis=0).astype(jnp.float32)
+        dx = dx + jnp.where(held[:, j, None], picked, 0.0)
+    return dx.astype(g.dtype), None, None, None, None
+
+
+_rows_gather.defvjp(_rows_gather_fwd, _rows_gather_bwd)
+
+
+@jax.custom_vjp
+def _rows_combine(o, w, choice_row, held, row_choice, row_valid):
+    """``y[t] = sum_k w[t, k] * o[choice_row[t, k]]`` over the held
+    choices, in float32.  ``o``'s gradient is a gather through the
+    inverse map (``row_choice``), 0 on rows that hold no choice."""
+    y = jnp.zeros((w.shape[0], o.shape[1]), jnp.float32)
+    for j in range(w.shape[1]):
+        picked = jnp.take(o, choice_row[:, j], axis=0).astype(jnp.float32)
+        y = y + jnp.where(held[:, j, None], picked * w[:, j, None], 0.0)
+    return y
+
+
+def _rows_combine_fwd(o, w, choice_row, held, row_choice, row_valid):
+    y = _rows_combine(o, w, choice_row, held, row_choice, row_valid)
+    return y, (o, w, choice_row, held, row_choice, row_valid)
+
+
+def _rows_combine_bwd(res, g):
+    o, w, choice_row, held, row_choice, row_valid = res
+    k = w.shape[1]
+    dw = jnp.stack([
+        jnp.where(
+            held[:, j],
+            (jnp.take(o, choice_row[:, j], axis=0).astype(jnp.float32) * g).sum(-1),
+            0.0,
+        )
+        for j in range(k)
+    ], axis=1)
+    scale = jnp.take(w.reshape(-1), row_choice)[:, None]
+    do = jnp.where(
+        row_valid[:, None], jnp.take(g, row_choice // k, axis=0) * scale, 0.0
+    )
+    return do.astype(o.dtype), dw.astype(w.dtype), None, None, None, None
+
+
+_rows_combine.defvjp(_rows_combine_fwd, _rows_combine_bwd)
+
+
 def _ambient_mesh_shape() -> dict:
     """Axis-name -> size of the ambient (abstract) mesh; {} when tracing
     without a mesh context (jax answers with an empty mesh, it does not
@@ -859,6 +1104,8 @@ class MoeMlp(nn.Module):
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
+        if cfg.moe_router == "sigmoid":
+            return self._dropless(x), jnp.zeros((), jnp.float32)
         b0, s0, d = x.shape
         # split the sequence into routing groups (moe_routing_plan):
         # capacity is per group and dispatch cost is O(group) per token,
@@ -1016,6 +1263,92 @@ class MoeMlp(nn.Module):
         y = nn.with_logical_constraint(y, ("batch", "act_seq", "act_embed"))
         return y, aux_loss
 
+    def _dropless(self, x):
+        """The dropless layer (``moe_router='sigmoid'``): sigmoid scores
+        over all ``num_experts``, the top ``expert_top_k`` of score + bias,
+        the chosen scores normalised and scaled, every choice on an
+        expert held here computed, the shared experts beside them.
+        Router in float32; the experts' products in the compute type
+        with float32 accumulation."""
+        # imported here: Pallas loads only where a dropless layer is built
+        from ddl_tpu.ops.grouped_matmul import ROW_TILE, grouped_matmul
+
+        cfg = self.cfg
+        b, t, d = x.shape
+        e, k, f, dt = cfg.num_experts, cfg.expert_top_k, cfg.expert_width, cfg.dtype
+        held = cfg.experts_held
+        lo = cfg.expert_share[0] * held
+        flat = x.reshape(b * t, d)
+        with jax.named_scope("moe/route"):
+            logits = nn.Dense(
+                e, use_bias=False, dtype=jnp.float32, param_dtype=jnp.float32,
+                kernel_init=nn.with_logical_partitioning(
+                    nn.initializers.lecun_normal(), ("embed", "expert")
+                ),
+                name="router",
+            )(flat.astype(jnp.float32))
+            scores = jax.nn.sigmoid(logits)
+            # the bias a training recipe moves to balance load: not
+            # trained by the loss, part of the selection only
+            bias = jax.lax.stop_gradient(self.param(
+                "bias",
+                nn.with_logical_partitioning(nn.initializers.zeros_init(), (None,)),
+                (e,), jnp.float32,
+            ))
+            _, idx = jax.lax.top_k(scores + bias, k)
+            picked = jnp.take_along_axis(scores, idx, axis=-1)
+            weights = cfg.route_scale * picked / (
+                picked.sum(-1, keepdims=True) + 1e-20
+            )
+
+        def bank(name, shape, axes):
+            return self.param(
+                name,
+                nn.with_logical_partitioning(
+                    nn.initializers.lecun_normal(batch_axis=(0,)), axes
+                ),
+                shape, jnp.float32,
+            )
+
+        wg = bank("wg", (held, d, f), ("expert", "embed", "mlp"))
+        wi = bank("wi", (held, d, f), ("expert", "embed", "mlp"))
+        wo = bank("wo", (held, f, d), ("expert", "mlp", "embed"))
+        with jax.named_scope("moe/dispatch"):
+            plan = dropless_plan(idx.astype(jnp.int32), lo, held, ROW_TILE)
+            xs = _rows_gather(
+                flat.astype(dt), plan["row_choice"] // k, plan["row_valid"],
+                plan["choice_row"], plan["held"],
+            )
+        tiles = (plan["tile_group"], plan["tile_src"], plan["n_active"])
+        with jax.named_scope("moe/experts"):
+            h = _swiglu(grouped_matmul(xs, wg, *tiles), grouped_matmul(xs, wi, *tiles))
+            o = grouped_matmul(h, wo, *tiles)
+        with jax.named_scope("moe/combine"):
+            y = _rows_combine(
+                o, weights, plan["choice_row"], plan["held"],
+                plan["row_choice"], plan["row_valid"],
+            )
+        with jax.named_scope("moe/shared"):
+            if cfg.num_shared_experts:
+                y = y + Mlp(cfg, f * cfg.num_shared_experts, name="shared")(
+                    x.astype(dt)
+                ).reshape(b * t, d).astype(jnp.float32)
+        counts = plan["counts"]
+        rows = counts.sum()
+        self.sow("intermediates", "moe_local_rows", rows.astype(jnp.float32))
+        self.sow(
+            "intermediates", "moe_load_max_over_mean",
+            counts.max() * held / jnp.maximum(rows, 1).astype(jnp.float32),
+        )
+        # what the buffer holds against what was routed here: 0 by the
+        # buffer's size, counted so that a run can say so
+        self.sow(
+            "intermediates", "moe_rows_dropped",
+            (rows - plan["row_valid"].sum()).astype(jnp.float32),
+        )
+        y = y.astype(dt).reshape(b, t, d)
+        return nn.with_logical_constraint(y, ("batch", "act_seq", "act_embed"))
+
 
 class Block(nn.Module):
     """Pre-norm decoder block.  With ``kv_cache`` (incremental decode) the
@@ -1023,26 +1356,40 @@ class Block(nn.Module):
 
     cfg: LMConfig
     attn_core: Optional[Callable] = None
+    # which layer of the model this is: its kind (window or full, rotary
+    # or none, dense or expert MLP) is the configuration's, LMConfig.layer_*
+    layer: int = 0
 
     @nn.compact
     def __call__(self, x, kv_cache=None, offset=None, deterministic=True,
                  rolling=False):
         cfg = self.cfg
         drop = nn.Dropout(cfg.dropout_rate, deterministic=deterministic)
-        attn = Attention(cfg, self.attn_core, name="attn")
-        h = RMSNorm(cfg.dtype, name="norm_attn")(x)
+
+        def norm(name):
+            return RMSNorm(cfg.dtype, cfg.norm_eps, name=name)
+
+        def post(name, y):
+            # the residual branch's own norm, where a block has four
+            return norm(name)(y) if cfg.sandwich_norm else y
+
+        attn = Attention(
+            cfg, self.attn_core, cfg.layer_window(self.layer),
+            cfg.layer_rope(self.layer), name="attn",
+        )
+        h = norm("norm_attn")(x)
         if kv_cache is None:
-            x = x + drop(attn(h))
+            x = x + drop(post("norm_post_attn", attn(h)))
             new_cache = None
         else:
             a, new_cache = attn(h, kv_cache, offset, rolling=rolling)
-            x = x + drop(a)
-        h = RMSNorm(cfg.dtype, name="norm_mlp")(x)
-        if cfg.num_experts > 0:
+            x = x + drop(post("norm_post_attn", a))
+        h = norm("norm_mlp")(x)
+        if cfg.layer_is_moe(self.layer):
             y, aux = MoeMlp(cfg, name="moe")(h)
         else:
             y, aux = Mlp(cfg, name="mlp")(h), jnp.zeros((), jnp.float32)
-        x = x + drop(y)
+        x = x + drop(post("norm_post_mlp", y))
         return (x, aux) if kv_cache is None else (x, aux, new_cache)
 
 
@@ -1073,7 +1420,10 @@ class TokenEmbed(nn.Module):
             jnp.float32,
         )
         table = nn.with_logical_constraint(table, (None, None))
-        return jnp.take(table, tokens, axis=0).astype(cfg.dtype)
+        x = jnp.take(table, tokens, axis=0)
+        if cfg.embed_scale:
+            x = x * jnp.sqrt(jnp.float32(cfg.d_model))
+        return x.astype(cfg.dtype)
 
 
 def make_embed(cfg: LMConfig) -> TokenEmbed:
@@ -1134,7 +1484,7 @@ def make_lm_head(cfg: LMConfig) -> "LMHead":
 def apply_final_norm_and_head(cfg: LMConfig, x):
     """Final RMSNorm ('norm_f') + lm_head -> constrained f32 logits.
     Call inside an ``nn.compact`` method."""
-    x = RMSNorm(cfg.dtype, name="norm_f")(x)
+    x = RMSNorm(cfg.dtype, cfg.norm_eps, name="norm_f")(x)
     logits = make_lm_head(cfg)(x.astype(jnp.float32))
     return nn.with_logical_constraint(logits, ("batch", "act_seq", "act_vocab"))
 
@@ -1162,12 +1512,12 @@ class TransformerLM(nn.Module):
         block = remat_block(cfg)
         aux_total = jnp.zeros((), jnp.float32)
         for i in range(cfg.n_layers):
-            x, aux = block(cfg, self.attn_core, name=f"block{i}")(
+            x, aux = block(cfg, self.attn_core, i, name=f"block{i}")(
                 x, None, None, deterministic
             )
             aux_total = aux_total + aux
         if return_hidden:
-            return RMSNorm(cfg.dtype, name="norm_f")(x), aux_total
+            return RMSNorm(cfg.dtype, cfg.norm_eps, name="norm_f")(x), aux_total
         return apply_final_norm_and_head(cfg, x), aux_total
 
 

@@ -220,7 +220,7 @@ def _aval_bytes(x) -> int:
 
 def plan_program(
     writer, label: str, fn, args=(), kwargs=None,
-    step: int | None = None, mode: str = "full",
+    step: int | None = None, mode: str = "full", parts: dict | None = None,
 ) -> dict | None:
     """Emit one ``hbm_plan``: the static per-program memory budget for a
     jitted ``fn`` at these ``args``.  ``mode="full"`` compiles the
@@ -229,7 +229,9 @@ def plan_program(
     run-time continuation of hlolint's inventory); ``mode="aval"`` keeps
     the cheap shape-arithmetic budget (argument/output bytes, no temp).
     Either way degrades instead of raising — a budget that cannot be
-    measured must not take the run down."""
+    measured must not take the run down.  ``parts`` (``{scope name: tag}``,
+    the caller's own scopes) asks for the second scope table, by part of
+    the model (``obs/scope.parts_table``)."""
     if writer is None:
         return None
     kwargs = kwargs or {}
@@ -269,7 +271,7 @@ def plan_program(
         "alias_bytes": alias_b,
         "code_bytes": code_b,
     }
-    scope = _scope_of(writer, label, compiled) if compiled is not None else None
+    scope = _scope_of(writer, label, compiled, parts) if compiled is not None else None
     if len(_recent_plans) < MAX_PLANS or label in _recent_plans:
         # the table stays in-process (and in its file): 14,000 rows for
         # DenseNet121's step do not belong in a JSONL line
@@ -281,7 +283,7 @@ def plan_program(
     return writer.emit("hbm_plan", step=step, label=str(label), **plan, **fields)
 
 
-def _scope_of(writer, label: str, compiled) -> dict | None:
+def _scope_of(writer, label: str, compiled, parts=None) -> dict | None:
     """The compiled program's scope table (``obs/scope.py``), written
     beside the host's event file: ``{"scope": {...}, "scope_module",
     "scope_counts", "scope_file"}``, or None — like the budget, a table
@@ -295,6 +297,7 @@ def _scope_of(writer, label: str, compiled) -> dict | None:
             return None
         name = sc.write_table(writer.path.parent, writer.host, label, module, table)
         return {
+            "parts": sc.parts_table(text, parts or {}),
             "scope": table,
             "scope_module": module,
             "scope_counts": sc.tag_counts(table),
@@ -308,7 +311,12 @@ def _scope_of(writer, label: str, compiled) -> dict | None:
 def scope_table(label: str) -> dict | None:
     """``{instruction name: tag}`` of the program this process last
     planned under ``label``, or None (``mode="aval"``/``off``, no
-    executable text, no plan yet)."""
+    executable text, no plan yet).  ``<label>.parts`` is the same
+    program's second table, by part of the model
+    (``obs/scope.parts_table``); it is kept beside the direction table,
+    which alone goes by module name in ``scope_tables``."""
+    if label.endswith(".parts"):
+        return _recent_plans.get(label[: -len(".parts")], {}).get("parts") or None
     return _recent_plans.get(label, {}).get("scope")
 
 
@@ -626,6 +634,16 @@ def render_hbm(account: dict, job_id: str = "") -> str:
                 counts = ", ".join(f"{t} {n}" for t, n in p["scope_counts"].items())
                 lines.append(f"    scope ({p.get('scope_file')}): {counts}")
             for kernel, n in (p.get("kernel_tiles") or {}).items():
+                if "computed" not in n:
+                    # a grouped product: its grid's worst case and the
+                    # fewest steps any routing leaves it (the steps
+                    # computed are the routing's, not the text's)
+                    lines.append(
+                        f"    tiles {kernel}: {n.get('calls')} call(s), "
+                        f"{n.get('total')} grid steps at most, "
+                        f"{n.get('floor')} at least"
+                    )
+                    continue
                 share = 100.0 * n.get("computed", 0) / max(n.get("total", 0), 1)
                 lines.append(
                     f"    tiles {kernel}: {n.get('calls')} call(s), "

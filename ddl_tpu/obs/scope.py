@@ -47,7 +47,7 @@ from pathlib import Path
 
 __all__ = [
     "kernel_tiles", "load_tables", "module_name", "opcode_of", "own_name",
-    "scope_table", "tag_counts", "write_table",
+    "parts_table", "scope_table", "tag_counts", "write_table",
 ]
 
 # ``<opcode>(`` after the result type; types hold ``T(8,128)`` and ``S(1)``,
@@ -140,15 +140,40 @@ def _direction(op_name: str) -> str | None:
     return "update"
 
 
+def _op_name(line: str) -> str:
+    at = line.find('op_name="')
+    if at < 0:
+        return ""
+    at += len('op_name="')
+    return line[at:line.find('"', at)]
+
+
+def _part_tags(parts: dict[str, str]):
+    """``tags_of`` for the second table: the first scope on an
+    instruction's ``op_name`` path that ``parts`` names gives its tag,
+    ``other`` when none does; a kernel takes the tag of the scope it is
+    called in."""
+    rx = re.compile(
+        "(?:^|/)(" + "|".join(map(re.escape, sorted(parts, key=len, reverse=True)))
+        + ")(?:/|$)"
+    )
+
+    def tags_of(line: str, name: str, opcode: str) -> tuple[str | None, str | None]:
+        op_name = _op_name(line)
+        if not op_name:
+            return None, None
+        m = rx.search(op_name)
+        tag = "other" if m is None else parts[m.group(1)]
+        return tag, tag
+
+    return tags_of
+
+
 def _own_tags(line: str, name: str, opcode: str) -> tuple[str | None, str | None]:
     """(the instruction's tag, the tag it hands to operands that have
     none): they differ for a kernel, whose inputs' copies belong to its
     direction and not to the kernel's own time."""
-    at = line.find('op_name="')
-    op_name = ""
-    if at >= 0:
-        at += len('op_name="')
-        op_name = line[at:line.find('"', at)]
+    op_name = _op_name(line)
     direction = _direction(op_name)
     if opcode == "custom-call" and 'custom_call_target="tpu_custom_call"' in line:
         m = _KERNEL_RX.search(op_name)
@@ -161,6 +186,23 @@ def _own_tags(line: str, name: str, opcode: str) -> tuple[str | None, str | None
 def scope_table(text: str) -> dict[str, str]:
     """``{instruction name: tag}`` of the ENTRY computation of an
     optimized HLO module's text; empty when the text has no ENTRY."""
+    return _tag_table(text, _own_tags, "update")
+
+
+def parts_table(text: str, parts: dict[str, str]) -> dict[str, str]:
+    """The same instructions tagged by part of the model instead of by
+    direction: what share of a step the expert layers, the attention, the
+    MLPs and the loss edge take, whatever their direction.  ``parts`` is
+    the vocabulary of the caller that owns the scopes, ``{scope name on
+    the op_name path: tag}`` (``train/lm_steps.STEP_PARTS``).  Empty when
+    no instruction lies in any part."""
+    if not parts:
+        return {}
+    table = _tag_table(text, _part_tags(parts), "other")
+    return table if any(tag != "other" for tag in table.values()) else {}
+
+
+def _tag_table(text: str, tags_of, default: str) -> dict[str, str]:
     rows = []  # (name, own tag, tag handed down, operand names), in program order
     for line in _entry_lines(text):
         _, sep, rest = line.partition(" = ")
@@ -175,7 +217,7 @@ def scope_table(text: str) -> dict[str, str]:
         # kernel's serialized body out of the regex
         args = body[m.end():body.find(")", m.end())]
         rows.append(
-            (name, *_own_tags(line, name, m.group(1)), _OPERAND_RX.findall(args))
+            (name, *tags_of(line, name, m.group(1)), _OPERAND_RX.findall(args))
         )
     table = {name: tag for name, tag, _, _ in rows if tag is not None}
     bare = {name for name, tag, _, _ in rows if tag is None}
@@ -190,7 +232,7 @@ def scope_table(text: str) -> dict[str, str]:
             if op in bare:
                 inherited[op] = down
     for name in bare:
-        table[name] = inherited.get(name, "update")
+        table[name] = inherited.get(name, default)
     return table
 
 

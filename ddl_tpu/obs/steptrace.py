@@ -141,6 +141,10 @@ class _CompileCounter:
         return cls._shared
 
 
+# step metrics of a dropless expert layer that the period event copies
+_DROPLESS_COUNTERS = ("moe_local_rows", "moe_load_max_over_mean", "moe_rows_dropped")
+
+
 class StepTrace:
     """The object a trainer threads through its loop.
 
@@ -415,6 +419,11 @@ class StepTrace:
             hbm_bytes_in_use=mem["bytes_in_use"] if mem else None,
             hbm_peak_bytes=mem["peak_bytes_in_use"] if mem else None,
             **({"rates": dict(rates)} if rates else {}),
+            # a dropless expert layer's counters
+            # (lm_steps.moe_router_metrics), as the period's last step
+            # read them; absent for every other program
+            **{k: float(metrics[k]) for k in _DROPLESS_COUNTERS
+               if metrics and metrics.get(k) is not None},
         )
         self.anomaly.observe_period(
             idx,

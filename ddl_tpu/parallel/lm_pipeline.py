@@ -942,7 +942,7 @@ class _HeadNorm(nn.Module):
     def __call__(self, x):
         from ddl_tpu.models.transformer import RMSNorm
 
-        return RMSNorm(self.cfg.dtype, name="norm_f")(x)
+        return RMSNorm(self.cfg.dtype, self.cfg.norm_eps, name="norm_f")(x)
 
 
 def stack_block_params(full_params: Any, n_stages: int, virtual: int = 1):
@@ -1267,6 +1267,16 @@ def make_lm_pipeline_step_fns(
     if cfg.num_experts and cfg.num_experts % spec.expert:
         raise ValueError(
             f"num_experts {cfg.num_experts} % mesh expert={spec.expert} != 0"
+        )
+    if not cfg.layers_alike or cfg.moe_router == "sigmoid":
+        # one block module runs every layer of a stage over stacked
+        # parameters, so layers that differ would all become layer 0; and
+        # the stages collect nothing sown, so a dropless layer's counters
+        # (moe_rows_dropped) would be lost
+        raise NotImplementedError(
+            "the pipeline stacks one block for all layers and carries no "
+            "sown counters: layer_types, num_dense_layers and the dropless "
+            "('sigmoid') expert layer are built for make_lm_step_fns only"
         )
     mesh = build_lm_mesh(spec, devices)
     rules = lm_logical_rules(cfg.fsdp)

@@ -291,14 +291,20 @@ def _transformer_block_rules(E) -> tuple[tuple[str, P], ...]:
     attention QKV column-parallel and the out projection row-parallel
     over 'model' (Megatron split), MLP the same, MoE experts over
     'expert'; ``E`` is the embed-dimension axis — 'data' under FSDP
-    (ZeRO-3-style parameter sharding), unsharded otherwise."""
+    (ZeRO-3-style parameter sharding), unsharded otherwise.  The gated
+    block's leaves (``LMConfig.attn_gate``, ``mlp_gated``, the dropless
+    router's shared expert and selection bias) split as their twins do:
+    the attention gate with q, a gate matrix with its up matrix, a shared
+    expert as a dense MLP; the bias is one small vector, replicated; the
+    q/k norms' scales fall under the norm rule."""
     return (
-        (r"attn/(q|k|v)/kernel$", P(E, "model")),
+        (r"attn/(q|k|v|gate)/kernel$", P(E, "model")),
         (r"attn/out/kernel$", P("model", E)),
-        (r"mlp/wi/kernel$", P(E, "model")),
-        (r"mlp/wo/kernel$", P("model", E)),
+        (r"(mlp|moe/shared)/(wi|wg)/kernel$", P(E, "model")),
+        (r"(mlp|moe/shared)/wo/kernel$", P("model", E)),
         (r"moe/router/kernel$", P(E, "expert")),
-        (r"moe/wi$", P("expert", E, "model")),
+        (r"moe/bias$", P()),
+        (r"moe/(wi|wg)$", P("expert", E, "model")),
         (r"moe/wo$", P("expert", "model", E)),
         (r"norm\w*/scale$", P()),
     )

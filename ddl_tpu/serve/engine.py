@@ -283,12 +283,12 @@ class ServeBlock(nn.Module):
     @nn.compact
     def __call__(self, x, pool, cache, tables, lengths):
         cfg = self.cfg
-        h = RMSNorm(cfg.dtype, name="norm_attn")(x)
+        h = RMSNorm(cfg.dtype, cfg.norm_eps, name="norm_attn")(x)
         a, pool, cache = ServeAttention(cfg, name="attn")(
             h, pool, cache, tables, lengths
         )
         x = x + a
-        h = RMSNorm(cfg.dtype, name="norm_mlp")(x)
+        h = RMSNorm(cfg.dtype, cfg.norm_eps, name="norm_mlp")(x)
         if cfg.num_experts > 0:
             y, _aux = MoeMlp(cfg, name="moe")(h)
         else:
@@ -364,6 +364,13 @@ def make_serve_step_fns(
     spec = spec or LMMeshSpec()
     if not cfg.causal:
         raise ValueError("serving decode requires a causal LM")
+    if not cfg.layers_alike or cfg.qk_norm or cfg.attn_gate or cfg.sandwich_norm:
+        # ServeBlock/ServeAttention are the GPT-class block over the paged
+        # pool; they read none of these and would serve another model
+        raise NotImplementedError(
+            "the serving block is not built for layer_types, num_dense_layers, "
+            "qk_norm, attn_gate or sandwich_norm"
+        )
     if spec.pipe > 1 or spec.expert > 1:
         raise ValueError(
             "serving meshes use data/seq/model axes only (pipe/expert "

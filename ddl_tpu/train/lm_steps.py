@@ -52,6 +52,7 @@ from ddl_tpu.parallel.ulysses import make_ulysses_self_attention
 __all__ = [
     "LMTrainState",
     "LMStepFns",
+    "STEP_PARTS",
     "TOKEN_SPEC",
     "make_lm_step_fns",
     "make_ring_core",
@@ -156,6 +157,18 @@ def chunked_ce_loss(cfg, hidden, kernel, targets, aux, with_accuracy):
     return loss, (None, metrics)
 
 
+# The parts of an LM step, for the plan's second scope table
+# (obs/scope.parts_table): {scope on an instruction's op_name path: its
+# tag}.  The scopes are this package's own: flax names a module's scope
+# after it (``attn``, ``mlp``, ``lm_head`` of models/transformer), and
+# ``MoeMlp._dropless`` and ``loss_fn`` below open the rest by name.
+STEP_PARTS = {
+    **{f"moe/{part}": f"moe/{part}"
+       for part in ("route", "dispatch", "experts", "shared", "combine")},
+    "attn": "attn", "mlp": "mlp", "lm_head": "head", "head": "head",
+}
+
+
 def moe_router_metrics(intermediates) -> dict:
     """Aggregate the per-block router stats ``MoeMlp`` sows into scalar
     step metrics: mean token-drop fraction (capacity overflow silently
@@ -167,12 +180,27 @@ def moe_router_metrics(intermediates) -> dict:
     single hot microbatch; watch per-chunk logs (accum=1) when hunting
     routing collapse."""
     drops, loads = [], []
+    dropless = {"moe_local_rows": [], "moe_load_max_over_mean": [],
+                "moe_rows_dropped": []}
     for path, leaf in jax.tree_util.tree_leaves_with_path(intermediates):
         name = jax.tree_util.keystr(path)
         if "moe_drop_frac" in name:
             drops.append(leaf)
         elif "moe_expert_load" in name:
             loads.append(leaf)
+        for key, leaves in dropless.items():
+            if key in name:
+                leaves.append(leaf)
+    if dropless["moe_local_rows"]:
+        # the dropless layers' counters: token-choices that landed on
+        # held experts (all layers, a step), the worst layer's load of
+        # its busiest held expert over the mean, rows not computed (0)
+        return {
+            "moe_local_rows": jnp.stack(dropless["moe_local_rows"]).sum(),
+            "moe_load_max_over_mean": jnp.stack(
+                dropless["moe_load_max_over_mean"]).max(),
+            "moe_rows_dropped": jnp.stack(dropless["moe_rows_dropped"]).sum(),
+        }
     if not drops:
         return {}
     load = jnp.stack(loads).mean(0)
@@ -544,6 +572,11 @@ def make_lm_step_fns(
     # logical rule, so the manual attention cores see the local batch
     # shard instead of forcing an ep-fold replication at their boundary
     manual_spec = LM_MANUAL_ATTN_SPEC
+    if cfg.layer_types and cfg.attn_impl != "dense":
+        raise ValueError(
+            "layer_types (sliding and full layers mixed) is built for the "
+            "dense and flash cores; ring and Ulysses bind one window"
+        )
     if cfg.attn_impl == "ring":
         attn_core = make_ring_core(
             mesh, use_flash=bool(cfg.flash), window=cfg.attn_window
@@ -561,13 +594,22 @@ def make_lm_step_fns(
         # dense + flash: manual shard_map so the Pallas call sees the local
         # (batch, full seq, local heads) block — GSPMD cannot partition a
         # custom kernel, so it must live inside the manual region.
-        attn_core = jax.shard_map(
-            partial(flash_attention, causal=True, window=cfg.attn_window),
-            mesh=mesh,
-            in_specs=(manual_spec,) * 3,
-            out_specs=manual_spec,
-            check_vma=False,
-        )
+        def flash_core(window):
+            return jax.shard_map(
+                partial(flash_attention, causal=True, window=window),
+                mesh=mesh,
+                in_specs=(manual_spec,) * 3,
+                out_specs=manual_spec,
+                check_vma=False,
+            )
+
+        if cfg.layer_types:
+            # one core a kind of layer; Attention asks by its own window
+            cores = {w: flash_core(w) for w in {
+                cfg.layer_window(i) for i in range(cfg.n_layers)}}
+            attn_core = lambda q, k, v, window: cores[window](q, k, v)  # noqa: E731
+        else:
+            attn_core = flash_core(cfg.attn_window)
     else:
         attn_core = None
     model = TransformerLM(cfg, attn_core)
@@ -644,7 +686,8 @@ def make_lm_step_fns(
                 router = moe_router_metrics(col["intermediates"])
             else:
                 logits, aux = out
-        ce = _token_ce(logits, targets)
+        with jax.named_scope("head"):
+            ce = _token_ce(logits, targets)
         loss = ce + cfg.moe_aux_weight * aux
         metrics = {"loss": loss, "ce": ce, "moe_aux": aux, **router}
         return loss, (logits, metrics)

@@ -36,7 +36,7 @@ import numpy as np
 from ddl_tpu import checkpoint as ckpt
 from ddl_tpu.models.transformer import LMConfig
 from ddl_tpu.parallel.sharding import LMMeshSpec
-from ddl_tpu.train.lm_steps import make_lm_step_fns
+from ddl_tpu.train.lm_steps import STEP_PARTS, make_lm_step_fns
 from ddl_tpu.train.loop import BaseTrainer, _child, _phase
 from ddl_tpu.utils import MetricLogger, faultinject
 
@@ -243,8 +243,8 @@ class LMTrainer(BaseTrainer):
         capacity_factor_min docs for the measured warm-up/steady-state
         numbers."""
         cfg = self.cfg
-        if not cfg.num_experts:
-            return
+        if not cfg.num_experts or cfg.moe_router == "sigmoid":
+            return  # no experts, or a dropless layer: no capacity to anneal
         target = min(cfg.capacity_factor_min, cfg.capacity_factor)
         if cfg.capacity_factor <= target:
             return
@@ -501,7 +501,7 @@ class LMTrainer(BaseTrainer):
             # HBM ledger: stamp the train step's static memory budget
             # once, after its first dispatch (obs/hbm.py hbm_plan)
             self.emit_hbm_plan("train_step", self.fns.train,
-                               self.state, inp, tgt)
+                               self.state, inp, tgt, parts=STEP_PARTS)
             steps += 1
             faultinject.check_step(i, guard)
             if guard is not None and guard.requested:

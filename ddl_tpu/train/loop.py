@@ -195,7 +195,7 @@ class BaseTrainer:
         self._param_hbm_cache = tree_shard_bytes(params)
         return self._param_hbm_cache
 
-    def emit_hbm_plan(self, label: str, fn, *args, **kwargs) -> None:
+    def emit_hbm_plan(self, label: str, fn, *args, parts=None, **kwargs) -> None:
         """Stamp one ``hbm_plan`` static budget for a compiled program,
         once per label per trainer.  Families call it right AFTER the
         program's first dispatch (the run's own compile has happened;
@@ -203,7 +203,9 @@ class BaseTrainer:
         instead of racing the first step).  Costs one extra backend
         compile per program when the persistent cache is cold —
         ``DDL_HBM_PLAN=off`` disables, ``=aval`` keeps the cheap
-        shape-arithmetic budget without the executable analysis."""
+        shape-arithmetic budget without the executable analysis.
+        ``parts``: the family's own scopes, for the plan's second scope
+        table (``hbm.plan_program``)."""
         if self.obs is None:
             return
         if self._hbm_planned is None:
@@ -218,7 +220,7 @@ class BaseTrainer:
 
         hbm.plan_program(
             self.obs.writer, label, fn, args, kwargs,
-            mode="aval" if mode == "aval" else "full",
+            mode="aval" if mode == "aval" else "full", parts=parts,
         )
 
     def _emit_hbm_sample(self, step=None, context=None) -> None:

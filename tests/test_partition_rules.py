@@ -126,6 +126,40 @@ def test_lm_table_matches_logical_resolution(fsdp, moe):
 
 
 @pytest.mark.parametrize("fsdp", [False, True])
+def test_afmoe_table_matches_logical_resolution(fsdp):
+    """The gated block's leaves (attention gate, q/k norms, the three
+    expert banks, the shared expert, the selection bias) each have a
+    rule, and it is the one their logical annotations resolve to: a
+    four-chip cell does not start from an unsharded bank of experts."""
+    import flax.linen as nn
+
+    from ddl_tpu.models.transformer import LMConfig, TransformerLM
+
+    cfg = LMConfig(
+        vocab_size=512, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
+        head_dim=16, d_ff=192, compute_dtype="float32", fsdp=fsdp,
+        num_experts=8, expert_top_k=2, num_dense_layers=1,
+        moe_router="sigmoid", moe_d_ff=32, num_shared_experts=1,
+        layer_types=("sliding_attention", "full_attention"), attn_window=4,
+        qk_norm=True, attn_gate=True, mlp_gated=True,
+        sandwich_norm=True, embed_scale=True, expert_share=(1, 2),
+    )
+    abs_params = jax.eval_shape(
+        lambda r: TransformerLM(cfg, None).init(
+            r, jnp.zeros((4, 8), jnp.int32)
+        )["params"],
+        jax.random.key(0),
+    )
+    names = {R.tree_path_str(p) for p, _ in
+             jtu.tree_leaves_with_path(nn.meta.unbox(abs_params))}
+    for leaf in ("block0/attn/gate/kernel", "block0/attn/q_norm/scale",
+                 "block0/mlp/wg/kernel", "block1/moe/wg", "block1/moe/bias",
+                 "block1/moe/shared/wo/kernel", "block1/norm_post_mlp/scale"):
+        assert leaf in names
+    _assert_table_matches_logical(abs_params, R.lm_rules(fsdp), fsdp, _lm_mesh())
+
+
+@pytest.mark.parametrize("fsdp", [False, True])
 def test_vit_table_matches_logical_resolution(fsdp):
     from ddl_tpu.models.vit import ViT, ViTConfig
 

@@ -110,6 +110,34 @@ def test_flash_attention_fwd_and_grad(
     assert plan["flash_fwd"]["computed"] < plan["flash_fwd"]["total"]
 
 
+@pytest.mark.parametrize(
+    "k,n", [pytest.param(2048, 1024, id="gate-up"), pytest.param(1024, 2048, id="down")]
+)
+def test_grouped_matmul_fwd_and_both_grads(compile_for_chip, k, n):
+    """The dropless expert layer's products at the Trinity-Mini cell's
+    widths: 8 held experts, the buffer of 8192 x 8 choices' worst case."""
+    from ddl_tpu.obs.scope import kernel_tiles
+    from ddl_tpu.ops.grouped_matmul import ROW_TILE, buffer_rows, grouped_matmul
+
+    rows = buffer_rows(8192 * 8, 8)
+    tiles = rows // ROW_TILE
+    idx = _s((tiles,), jnp.int32)
+
+    def product(x, w, tg, ts, na):
+        return grouped_matmul(x, w, tg, ts, na, interpret=False)
+
+    args = (_s((rows, k), BF16), _s((8, k, n), F32), idx, idx, _s((1,), jnp.int32))
+    fwd = kernel_tiles(compile_for_chip(product, *args))
+    assert fwd == {"moe_gmm_fwd": {
+        "calls": 1, "total": tiles * (n // 512), "floor": 8 * (n // 512)}}
+    text = compile_for_chip(
+        jax.grad(lambda x, w, *t: _sum_f32(product(x, w, *t)), argnums=(0, 1)), *args
+    )
+    got = kernel_tiles(text)
+    assert got["moe_gmm_dx"] == {"calls": 1, "total": tiles * (k // 512), "floor": 8 * (k // 512)}
+    assert got["moe_gmm_dw"]["total"] == tiles * (k // 512) * (n // 512)
+
+
 @pytest.mark.parametrize("kv_heads", [12, 4], ids=["fused768", "fused256"])
 @pytest.mark.parametrize("cache_len", [1024, 8192])
 def test_decode_attention_bf16_and_int8(compile_for_chip, cache_len, kv_heads):

@@ -881,8 +881,10 @@ def dropless_plan(expert_idx, lo: int, held: int, tile: int):
     buffer row and ``row_valid`` (R,) whether one is; ``choice_row``
     (N, K) each choice's row and ``held`` (N, K) whether it has one;
     ``counts`` (held,) rows an expert; the grouped product's
-    ``tile_group``, ``tile_src``, ``n_active``."""
+    ``tile_group``, ``tile_src``, ``n_active``; ``pairs``, what the row
+    kernels walk (``ops/moe_rows.pair_plan``)."""
     from ddl_tpu.ops.grouped_matmul import align_groups, buffer_rows
+    from ddl_tpu.ops.moe_rows import pair_plan
 
     n, k = expert_idx.shape
     rows = buffer_rows(n * min(k, held), held, tile)
@@ -893,16 +895,18 @@ def dropless_plan(expert_idx, lo: int, held: int, tile: int):
     counts = (key[:, None] == jnp.arange(held)).sum(0, dtype=jnp.int32)
     start, tile_group, tile_src, n_active = align_groups(counts, rows // tile, tile)
     packed = jnp.cumsum(counts) - counts  # a run's start in sorted order
-    r = jnp.arange(rows, dtype=jnp.int32)
-    g = tile_group[r // tile]
-    pos = r - start[g]
-    row_valid = (pos < counts[g]) & (r // tile < n_active[0])
-    row_choice = jnp.where(
-        row_valid, order[jnp.minimum(packed[g] + pos, n * k - 1)], 0
-    )
-    rank = jnp.zeros(n * k, jnp.int32).at[order].set(
-        jnp.arange(n * k, dtype=jnp.int32)
-    )
+    # a tile's group places its rows: worked out a tile and spread over the
+    # tile's rows, and the permutation inverted by a sort (a gather or a
+    # scatter of 65,536 scalars costs the chip 0.3-0.55 ms, the sort 0.05;
+    # PERF.md section 6, PR 28)
+    t = jnp.arange(rows // tile, dtype=jnp.int32)
+    pos = (t * tile - start[tile_group])[:, None] + jnp.arange(tile, dtype=jnp.int32)
+    row_valid = (
+        (pos < counts[tile_group][:, None]) & (t < n_active[0])[:, None]
+    ).reshape(rows)
+    at = (packed[tile_group][:, None] + pos).reshape(rows)
+    row_choice = jnp.where(row_valid, order[jnp.minimum(at, n * k - 1)], 0)
+    rank = jnp.argsort(order).astype(jnp.int32)
     kc = jnp.minimum(key, held - 1)
     choice_row = jnp.where(is_held, start[kc] + rank - packed[kc], 0)
     return {
@@ -910,67 +914,78 @@ def dropless_plan(expert_idx, lo: int, held: int, tile: int):
         "choice_row": choice_row.reshape(n, k), "held": is_held.reshape(n, k),
         "counts": counts, "tile_group": tile_group, "tile_src": tile_src,
         "n_active": n_active,
+        "pairs": pair_plan(
+            row_choice // k, row_valid, n_active, tokens=n, groups=held, tile=tile
+        ),
     }
 
 
 @jax.custom_vjp
-def _rows_gather(x, row_token, row_valid, choice_row, held):
-    """``xs[r] = x[row_token[r]]`` where the buffer row holds a choice, 0
-    where it pads a run.  The VJP is gathers too: a token's gradient is
-    the sum over its held choices' rows (a TPU scatter-add never
-    appears; rows of unwritten tiles are never read)."""
-    return jnp.where(row_valid[:, None], jnp.take(x, row_token, axis=0), 0)
+def _rows_gather(x, plan):
+    """``xs[r] = x[token of r]`` where the buffer row holds a choice, 0
+    where it pads a run in an active tile; rows of other tiles stay
+    unwritten.  The VJP sums a token's held choices' rows.  Both walk the
+    plan's pairs and no further (``ops/moe_rows``)."""
+    from ddl_tpu.ops.moe_rows import rows_gather
+
+    return rows_gather(x, plan["pairs"], groups=plan["counts"].shape[0])
 
 
-def _rows_gather_fwd(x, row_token, row_valid, choice_row, held):
-    return _rows_gather(x, row_token, row_valid, choice_row, held), (choice_row, held)
+def _rows_gather_fwd(x, plan):
+    return _rows_gather(x, plan), plan
 
 
-def _rows_gather_bwd(res, g):
-    choice_row, held = res
-    dx = jnp.zeros((choice_row.shape[0], g.shape[1]), jnp.float32)
-    for j in range(choice_row.shape[1]):
-        picked = jnp.take(g, choice_row[:, j], axis=0).astype(jnp.float32)
-        dx = dx + jnp.where(held[:, j, None], picked, 0.0)
-    return dx.astype(g.dtype), None, None, None, None
+def _rows_gather_bwd(plan, g):
+    from ddl_tpu.ops.moe_rows import rows_combine
+
+    dx = rows_combine(
+        g, plan["pairs"], tokens=plan["held"].shape[0],
+        groups=plan["counts"].shape[0], out_dtype=g.dtype,
+        name="moe_rows_gather_bwd",
+    )
+    return dx, None
 
 
 _rows_gather.defvjp(_rows_gather_fwd, _rows_gather_bwd)
 
 
+def _row_weights(w, plan):
+    """Each buffer row's routing weight, laid out as the row kernels read
+    it: (row tiles, 1, tile) float32, 0 where the row holds no choice."""
+    picked = jnp.take(w.reshape(-1).astype(jnp.float32), plan["row_choice"])
+    return jnp.where(plan["row_valid"], picked, 0.0).reshape(
+        plan["pairs"]["tok"].shape
+    )
+
+
 @jax.custom_vjp
-def _rows_combine(o, w, choice_row, held, row_choice, row_valid):
+def _rows_combine(o, w, plan):
     """``y[t] = sum_k w[t, k] * o[choice_row[t, k]]`` over the held
     choices, in float32.  ``o``'s gradient is a gather through the
-    inverse map (``row_choice``), 0 on rows that hold no choice."""
-    y = jnp.zeros((w.shape[0], o.shape[1]), jnp.float32)
-    for j in range(w.shape[1]):
-        picked = jnp.take(o, choice_row[:, j], axis=0).astype(jnp.float32)
-        y = y + jnp.where(held[:, j, None], picked * w[:, j, None], 0.0)
-    return y
+    inverse map, 0 on rows that hold no choice; ``w``'s is each held
+    choice's row of ``o`` against the token's cotangent."""
+    from ddl_tpu.ops.moe_rows import rows_combine
+
+    return rows_combine(
+        o, plan["pairs"], tokens=w.shape[0], groups=plan["counts"].shape[0],
+        out_dtype=jnp.float32, weights=_row_weights(w, plan),
+    )
 
 
-def _rows_combine_fwd(o, w, choice_row, held, row_choice, row_valid):
-    y = _rows_combine(o, w, choice_row, held, row_choice, row_valid)
-    return y, (o, w, choice_row, held, row_choice, row_valid)
+def _rows_combine_fwd(o, w, plan):
+    return _rows_combine(o, w, plan), (o, w, plan)
 
 
 def _rows_combine_bwd(res, g):
-    o, w, choice_row, held, row_choice, row_valid = res
-    k = w.shape[1]
-    dw = jnp.stack([
-        jnp.where(
-            held[:, j],
-            (jnp.take(o, choice_row[:, j], axis=0).astype(jnp.float32) * g).sum(-1),
-            0.0,
-        )
-        for j in range(k)
-    ], axis=1)
-    scale = jnp.take(w.reshape(-1), row_choice)[:, None]
-    do = jnp.where(
-        row_valid[:, None], jnp.take(g, row_choice // k, axis=0) * scale, 0.0
+    from ddl_tpu.ops.moe_rows import rows_gather
+
+    o, w, plan = res
+    do, dots = rows_gather(
+        g, plan["pairs"], groups=plan["counts"].shape[0], out_dtype=o.dtype,
+        scale=_row_weights(w, plan), dot_with=o, name="moe_rows_combine_bwd",
     )
-    return do.astype(o.dtype), dw.astype(w.dtype), None, None, None, None
+    dw = jnp.where(plan["held"], jnp.take(dots.reshape(-1), plan["choice_row"]), 0.0)
+    return do, dw.astype(w.dtype), None
 
 
 _rows_combine.defvjp(_rows_combine_fwd, _rows_combine_bwd)
@@ -1315,19 +1330,13 @@ class MoeMlp(nn.Module):
         wo = bank("wo", (held, f, d), ("expert", "mlp", "embed"))
         with jax.named_scope("moe/dispatch"):
             plan = dropless_plan(idx.astype(jnp.int32), lo, held, ROW_TILE)
-            xs = _rows_gather(
-                flat.astype(dt), plan["row_choice"] // k, plan["row_valid"],
-                plan["choice_row"], plan["held"],
-            )
+            xs = _rows_gather(flat.astype(dt), plan)
         tiles = (plan["tile_group"], plan["tile_src"], plan["n_active"])
         with jax.named_scope("moe/experts"):
             h = _swiglu(grouped_matmul(xs, wg, *tiles), grouped_matmul(xs, wi, *tiles))
             o = grouped_matmul(h, wo, *tiles)
         with jax.named_scope("moe/combine"):
-            y = _rows_combine(
-                o, weights, plan["choice_row"], plan["held"],
-                plan["row_choice"], plan["row_valid"],
-            )
+            y = _rows_combine(o, weights, plan)
         with jax.named_scope("moe/shared"):
             if cfg.num_shared_experts:
                 y = y + Mlp(cfg, f * cfg.num_shared_experts, name="shared")(
@@ -1336,6 +1345,12 @@ class MoeMlp(nn.Module):
         counts = plan["counts"]
         rows = counts.sum()
         self.sow("intermediates", "moe_local_rows", rows.astype(jnp.float32))
+        # row tiles in use over the buffer's: the share of the worst case
+        # that the row kernels and the grouped products walk
+        self.sow(
+            "intermediates", "moe_buffer_fill",
+            plan["n_active"][0].astype(jnp.float32) / plan["tile_group"].shape[0],
+        )
         self.sow(
             "intermediates", "moe_load_max_over_mean",
             counts.max() * held / jnp.maximum(rows, 1).astype(jnp.float32),

@@ -635,9 +635,10 @@ def render_hbm(account: dict, job_id: str = "") -> str:
                 lines.append(f"    scope ({p.get('scope_file')}): {counts}")
             for kernel, n in (p.get("kernel_tiles") or {}).items():
                 if "computed" not in n:
-                    # a grouped product: its grid's worst case and the
-                    # fewest steps any routing leaves it (the steps
-                    # computed are the routing's, not the text's)
+                    # a grouped product or a row kernel of the dropless
+                    # shuffle: its grid's worst case and the fewest steps
+                    # any routing leaves it (the steps computed are the
+                    # routing's, not the text's)
                     lines.append(
                         f"    tiles {kernel}: {n.get('calls')} call(s), "
                         f"{n.get('total')} grid steps at most, "

@@ -142,7 +142,9 @@ class _CompileCounter:
 
 
 # step metrics of a dropless expert layer that the period event copies
-_DROPLESS_COUNTERS = ("moe_local_rows", "moe_load_max_over_mean", "moe_rows_dropped")
+_DROPLESS_COUNTERS = (
+    "moe_local_rows", "moe_load_max_over_mean", "moe_rows_dropped", "moe_buffer_fill",
+)
 
 
 class StepTrace:

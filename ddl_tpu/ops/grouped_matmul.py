@@ -18,11 +18,14 @@ do work only for those:
 A grid step past ``n_active`` maps every block to the last active
 tile's (no new DMA) and computes nothing, so time follows the rows
 really routed (plus a third of a microsecond a skipped step).  Rows of
-such tiles are **never written**: what reads the result masks them
-(``models/transformer.MoeMlp`` gathers only rows that a held choice
-points to).  Rows that pad a group inside an active tile must be zero in
-``x`` (forward) and in ``dy`` (backward): then they add nothing to
-``dw`` and come out as zeros.
+such tiles are **never written**, and what reads the result never reads
+them: the row kernels of ``ops/moe_rows`` (``moe_rows_combine`` the
+forward's result, ``moe_rows_gather_bwd`` ``dx``) walk only the pairs of
+row tiles that hold a routed row, as these kernels' own backward walks
+only active tiles of ``x`` and ``dy``.  Rows that pad a group inside an
+active tile must be zero in ``x`` (forward) and in ``dy`` (backward):
+``moe_rows_gather`` and ``moe_rows_combine_bwd`` write them so; then
+they add nothing to ``dw`` and come out as zeros.
 
 The design follows ``jax.experimental.pallas.ops.tpu.megablox`` in using
 scalar-prefetched group metadata to index the weight bank; aligning the

@@ -181,7 +181,7 @@ def moe_router_metrics(intermediates) -> dict:
     routing collapse."""
     drops, loads = [], []
     dropless = {"moe_local_rows": [], "moe_load_max_over_mean": [],
-                "moe_rows_dropped": []}
+                "moe_rows_dropped": [], "moe_buffer_fill": []}
     for path, leaf in jax.tree_util.tree_leaves_with_path(intermediates):
         name = jax.tree_util.keystr(path)
         if "moe_drop_frac" in name:
@@ -194,12 +194,14 @@ def moe_router_metrics(intermediates) -> dict:
     if dropless["moe_local_rows"]:
         # the dropless layers' counters: token-choices that landed on
         # held experts (all layers, a step), the worst layer's load of
-        # its busiest held expert over the mean, rows not computed (0)
+        # its busiest held expert over the mean, rows not computed (0),
+        # the share of the sorted buffer's row tiles in use (mean layer)
         return {
             "moe_local_rows": jnp.stack(dropless["moe_local_rows"]).sum(),
             "moe_load_max_over_mean": jnp.stack(
                 dropless["moe_load_max_over_mean"]).max(),
             "moe_rows_dropped": jnp.stack(dropless["moe_rows_dropped"]).sum(),
+            "moe_buffer_fill": jnp.stack(dropless["moe_buffer_fill"]).mean(),
         }
     if not drops:
         return {}

@@ -2,8 +2,10 @@
 ``TransformerLM`` built from ``LMConfig``'s per-layer fields against the
 plain reference ``benchmark/reference/afmoe.py`` on seeded weights, the
 dropless expert layer's share arithmetic and counters, the per-layer
-attention kinds, the grouped product against a ``jnp`` loop, the work
-functions against hand arithmetic, and the parts table of a compiled step.
+attention kinds, the grouped product against a ``jnp`` loop, the row
+kernels of the shuffle against the ``jnp.take`` gathers they replaced, the
+work functions against hand arithmetic, and the parts table of a compiled
+step.
 
 Sizes: d 64, 4 x 16 heads, 2 K/V heads, 8 experts top-2, 1 shared, window
 8, T 32, layers dense-sliding, sliding, sliding, sliding, full.
@@ -29,10 +31,12 @@ from benchmark.families.common import flatten, unflatten_like  # noqa: E402
 from benchmark.reference import afmoe as ref  # noqa: E402
 from benchmark.reference import common as refcommon  # noqa: E402
 from benchmark.work import afmoe as work  # noqa: E402
+from ddl_tpu.models import transformer  # noqa: E402
 from ddl_tpu.models.transformer import (  # noqa: E402
     Block, LMConfig, MoeMlp, TransformerLM, dropless_plan,
 )
 from ddl_tpu.ops import grouped_matmul as gm  # noqa: E402
+from ddl_tpu.ops import moe_rows  # noqa: E402
 
 F32 = refcommon.caster("f32")
 
@@ -297,6 +301,186 @@ def test_grouped_product_against_a_loop(counts):
     assert gw.dtype == w.dtype
 
 
+# ------------------------------------------------------------ the row kernels
+
+# The gathers as the package had them before the row kernels (ISSUE 28): a
+# ``jnp.take`` over the whole buffer or over every choice, masked after.
+
+
+def take_gather(x, plan, k):
+    return jnp.where(plan["row_valid"][:, None], jnp.take(x, plan["row_choice"] // k, axis=0), 0)
+
+
+def take_gather_bwd(g, plan):
+    choice_row, held = plan["choice_row"], plan["held"]
+    dx = jnp.zeros((choice_row.shape[0], g.shape[1]), jnp.float32)
+    for j in range(choice_row.shape[1]):
+        picked = jnp.take(g, choice_row[:, j], axis=0).astype(jnp.float32)
+        dx = dx + jnp.where(held[:, j, None], picked, 0.0)
+    return dx.astype(g.dtype)
+
+
+def take_combine(o, w, plan):
+    choice_row, held = plan["choice_row"], plan["held"]
+    y = jnp.zeros((w.shape[0], o.shape[1]), jnp.float32)
+    for j in range(w.shape[1]):
+        picked = jnp.take(o, choice_row[:, j], axis=0).astype(jnp.float32)
+        y = y + jnp.where(held[:, j, None], picked * w[:, j, None], 0.0)
+    return y
+
+
+def take_combine_bwd(o, w, plan, g):
+    choice_row, held = plan["choice_row"], plan["held"]
+    k = w.shape[1]
+    dw = jnp.stack([
+        jnp.where(held[:, j],
+                  (jnp.take(o, choice_row[:, j], axis=0).astype(jnp.float32) * g).sum(-1), 0.0)
+        for j in range(k)
+    ], axis=1)
+    scale = jnp.take(w.reshape(-1), plan["row_choice"])[:, None]
+    do = jnp.where(plan["row_valid"][:, None],
+                   jnp.take(g, plan["row_choice"] // k, axis=0) * scale, 0.0)
+    return do.astype(o.dtype), dw.astype(w.dtype)
+
+
+ROUTINGS = ["uniform", "none_held", "one_expert", "all_held", "on_the_tile"]
+
+
+def routing(kind, n=48, k=2, experts=8, lo=2, held=3, tile=8):
+    """(n, k) choices over ``experts`` of which ``[lo, lo + held)`` are
+    held: the seed's uniform lot, no choice held, every token's first
+    choice on one held expert, every choice held (k = held: the buffer
+    full), and runs that end exactly on a tile boundary."""
+    if kind == "uniform":
+        idx = jnp.argsort(jax.random.uniform(jax.random.key(4), (n, experts)), axis=1)[:, :k]
+    elif kind == "none_held":
+        idx = jnp.tile(jnp.array([0, lo + held]), (n, 1))
+    elif kind == "one_expert":
+        idx = jnp.tile(jnp.array([lo + 1, 0]), (n, 1))
+    elif kind == "all_held":
+        k = held
+        idx = jnp.tile(jnp.arange(lo, lo + held), (n, 1))
+    else:  # tile rows on each of two held experts, none on the third
+        idx = jnp.where((jnp.arange(n) < tile)[:, None], jnp.array([lo, lo + 2]), jnp.array([0, 1]))
+    return dropless_plan(idx.astype(jnp.int32), lo, held, tile), n, k
+
+
+def valid_rows(plan, x):
+    return jnp.where(plan["row_valid"][:, None], x, 0)
+
+
+def buffer_like(plan, key, d, dtype):
+    """A buffer as the grouped products leave it: values where a row
+    holds a choice, zeros where it pads an active tile."""
+    rows = plan["row_valid"].shape[0]
+    return valid_rows(plan, jax.random.normal(key, (rows, d), jnp.float32)).astype(dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("kind", ROUTINGS)
+def test_row_kernels_against_the_take_gathers(kind, dtype):
+    """Values and every VJP of ``_rows_gather`` and ``_rows_combine``
+    (interpret mode) against the ``jnp.take`` formulations, compared where
+    a buffer row is written (an active tile): exact for the 0/1
+    selections, float32 rounding for the weighted sums."""
+    plan, n, k = routing(kind)
+    d = 16
+    x = jax.random.normal(jax.random.key(0), (n, d), jnp.float32).astype(dtype)
+    w = jax.random.uniform(jax.random.key(1), (n, k), jnp.float32, 0.1, 1.0)
+    o = buffer_like(plan, jax.random.key(2), d, dtype)
+    g_rows = buffer_like(plan, jax.random.key(3), d, dtype)
+    g_tok = jax.random.normal(jax.random.key(5), (n, d), jnp.float32)
+    tile = plan["pairs"]["tok"].shape[-1]
+    written = jnp.repeat(jnp.arange(o.shape[0] // tile) < plan["n_active"][0], tile)[:, None]
+    assert bool((plan["row_valid"][:, None] <= written).all())
+
+    xs, gather_vjp = jax.vjp(lambda x: transformer._rows_gather(x, plan), x)
+    assert xs.dtype == dtype
+    np.testing.assert_array_equal(jnp.where(written, xs, 0), take_gather(x, plan, k))
+    (dx,) = gather_vjp(g_rows)
+    assert dx.dtype == dtype
+    np.testing.assert_allclose(dx.astype(jnp.float32), take_gather_bwd(g_rows, plan).astype(jnp.float32),
+                               rtol=1e-2 if dtype == jnp.bfloat16 else 1e-6, atol=1e-6)
+
+    y, combine_vjp = jax.vjp(lambda o, w: transformer._rows_combine(o, w, plan), o, w)
+    assert y.dtype == jnp.float32
+    np.testing.assert_allclose(y, take_combine(o, w, plan), rtol=1e-6, atol=1e-6)
+    do, dw = combine_vjp(g_tok)
+    want_do, want_dw = take_combine_bwd(o, w, plan, g_tok)
+    assert do.dtype == dtype and dw.dtype == w.dtype
+    np.testing.assert_allclose(jnp.where(written, do, 0).astype(jnp.float32),
+                               want_do.astype(jnp.float32),
+                               rtol=1e-2 if dtype == jnp.bfloat16 else 1e-6, atol=1e-6)
+    np.testing.assert_allclose(dw, want_dw, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("kind", ROUTINGS)
+def test_the_pairs_cover_every_routed_row_and_stay_in_their_bound(kind):
+    plan, n, k = routing(kind)
+    pairs = plan["pairs"]
+    tile = pairs["tok"].shape[-1]
+    tt = moe_rows.token_tile(n, tile)
+    tok = np.asarray(pairs["tok"]).reshape(-1, tile)
+    pi, pj, used = (np.asarray(a) for a in pairs["by_row"])
+    assert len(pi) == moe_rows.pairs_bound(tok.shape[0], n // tt, plan["counts"].shape[0])
+    listed = set(zip(pi[:used[0]].tolist(), pj[:used[0]].tolist()))
+    assert len(listed) == used[0]  # no pair twice
+    shared = {(i, t // tt) for i in range(tok.shape[0]) for t in tok[i] if t >= 0}
+    assert shared <= listed
+    # every active row tile is written, no other is touched
+    assert {i for i, _ in listed} == set(range(int(plan["n_active"][0])))
+    assert (pi[used[0]:] == pi[used[0] - 1]).all() and (pj[used[0]:] == pj[used[0] - 1]).all()
+    qi, qj, entries = (np.asarray(a) for a in pairs["by_token"])
+    assert entries[0] == used[0] + n // tt
+    runs = qj[:entries[0]]
+    assert (np.diff(runs) >= 0).all() and set(runs.tolist()) == set(range(n // tt))
+    opens = np.r_[True, np.diff(runs) > 0]
+    assert sorted(zip(qi[:entries[0]][~opens].tolist(), runs[~opens].tolist())) == sorted(listed)
+
+
+def test_rows_of_inactive_tiles_are_never_read(monkeypatch):
+    """NaN in every row of the tiles past ``n_active`` of ``xs``, of the
+    grouped products' results (``o`` among them) and of the cotangents that
+    come back to them (``do`` among them): the layer's output and every
+    gradient are finite and equal to the run without the poison."""
+    model = small_model(2, 1)
+    p, x = layer_weights(model)
+    cfg = lm_config(model)
+    seen = []
+
+    def spoiling(fn, n_active_of, poison):
+        def spoil(a, n_active):  # what an unwritten tile may hold
+            tiles = jnp.arange(a.shape[0]) // gm.ROW_TILE
+            seen.append(a.shape[0] // gm.ROW_TILE - n_active[0])
+            return jnp.where((tiles >= n_active[0])[:, None], poison, a)
+
+        @jax.custom_vjp
+        def spoiled(a, n_active):
+            return spoil(a, n_active)
+
+        spoiled.defvjp(lambda a, n: (spoil(a, n), n), lambda n, g: (spoil(g, n), None))
+        return lambda *args, **kw: spoiled(fn(*args, **kw), n_active_of(*args))
+
+    def run(poison):
+        with monkeypatch.context() as m:
+            m.setattr(transformer, "_rows_gather", spoiling(
+                transformer._rows_gather, lambda x, plan: plan["n_active"], poison))
+            m.setattr(gm, "grouped_matmul", spoiling(
+                gm.grouped_matmul, lambda x, w, tg, ts, na: na, poison))
+
+            def loss(params, x):
+                y, _ = MoeMlp(cfg).apply({"params": params}, x, mutable=["intermediates"])
+                return jnp.sum(y[0] ** 2), y[0]
+
+            return jax.value_and_grad(loss, (0, 1), has_aux=True)(as_tree(p), x)
+
+    clean, dirty = run(0.0), run(jnp.nan)
+    assert min(int(n) for n in seen) >= 1  # a tile was there to poison
+    for a, b in zip(jax.tree.leaves(clean), jax.tree.leaves(dirty)):
+        assert bool(jnp.isfinite(b).all())
+        np.testing.assert_array_equal(a, b)
+
+
 # ------------------------------------------------------------------- work
 
 
@@ -454,6 +638,7 @@ def test_parts_table_of_a_compiled_step_names_every_tag(tmp_path):
     state, m = fns.train(state, tok, tok)
     assert float(m["moe_rows_dropped"]) == 0.0 and float(m["moe_local_rows"]) > 0
     assert float(m["moe_load_max_over_mean"]) >= 1.0
+    assert 0.0 < float(m["moe_buffer_fill"]) <= 1.0  # row tiles in use over the buffer's
 
 
 def test_a_kernel_takes_the_part_it_is_called_in():
@@ -466,15 +651,20 @@ ENTRY %main () -> f32[] {
   %p = bf16[8,8]{1,0} parameter(0)
   %moe_gmm_fwd.1 = bf16[8,8]{1,0} custom-call(%p), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/jvp(LM)/block1/moe/moe._dropless/moe/experts/moe_gmm_fwd/pallas_call"}
   %flash.2 = bf16[8,8]{1,0} custom-call(%p), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/transpose(jvp(LM))/block1/attn/flash_bwd_dq/pallas_call"}
+  %rows.6 = bf16[8,8]{1,0} custom-call(%p), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/jvp(LM)/block1/moe/moe._dropless/moe/dispatch/jit(_gather)/moe_rows_gather/pallas_call"}
+  %rows.7 = bf16[8,8]{1,0} custom-call(%rows.6), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/transpose(jvp(LM))/block1/moe/moe._dropless/moe/combine/jit(_gather)/moe_rows_combine_bwd/pallas_call"}
   %f.3 = f32[] fusion(%flash.2), kind=kLoop, metadata={op_name="jit(step)/jvp(LM)/head/reduce_sum"}
   %f.4 = f32[] fusion(%f.3), kind=kLoop, metadata={op_name="jit(step)/jvp(LM)/lm_head/dot_general"}
   ROOT %f.5 = f32[] fusion(%f.4), kind=kLoop, metadata={op_name="jit(step)/adam/mul"}
 }
 """
     assert scope.parts_table(text, STEP_PARTS) == {
-        "moe_gmm_fwd.1": "moe/experts", "flash.2": "attn", "f.3": "head", "f.4": "head",
-        "f.5": "other"}
-    assert scope.scope_table(text)["moe_gmm_fwd.1"] == "kernel/moe_gmm_fwd"
+        "moe_gmm_fwd.1": "moe/experts", "flash.2": "attn", "rows.6": "moe/dispatch",
+        "rows.7": "moe/combine", "f.3": "head", "f.4": "head", "f.5": "other"}
+    direction = scope.scope_table(text)
+    assert direction["moe_gmm_fwd.1"] == "kernel/moe_gmm_fwd"
+    assert (direction["rows.6"], direction["rows.7"]) == (
+        "kernel/moe_rows_gather", "kernel/moe_rows_combine_bwd")
     # a program that opens none of the caller's scopes, or a caller with
     # none, has no second table
     assert scope.parts_table(text, {"encoder": "encoder"}) == {}
@@ -489,13 +679,13 @@ def test_the_period_event_carries_the_dropless_counters(tmp_path):
     trace.begin_period(0)
     trace.end_period(0, 5, elapsed=1.0, steps=5, metrics={
         "loss": 9.5, "ce": 9.5, "moe_local_rows": 16384.0,
-        "moe_load_max_over_mean": 1.25, "moe_rows_dropped": 0.0})
+        "moe_load_max_over_mean": 1.25, "moe_rows_dropped": 0.0, "moe_buffer_fill": 0.09})
     trace.begin_period(1)
     trace.end_period(1, 10, elapsed=1.0, steps=5, metrics={"loss": 9.4, "moe_aux": 0.0})
     w.close()
     first, second = [e for e in read_events(w.path) if e["kind"] == "period"]
     assert (first["moe_local_rows"], first["moe_load_max_over_mean"],
-            first["moe_rows_dropped"]) == (16384.0, 1.25, 0.0)
+            first["moe_rows_dropped"], first["moe_buffer_fill"]) == (16384.0, 1.25, 0.0, 0.09)
     assert first["loss"] == 9.5 and "ce" not in first
     assert not [k for k in second if k.startswith("moe_")]
 

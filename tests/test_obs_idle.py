@@ -472,7 +472,8 @@ def test_obs_hbm_prints_the_events_scope_counts_and_file(tmp_path):
            output_bytes=4096, temp_bytes=512, alias_bytes=0, code_bytes=64,
            scope_counts={"bwd": 7, "fwd": 5, "kernel/flash_fwd": 1, "update": 2},
            scope_file="scope-h000-train_step.json",
-           kernel_tiles={"flash_fwd": {"calls": 12, "computed": 23040, "masked": 9216, "total": 36864}})
+           kernel_tiles={"flash_fwd": {"calls": 12, "computed": 23040, "masked": 9216, "total": 36864},
+                         "moe_rows_gather": {"calls": 4, "total": 2048, "floor": 32}})
     w.emit("hbm_plan", label="eval_step", analysis="aval", argument_bytes=64, output_bytes=8)
     w.emit("hbm_sample", params_bytes=600, watermark=2000, peak=2000, limit=4096, synthetic=True)
     w.close()
@@ -482,7 +483,9 @@ def test_obs_hbm_prints_the_events_scope_counts_and_file(tmp_path):
     assert out.count("scope (") == 1  # a plan without a table gets no such line
     assert ("tiles flash_fwd: 12 call(s), 23040 of 36864 sub-tiles computed (62.5%), 9216 masked"
             in out)
-    assert out.count("    tiles ") == 1
+    # a kernel whose steps the routing decides says its grid's two ends
+    assert "tiles moe_rows_gather: 4 call(s), 2048 grid steps at most, 32 at least" in out
+    assert out.count("    tiles ") == 2
 
 
 # the benchmark's cases (tests/benchmark: test_trace_reduction_on_a_hand_built_trace)

@@ -138,6 +138,43 @@ def test_grouped_matmul_fwd_and_both_grads(compile_for_chip, k, n):
     assert got["moe_gmm_dw"]["total"] == tiles * (k // 512) * (n // 512)
 
 
+def test_moe_row_kernels_fwd_and_grads(compile_for_chip, monkeypatch):
+    """The dropless shuffle's four row kernels at the Trinity-Mini cell's
+    shapes: a 67,584 x 2,048 bf16 buffer, 8,192 tokens, top-8, 8 held
+    experts; what each says of its grid in ``kernel_tiles``."""
+    from ddl_tpu.models.transformer import _rows_combine, _rows_gather, dropless_plan
+    from ddl_tpu.obs.scope import kernel_tiles
+    from ddl_tpu.ops import moe_rows
+    from ddl_tpu.ops.grouped_matmul import ROW_TILE, buffer_rows
+    from ddl_tpu.ops.moe_rows import pairs_bound
+
+    # the layer's own entry points resolve the backend: here that is the CPU
+    monkeypatch.setattr(moe_rows, "interpret_default", lambda: False)
+
+    tokens, k, held, d = 8192, 8, 8, 2048
+    rows = buffer_rows(tokens * k, held)
+    assert rows == 67584
+    token_tiles = tokens // ROW_TILE
+    pairs = pairs_bound(rows // ROW_TILE, token_tiles, held)
+
+    def shuffle(x, w, idx):
+        plan = dropless_plan(idx, 0, held, ROW_TILE)
+        return _rows_combine(_rows_gather(x, plan), w, plan)
+
+    args = (_s((tokens, d), BF16), _s((tokens, k), F32), _s((tokens, k), jnp.int32))
+    row_side = {"calls": 1, "total": pairs, "floor": held}
+    token_side = {"calls": 1, "total": pairs + token_tiles, "floor": held + token_tiles}
+    assert kernel_tiles(compile_for_chip(shuffle, *args)) == {
+        "moe_rows_combine": token_side, "moe_rows_gather": row_side}
+    text = compile_for_chip(
+        jax.grad(lambda x, w, idx: _sum_f32(shuffle(x, w, idx)), argnums=(0, 1)), *args
+    )
+    # the sum's gradient does not need the combine's value: its forward is gone
+    assert kernel_tiles(text) == {
+        "moe_rows_combine_bwd": row_side, "moe_rows_gather": row_side,
+        "moe_rows_gather_bwd": token_side}
+
+
 @pytest.mark.parametrize("kv_heads", [12, 4], ids=["fused768", "fused256"])
 @pytest.mark.parametrize("cache_len", [1024, 8192])
 def test_decode_attention_bf16_and_int8(compile_for_chip, cache_len, kv_heads):

@@ -36,17 +36,19 @@ for ``--chips 4``); such a run never ends in ``"ok": true``.
 
 Standard output: whatever the entry points print, one JSON object per
 phase (seconds split into compile and run, first and last loss or the
-tokens checked, compile-cache hits and misses, ``peak_bytes_in_use``, the
-kernels found in the compiled text), and as the LAST line the device as
-JAX reports it: ``{"ok": true, "device": {"platform": "tpu", "kind":
-"...", "count": 1}}``.  Exit code 0 only when every phase passed on a
-TPU at full size.  Logs and snapshots go under ``chiprun_out/`` (the
-chip tool's output directory), never into tracked paths.
+tokens checked with their hash and rate, compile-cache hits and misses,
+``peak_bytes_in_use``, the kernels found in the compiled text), and as
+the LAST line the device as JAX reports it: ``{"ok": true, "device":
+{"platform": "tpu", "kind": "...", "count": 1}}``.  Exit code 0 only when
+every phase passed on a TPU at full size.  Logs and snapshots go under
+``chiprun_out/`` (the chip tool's output directory), never into tracked
+paths.
 """
 
 from __future__ import annotations
 
 import argparse
+import hashlib
 import json
 import os
 import shutil
@@ -408,9 +410,16 @@ def phase_serve(rep: PhaseReport, size: str) -> None:
     for c in clients:  # together, so continuous batching is exercised
         engine.submit(c["prompt"], c["max_new"], request_id=c["id"],
                       rng_seed=SEED)
-    results = engine.run()
+    t0 = time.perf_counter()
+    results = engine.run()  # numpy out: the device has finished
+    run_s = time.perf_counter() - t0
+    streams = [np.asarray(results[c["id"]], np.int32) for c in clients]
     st = engine.stats
     rep.fields.update(
+        # for a comparison of two trees on one chip: the same seed gives
+        # the same streams, and the rate is a lead, one run of 8 requests
+        tokens_sha256=hashlib.sha256(np.concatenate(streams).tobytes()).hexdigest(),
+        run_tok_per_s=round(sum(map(len, streams)) / run_s, 1),
         precompiled=pre,
         completed=st["completed"],
         prefill_compiles=st["prefill_compiles"],

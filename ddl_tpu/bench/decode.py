@@ -128,14 +128,14 @@ def _bench_one(
     # rolling auto-mode); read the real allocation from init_kv_cache so
     # the reported bytes cannot drift from what the generator builds —
     # including the int8 + f32-scale layout of the quantized cache
-    from ddl_tpu.infer.decode import init_kv_cache
+    from ddl_tpu.infer.kv_cache import init_kv_cache
 
     rolling = bool(window) and window < capacity
     layer0 = jax.eval_shape(
         lambda: init_kv_cache(
             cfg, batch, capacity, rolling=rolling, quant=kv_quant
         )
-    )[0]
+    )[0].kv
     alloc = layer0[0].shape[1]
     layer_bytes = sum(
         int(np.prod(a.shape)) * a.dtype.itemsize

@@ -67,7 +67,7 @@ def main() -> None:
     ap.add_argument("--steps", type=int, default=10)
     ap.add_argument("--top", type=int, default=25)
     ap.add_argument("--impl", default="packed",
-                    choices=("concat", "buffer", "packed", "fused"))
+                    choices=("concat", "packed", "fused"))
     ap.add_argument("--trace-dir", default=None,
                     help="reuse an existing trace instead of capturing")
     args = ap.parse_args()

@@ -60,9 +60,7 @@ class ModelConfig:
     # for A/B timing on real hardware.
     pallas_normalize: bool = False
     # How dense blocks materialise their concatenative skips: "concat"
-    # (textbook jnp.concatenate per layer), "buffer" (memory-efficient:
-    # one preallocated per-block feature buffer, layers write their
-    # growth-rate strip in place), "packed" (TPU-native: lane-aligned
+    # (textbook jnp.concatenate per layer), "packed" (TPU-native: lane-aligned
     # 128-channel feature packs, implicit concat via per-pack 1x1-conv
     # contraction, per-pack batch stats computed once — see
     # models/densenet.py PackedDenseBlock and PERF.md), or "fused"

@@ -34,13 +34,13 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding
 
+from ddl_tpu.infer.kv_cache import init_kv_cache
 from ddl_tpu.models.transformer import (
     Block,
     LMConfig,
     apply_final_norm_and_head,
     make_embed,
 )
-from ddl_tpu.ops.quant import QuantKV
 # Jit-boundary spec + the family rule table come from the partition-rule
 # engine (parallel/rules.py); re-exported here for the generator's
 # callers.
@@ -53,21 +53,24 @@ from ddl_tpu.parallel.sharding import (
     validate_kv_head_sharding,
 )
 
-__all__ = ["LMDecode", "DECODE_TOKEN_SPEC", "init_kv_cache", "make_lm_generator"]
+__all__ = [
+    "LMDecode", "DECODE_TOKEN_SPEC", "init_kv_cache", "make_lm_generator",
+    "prefill_attn_core", "sample_token",
+]
 
 
 class LMDecode(nn.Module):
     """One incremental forward over the full layer stack.
 
     ``tokens`` (B, T) — the prompt at prefill (T = prompt length) or the
-    last sampled token during decode (T = 1); ``caches`` — per-layer
-    ``(k, v)`` tuples; ``offset`` — positions already in the cache.
-    Returns (logits (B, T, V) f32, new caches).  Submodule names mirror
-    ``TransformerLM`` exactly, so the training param tree applies as-is.
+    last sampled token during decode (T = 1); ``caches`` — one cache
+    object a layer (``infer/kv_cache.py``, ``serve/kv_pool.PagedKV``),
+    which knows the positions it already holds.  Returns (logits
+    (B, T, V) f32, new caches).  Submodule names mirror ``TransformerLM``
+    exactly, so the training param tree applies as-is.
     """
 
     cfg: LMConfig
-    rolling: bool = False  # ring cache of capacity attn_window
     # attention core for the PREFILL pass only (e.g. the flash kernel —
     # prefill is a training-style causal forward over the prompt); decode
     # steps (T=1) always use cached dense attention.
@@ -75,8 +78,7 @@ class LMDecode(nn.Module):
 
     @nn.compact
     def __call__(
-        self, tokens, caches, offset, last_only: bool = False,
-        last_index=None,
+        self, tokens, caches, last_only: bool = False, last_index=None,
     ):
         cfg = self.cfg
         x = make_embed(cfg)(tokens)
@@ -84,7 +86,7 @@ class LMDecode(nn.Module):
         new_caches = []
         for i in range(cfg.n_layers):
             x, _aux, c = Block(cfg, self.attn_core, i, name=f"block{i}")(
-                x, caches[i], offset, rolling=self.rolling
+                x, caches[i]
             )
             new_caches.append(c)
         if last_index is not None:
@@ -101,47 +103,35 @@ class LMDecode(nn.Module):
         return apply_final_norm_and_head(cfg, x), tuple(new_caches)
 
 
-def init_kv_cache(
-    cfg: LMConfig, batch: int, max_len: int, dtype=None,
-    rolling: bool = False, quant: bool = False,
-) -> tuple:
-    """Per-layer zeroed ``(k, v)`` buffers of shape (B, L, Hkv*Dh).
+def prefill_attn_core(cfg: LMConfig, mesh, prompt_len: int):
+    """The attention core of a prefill over ``prompt_len`` tokens: prefill
+    is a training-style causal forward over the prompt, so it rides the
+    flash kernel where training would (single-device mesh: GSPMD cannot
+    partition a Pallas custom call, and multi-device decode keeps the
+    dense prefill core inside its sharded program).  None = dense."""
+    if mesh.size == 1 and cfg.causal and (
+        cfg.flash is True
+        or (cfg.flash == "auto" and prompt_len >= FLASH_AUTO_MIN_T)
+    ):
+        from ddl_tpu.ops.flash_attention import flash_attention
 
-    ``L`` is ``max_len``, or ``min(max_len, attn_window)`` with
-    ``rolling=True`` — the ring cache holds only the window, so a
-    windowed generation's cache memory is O(window) regardless of
-    ``max_len`` (pair with ``LMDecode(rolling=True)``).
+        return partial(flash_attention, causal=True, window=cfg.attn_window)
+    return None
 
-    With grouped-query attention (``cfg.n_kv_heads``) the cache holds only
-    the K/V heads — an ``n_heads/n_kv_heads``-times smaller buffer, which
-    is GQA's decode-bandwidth win (the grouped ``dense_attention`` reads it
-    without re-materialising full heads).
 
-    ``quant=True`` allocates ``ops.quant.QuantKV`` leaves instead: int8
-    K/V plus per-(token, head) f32 scales — ~0.53x the bf16 bytes, the
-    KV half of the int8 serving path (attention quantizes on write and
-    reads the int8 buffers directly)."""
-    if rolling and not cfg.attn_window:
-        raise ValueError("rolling cache requires cfg.attn_window > 0")
-    if quant and dtype is not None:
-        raise ValueError(
-            "quant=True fixes the cache layout (int8 + f32 scales); "
-            "dtype cannot be combined with it"
-        )
-    dtype = dtype or cfg.dtype
-    length = min(max_len, cfg.attn_window) if rolling else max_len
-    # storage fuses (Hkv, Dh) -> Hkv*Dh so XLA's layout keeps the feature
-    # dim in lanes and the per-token cache write is in place
-    # (ops/quant.kv_fuse); readers unfuse at the attention einsum
-    shape = (batch, length, cfg.kv_heads * cfg.head_dim)
-    if quant:
-        q = jnp.zeros(shape, jnp.int8)
-        # scales keep L minor: the decode kernel reads one aligned (L,)
-        # lane vector per head (ops/quant.QuantKV)
-        s = jnp.zeros((batch, cfg.kv_heads, length), jnp.float32)
-        return tuple(QuantKV(q, s, q, s) for _ in range(cfg.n_layers))
-    zero = jnp.zeros(shape, dtype)
-    return tuple((zero, zero) for _ in range(cfg.n_layers))
+def sample_token(logits, rng, temperature: float, top_k: int | None):
+    """(..., V) logits -> int32 tokens: argmax at ``temperature`` 0, else
+    a draw from ``softmax(logits / temperature)`` over the ``top_k`` most
+    likely.  One body for the generator's batch and the engine's lanes
+    (vmapped there, a key a lane)."""
+    if temperature == 0.0:
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    if top_k is not None:
+        kth = lax.top_k(logits, top_k)[0][..., -1:]
+        logits = jnp.where(logits < kth, -jnp.inf, logits)
+    return jax.random.categorical(
+        rng, logits / jnp.float32(temperature), axis=-1
+    ).astype(jnp.int32)
 
 
 def make_lm_generator(
@@ -249,40 +239,19 @@ def make_lm_generator(
     if mesh is None:
         mesh = build_lm_mesh(spec or LMMeshSpec(), devices)
     rules = lm_logical_rules(cfg.fsdp)
-    # Prefill is a training-style causal forward over the prompt, so it
-    # can ride the flash kernel where training would (single-device mesh:
-    # GSPMD cannot partition a Pallas custom call, and multi-device decode
-    # keeps the dense prefill core inside its sharded program).
-    attn_core = None
-    if mesh.size == 1 and cfg.causal and (
-        cfg.flash is True
-        or (cfg.flash == "auto" and prompt_len >= FLASH_AUTO_MIN_T)
-    ):
-        from ddl_tpu.ops.flash_attention import flash_attention
-
-        attn_core = partial(
-            flash_attention, causal=True, window=cfg.attn_window
-        )
-    model = LMDecode(cfg, rolling=rolling, attn_core=attn_core)
-
-    def sample(logits, rng):
-        if temperature == 0.0:
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        if top_k is not None:
-            kth = lax.top_k(logits, top_k)[0][..., -1:]
-            logits = jnp.where(logits < kth, -jnp.inf, logits)
-        return jax.random.categorical(
-            rng, logits / jnp.float32(temperature), axis=-1
-        ).astype(jnp.int32)
+    model = LMDecode(
+        cfg, attn_core=prefill_attn_core(cfg, mesh, prompt_len)
+    )
+    sample = partial(sample_token, temperature=temperature, top_k=top_k)
 
     def make_step(params):
-        def step(carry, i):
+        def step(carry, _):
             last, caches, rng = carry
             rng, sub = jax.random.split(rng)
             tok = sample(last, sub)
             with nn.logical_axis_rules(rules):
                 logits, caches = model.apply(
-                    {"params": params}, tok[:, None], caches, prompt_len + i
+                    {"params": params}, tok[:, None], caches
                 )
             return (logits[:, 0], caches, rng), tok
 
@@ -296,10 +265,10 @@ def make_lm_generator(
         )
         with nn.logical_axis_rules(rules):
             logits, caches = model.apply(
-                {"params": params}, prompt, caches, 0, last_only=True
+                {"params": params}, prompt, caches, last_only=True
             )
         last = logits[:, -1]
-        (last, caches, rng), tok0 = make_step(params)((last, caches, rng), 0)
+        (last, caches, rng), tok0 = make_step(params)((last, caches, rng), None)
         return tok0, last, caches, rng
 
     def _rest(params, tok0, last, caches, rng):
@@ -307,7 +276,7 @@ def make_lm_generator(
         one fused prefill+scan program, so the two-program split is
         token-identical to the fused path."""
         (_, _, _), toks = lax.scan(
-            make_step(params), (last, caches, rng), jnp.arange(1, max_new)
+            make_step(params), (last, caches, rng), None, length=max_new - 1
         )
         return jnp.concatenate([tok0[:, None], toks.T], axis=1)
 

@@ -457,16 +457,12 @@ def _bn(dtype, name: str):
 
 
 class DenseLayer(nn.Module):
-    """Bottleneck layer: BN-ReLU-Conv1x1(bn_size*k) -> BN-ReLU-Conv3x3(k).
-
-    ``concat_output=False`` returns only the new ``growth_rate`` feature
-    maps (the buffer-based block writes them into its preallocated
-    feature buffer); the parameter tree is identical either way."""
+    """Bottleneck layer: BN-ReLU-Conv1x1(bn_size*k) -> BN-ReLU-Conv3x3(k),
+    concatenated onto its input."""
 
     growth_rate: int
     bn_size: int
     dtype: Any = jnp.float32
-    concat_output: bool = True
 
     @nn.compact
     def __call__(self, x, train: bool):
@@ -493,31 +489,18 @@ class DenseLayer(nn.Module):
             kernel_init=_conv_init,
             name="conv2",
         )(h)
-        if not self.concat_output:
-            return h
         return jnp.concatenate([x, h], axis=-1)
 
 
 class DenseBlock(nn.Module):
-    """A run of dense layers.  ``impl`` picks how the concatenative skip
-    connections materialise (same math, same parameter tree, different
-    memory traffic — PERF.md 'DenseNet dense-block memory'):
-
-    * ``"concat"`` — the textbook form: every layer concatenates its 32
-      new channels onto the running features, copying all C prior
-      channels per layer (O(L^2) channel-writes per block).
-    * ``"buffer"`` — the memory-efficient-DenseNet form (Pleiss et al.
-      2017): the block's full (B, H, W, C_in + L*k) feature buffer is
-      allocated once; each layer reads the first-C slice and writes only
-      its own k-channel strip (``lax.dynamic_update_slice``).
-
-    Measured on one v5e chip (PERF.md): "buffer" is ~2x SLOWER than
-    "concat" for the full bs-30 train step — XLA's copy-insertion does
-    NOT keep the update in place while the prefix slice is still live in
-    the same program (plus its transpose in the backward), so every
-    layer copies the whole buffer where concat copies only the prefix.
-    The flag stays as the committed evidence for that result; "concat"
-    is the right default under XLA.
+    """A run of dense layers in the textbook form (``impl="concat"``):
+    every layer concatenates its 32 new channels onto the running
+    features, copying all C prior channels per layer (O(L^2)
+    channel-writes per block).  The tests' reference for the packed and
+    fused blocks, which share its parameter tree.  (A preallocated
+    feature buffer written strip by strip, Pleiss et al. 2017, measured
+    ~2x slower under XLA and is gone: PERF_HISTORY.md, 'DenseNet
+    dense-block memory'.)
     """
 
     num_layers: int
@@ -528,38 +511,21 @@ class DenseBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, train: bool):
-        if self.impl == "concat":
-            for i in range(self.num_layers):
-                x = DenseLayer(
-                    self.growth_rate, self.bn_size, self.dtype,
-                    name=f"denselayer{i + 1}",
-                )(x, train)
-            return x
-        if self.impl != "buffer":
+        if self.impl != "concat":
             # "packed"/"fused" route to PackedDenseBlock/FusedDenseBlock
             # in DenseNetStage before DenseBlock is ever constructed, but
             # list them: they are valid config values ("packed" the
             # default)
             raise ValueError(
-                f"dense_block_impl must be 'concat', 'buffer', 'packed' "
-                f"or 'fused', got {self.impl!r}"
+                f"dense_block_impl must be 'concat', 'packed' or 'fused', "
+                f"got {self.impl!r}"
             )
-        b, hgt, wid, c_in = x.shape
-        total = c_in + self.num_layers * self.growth_rate
-        buf = jnp.zeros((b, hgt, wid, total), x.dtype)
-        buf = jax.lax.dynamic_update_slice(buf, x, (0, 0, 0, 0))
-        c = c_in
         for i in range(self.num_layers):
-            xi = jax.lax.slice_in_dim(buf, 0, c, axis=3)
-            h = DenseLayer(
+            x = DenseLayer(
                 self.growth_rate, self.bn_size, self.dtype,
-                concat_output=False, name=f"denselayer{i + 1}",
-            )(xi, train)
-            buf = jax.lax.dynamic_update_slice(
-                buf, h.astype(buf.dtype), (0, 0, 0, c)
-            )
-            c += self.growth_rate
-        return buf
+                name=f"denselayer{i + 1}",
+            )(x, train)
+        return x
 
 
 class Transition(nn.Module):

@@ -43,14 +43,6 @@ import jax
 import jax.numpy as jnp
 
 from ddl_tpu.ops.attention import dense_attention
-from ddl_tpu.ops.quant import (
-    QuantKV,
-    kv_attend,
-    kv_map,
-    kv_set_slots,
-    kv_slice,
-    kv_write,
-)
 
 __all__ = [
     "LMConfig",
@@ -324,8 +316,7 @@ class LMConfig:
     @property
     def layers_alike(self) -> bool:
         """Every layer is the same block: what a path that stacks one
-        block's parameters for all layers (the pipeline) or builds its own
-        block (the serving engine) can run."""
+        block's parameters for all layers (the pipeline) can run."""
         return not self.layer_types and not (self.num_experts and self.num_dense_layers)
 
     def layer_window(self, i: int) -> int:
@@ -348,8 +339,9 @@ def remat_block(cfg) -> type:
     """The Block class under this config's remat settings — the single
     construction every builder (TransformerLM, ViT, the pipeline step
     factories) must use so remat semantics cannot drift between paths.
-    ``static_argnums=(4,)`` keeps ``deterministic`` a Python bool through
-    the checkpoint wrapper.  Valid policy names: ``REMAT_POLICIES`` (the
+    ``static_argnums=(3,)`` (``Block.__call__(self, x, cache,
+    deterministic)``) keeps ``deterministic`` a Python bool through the
+    checkpoint wrapper.  Valid policy names: ``REMAT_POLICIES`` (the
     CLIs use it for their argparse choices)."""
     if not cfg.remat:
         return Block
@@ -366,8 +358,8 @@ def remat_block(cfg) -> type:
         )
     policy = policies[cfg.remat_policy]
     if policy is None:
-        return nn.remat(Block, static_argnums=(4,))
-    return nn.remat(Block, static_argnums=(4,), policy=policy)
+        return nn.remat(Block, static_argnums=(3,))
+    return nn.remat(Block, static_argnums=(3,), policy=policy)
 
 
 def _rope(x, theta: float, positions=None):
@@ -450,26 +442,31 @@ class QDense(nn.Module):
         return y
 
 
+def refuse_cache_over_layer_types(cfg: LMConfig) -> None:
+    """The one refusal of incremental decode over a layer pattern, raised
+    by ``Attention`` for any cache and by the serving factory before it
+    builds a program."""
+    if cfg.layer_types:
+        raise NotImplementedError(
+            "a decode cache over mixed sliding and full layers is not "
+            "built: a sliding layer needs O(window) rows or blocks and a "
+            "full layer all of them, and the caches and the KV pool hold "
+            "one kind for every layer (ROADMAP R2)"
+        )
+
+
 class Attention(nn.Module):
     """Causal self-attention.  Two modes share the same parameters:
 
-    * training/eval (``kv_cache=None``): full-sequence attention through
+    * training/eval (``cache=None``): full-sequence attention through
       ``attn_core`` (dense, ring, Ulysses, or flash).
-    * incremental decode (``kv_cache=(k, v)`` of shape (B, L, H, Dh),
-      ``offset`` = number of positions already decoded): the new tokens'
-      K/V are written into the cache at ``offset`` and the queries attend
-      over the whole cache under the causal mask; returns
-      ``(out, (new_k, new_v))``.  Used by ``infer/decode.py``.
-
-    ``rolling=True`` (requires ``cfg.attn_window``) treats the cache as a
-    RING of capacity ``attn_window`` instead of a linear buffer: slot
-    ``p % L`` holds position ``p``, so allocation is O(window) no matter
-    how long the generation runs — the memory-side twin of the linear
-    cache's O(window) read slice.  Prefill (``t > 1``) attends its own
-    fresh K/V directly (banded causal — the cache holds nothing older)
-    and writes only the last ``min(L, t)`` keys; single-token decode
-    writes one slot and reads the whole ring under a derived absolute-
-    position mask.
+    * incremental decode (``cache`` = a cache object, ``infer/kv_cache.py``
+      or ``serve/kv_pool.PagedKV``): the new tokens are rotated at
+      ``cache.positions(t)`` and ``cache.attend`` writes their K/V where
+      that cache keeps them and attends what it holds; returns
+      ``(out, new_cache)``.  The layer's own are the projections, the
+      norms, the rotation, the gate; where K/V rows are and how they are
+      indexed is the cache's.
     """
 
     cfg: LMConfig
@@ -480,14 +477,11 @@ class Attention(nn.Module):
     rope: bool = True
 
     @nn.compact
-    def __call__(self, x, kv_cache=None, offset=None, rolling=False):
+    def __call__(self, x, cache=None):
         cfg = self.cfg
         window = cfg.attn_window if self.window is None else self.window
-        if kv_cache is not None and cfg.layer_types:
-            raise NotImplementedError(
-                "a decode cache over mixed sliding and full layers is not "
-                "built (the cache paths read one model-wide attn_window)"
-            )
+        if cache is not None:
+            refuse_cache_over_layer_types(cfg)
         b, t, _ = x.shape
         # kernels are flat (embed, heads*head_dim) with the fused dim sharded
         # over 'model' — identical placement to a per-head split, one matmul.
@@ -510,115 +504,31 @@ class Attention(nn.Module):
         if cfg.qk_norm:
             q = RMSNorm(cfg.dtype, cfg.norm_eps, name="q_norm")(q)
             k = RMSNorm(cfg.dtype, cfg.norm_eps, name="k_norm")(k)
-        positions = None
-        if kv_cache is not None:
-            positions = offset + jnp.arange(t)
         if self.rope:
+            positions = None if cache is None else cache.positions(t)
             q = _rope(q, cfg.rope_theta, positions)
             k = _rope(k, cfg.rope_theta, positions)
         spec = ("batch", "act_seq", "act_heads", None)
-        # fused-storage cache leaves are 3-D (ops/quant.kv_fuse)
-        cache_spec = ("batch", "act_seq", "act_heads")
         q = nn.with_logical_constraint(q, spec)
         k = nn.with_logical_constraint(k, spec)
         v = nn.with_logical_constraint(v, spec)
-        if kv_cache is None:
-            # every core is grouped-native (dense groups by query reshape;
-            # flash indexes the shared K/V head per BlockSpec; ring
-            # ppermutes and Ulysses all-to-alls Hkv-head K/V) — K/V are
-            # never broadcast to H heads, so the manual cores' HBM and
-            # collective traffic keep GQA's Hkv/H savings.
-            if self.attn_core is None:
-                core = partial(dense_attention, causal=cfg.causal, window=window)
-            elif cfg.layer_types:
-                # a core built for a pattern takes the layer's window
-                core = partial(self.attn_core, window=window)
-            else:
-                core = self.attn_core
-            o = nn.with_logical_constraint(core(q, k, v), spec)
-            new_cache = None
-        elif rolling:
-            if not cfg.attn_window:
-                raise ValueError("rolling decode cache requires attn_window")
-            cap = kv_cache[0].shape[1]
-            if t > 1:
-                # prefill: the ring holds nothing older than these tokens,
-                # so attend the fresh K/V directly (banded causal) and
-                # persist only the last min(cap, t) of them
-                core = self.attn_core or partial(
-                    dense_attention, causal=True, window=cfg.attn_window
-                )
-                o = core(q, k, v)
-                keep = min(cap, t)
-                slots = (offset + t - keep + jnp.arange(keep)) % cap
-                kv_cache = kv_set_slots(
-                    kv_cache, k[:, -keep:], v[:, -keep:], slots
-                )
-            else:
-                slot = offset % cap
-                kv_cache = kv_write(kv_cache, k, v, slot)
-                # slot s holds the newest position congruent to s (mod
-                # cap); never-written slots derive negative positions
-                key_pos = offset - ((offset - jnp.arange(cap)) % cap)
-                mask = (
-                    (key_pos[None, :] <= offset)
-                    & (key_pos[None, :] > offset - cfg.attn_window)
-                    & (key_pos[None, :] >= 0)
-                )
-                o = kv_attend(
-                    q, kv_cache, mask,
-                    use_kernel=_ambient_mesh_size() <= 1,
-                )
-            kv_cache = _constrain_cache(kv_cache, cache_spec)
-            o = nn.with_logical_constraint(o, spec)
-            new_cache = kv_cache
-        elif t > 1 and isinstance(offset, int) and offset == 0:
-            # prefill: the cache holds nothing older than these tokens, so
-            # attend the fresh K/V directly — causal (+window) over the
-            # prompt, optionally through the flash kernel — instead of
-            # masked-attending the whole allocated buffer.  Scores are
-            # O(T^2) (O(T*W) windowed / O(T*block) flash) rather than
-            # O(T*capacity): a B=8, T=4096 prefill against an 8K cache
-            # would otherwise materialise a 13 GB score tensor and OOM.
-            kv_cache = kv_write(kv_cache, k, v, 0)
-            kv_cache = _constrain_cache(kv_cache, cache_spec)
-            core = self.attn_core or partial(
-                dense_attention, causal=True, window=cfg.attn_window
-            )
-            o = nn.with_logical_constraint(core(q, k, v), spec)
-            new_cache = kv_cache
+        # every core is grouped-native (dense groups by query reshape;
+        # flash indexes the shared K/V head per BlockSpec; ring
+        # ppermutes and Ulysses all-to-alls Hkv-head K/V) — K/V are
+        # never broadcast to H heads, so the manual cores' HBM and
+        # collective traffic keep GQA's Hkv/H savings.
+        if self.attn_core is None:
+            core = partial(dense_attention, causal=cfg.causal, window=window)
+        elif cfg.layer_types:
+            # a core built for a pattern takes the layer's window
+            core = partial(self.attn_core, window=window)
         else:
-            kv_cache = kv_write(kv_cache, k, v, offset)
-            kv_cache = _constrain_cache(kv_cache, cache_spec)
-            # queries at global positions offset+i attend keys <= that
-            # position; padded cache slots beyond offset+t are masked out.
-            q_pos = (offset + jnp.arange(t))[:, None]
-            cap = kv_cache[0].shape[1]
-            span = cap
-            att_cache = kv_cache
-            start = 0
-            if cfg.attn_window and cfg.attn_window + t - 1 < cap:
-                # windowed decode reads an O(window) slice, not the whole
-                # cache: the span (window + t - 1) covers every key any of
-                # the t queries can see, and the positional mask below
-                # handles the clamped warm-up region exactly.
-                span = cfg.attn_window + t - 1
-                start = jnp.clip(offset + t - span, 0, cap - span)
-                att_cache = kv_slice(kv_cache, start, span)
-            key_pos = start + jnp.arange(span)
-            mask = key_pos[None, :] <= q_pos  # (T, span)
-            if cfg.attn_window:
-                mask &= key_pos[None, :] > q_pos - cfg.attn_window
-            o = kv_attend(
-                q, att_cache, mask,
-                # the one-pass kernel attends the FULL buffer; a windowed
-                # O(span) slice keeps the einsum path
-                use_kernel=(
-                    t == 1 and span == cap and _ambient_mesh_size() <= 1
-                ),
-            )
-            o = nn.with_logical_constraint(o, spec)
-            new_cache = kv_cache
+            core = self.attn_core
+        if cache is None:
+            o = core(q, k, v)
+        else:
+            o, cache = cache.attend(q, k, v, window=window, core=core)
+        o = nn.with_logical_constraint(o, spec)
         o = o.reshape(b, t, cfg.n_heads * cfg.head_dim)
         if cfg.attn_gate:
             g = QDense(
@@ -636,7 +546,7 @@ class Attention(nn.Module):
             name="out",
         )(o)
         out = nn.with_logical_constraint(out, ("batch", "act_seq", "act_embed"))
-        return out if kv_cache is None else (out, new_cache)
+        return out if cache is None else (out, cache)
 
 
 class Mlp(nn.Module):
@@ -994,42 +904,9 @@ _rows_combine.defvjp(_rows_combine_fwd, _rows_combine_bwd)
 def _ambient_mesh_shape() -> dict:
     """Axis-name -> size of the ambient (abstract) mesh; {} when tracing
     without a mesh context (jax answers with an empty mesh, it does not
-    raise).  Shared by the decode-kernel and MoE-dispatch resolution
-    below."""
+    raise).  Shared by the decode caches' mesh size
+    (``infer/kv_cache.py``) and the MoE-dispatch resolution below."""
     return dict(jax.sharding.get_abstract_mesh().shape)
-
-
-def _ambient_mesh_size() -> int:
-    """Device count of the ambient mesh — 1 without a mesh context."""
-    size = 1
-    for n in _ambient_mesh_shape().values():
-        size *= int(n)
-    return size
-
-
-def _constrain_cache(cache, spec):
-    """Sharding-constrain the decode-cache leaves — SKIPPED on a trivial
-    mesh.  The constraint lowers to a sharding custom-call between the
-    cache update and its consumers; on one device it is semantically a
-    no-op but BREAKS XLA's while-loop in-place aliasing, so every decode
-    step copied the whole cache: profiled at B=32/T=768, the 24
-    dynamic-update-slices cost ~27 us each (full-buffer copy speed) plus
-    ~0.7 ms/step of explicit copies — the majority of decode time
-    (bench/profile_decode.py, PERF.md round 5).  Multi-device decode
-    keeps the constraints (the cache's model/seq sharding needs them).
-
-    ``spec`` is the fused-storage K/V spec (B, L, Hkv*Dh); QuantKV scale
-    leaves are (B, Hkv, L) so their spec transposes the last two axes."""
-    if _ambient_mesh_size() <= 1:
-        return cache
-    if isinstance(cache, QuantKV):
-        sspec = (spec[0], spec[2], spec[1])
-        c = nn.with_logical_constraint
-        return QuantKV(
-            c(cache.kq, spec), c(cache.ks, sspec),
-            c(cache.vq, spec), c(cache.vs, sspec),
-        )
-    return kv_map(lambda a: nn.with_logical_constraint(a, spec), cache)
 
 
 def _expert_axis_size() -> int:
@@ -1366,7 +1243,7 @@ class MoeMlp(nn.Module):
 
 
 class Block(nn.Module):
-    """Pre-norm decoder block.  With ``kv_cache`` (incremental decode) the
+    """Pre-norm decoder block.  With ``cache`` (incremental decode) the
     return gains the updated cache: ``(x, aux, new_cache)``."""
 
     cfg: LMConfig
@@ -1376,8 +1253,7 @@ class Block(nn.Module):
     layer: int = 0
 
     @nn.compact
-    def __call__(self, x, kv_cache=None, offset=None, deterministic=True,
-                 rolling=False):
+    def __call__(self, x, cache=None, deterministic=True):
         cfg = self.cfg
         drop = nn.Dropout(cfg.dropout_rate, deterministic=deterministic)
 
@@ -1393,19 +1269,18 @@ class Block(nn.Module):
             cfg.layer_rope(self.layer), name="attn",
         )
         h = norm("norm_attn")(x)
-        if kv_cache is None:
-            x = x + drop(post("norm_post_attn", attn(h)))
-            new_cache = None
+        if cache is None:
+            a = attn(h)
         else:
-            a, new_cache = attn(h, kv_cache, offset, rolling=rolling)
-            x = x + drop(post("norm_post_attn", a))
+            a, cache = attn(h, cache)
+        x = x + drop(post("norm_post_attn", a))
         h = norm("norm_mlp")(x)
         if cfg.layer_is_moe(self.layer):
             y, aux = MoeMlp(cfg, name="moe")(h)
         else:
             y, aux = Mlp(cfg, name="mlp")(h), jnp.zeros((), jnp.float32)
         x = x + drop(post("norm_post_mlp", y))
-        return (x, aux) if kv_cache is None else (x, aux, new_cache)
+        return (x, aux) if cache is None else (x, aux, cache)
 
 
 class TokenEmbed(nn.Module):
@@ -1528,7 +1403,7 @@ class TransformerLM(nn.Module):
         aux_total = jnp.zeros((), jnp.float32)
         for i in range(cfg.n_layers):
             x, aux = block(cfg, self.attn_core, i, name=f"block{i}")(
-                x, None, None, deterministic
+                x, None, deterministic
             )
             aux_total = aux_total + aux
         if return_hidden:

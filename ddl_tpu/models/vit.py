@@ -133,7 +133,7 @@ class ViT(nn.Module):
         block = remat_block(bc)
         for i in range(cfg.n_layers):
             x, _aux = block(bc, self.attn_core, name=f"block{i}")(
-                x, None, None, deterministic
+                x, None, deterministic
             )
         x = RMSNorm(cfg.dtype, name="norm_f")(x)
         x = x.mean(axis=1)  # mean-pool over patches

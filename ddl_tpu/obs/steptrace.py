@@ -181,7 +181,6 @@ class StepTrace:
         self.emit_step_spans = int(emit_step_spans)
         self.watchdog = None
         self._compiles = _CompileCounter.shared()
-        self._period = None
         self._period_compiles = self._compiles.count
         self._period_compile_s = self._compiles.secs
         self._totals: dict[str, float] = defaultdict(float)
@@ -293,7 +292,7 @@ class StepTrace:
         stack.enter_context(self._annotate(name, step=step))
         if write:
             stack.enter_context(
-                self.writer.span(name, step=step, period=self._period, **fields)
+                self.writer.span(name, step=step, **fields)
             )
 
     def child(self, name: str, step: int | None = None):
@@ -360,13 +359,12 @@ class StepTrace:
             if self.watchdog is not None:
                 self.watchdog.beat(step)
 
-    def begin_period(self, period: int) -> None:
+    def begin_period(self) -> None:
         if self._needs_run_start:
             # a second train() on the same trainer: mark the new segment
             # so run_end consumers don't attribute it to the previous one
             self.writer.emit("run_start", resumed=True)
             self._needs_run_start = False
-        self._period = period
         self._totals = defaultdict(float)
         self._period_compiles = self._compiles.count
         self._period_compile_s = self._compiles.secs
@@ -418,7 +416,6 @@ class StepTrace:
             loss=loss,
             compiles=compiles,
             compile_s=compile_s,
-            hbm_bytes_in_use=mem["bytes_in_use"] if mem else None,
             hbm_peak_bytes=mem["peak_bytes_in_use"] if mem else None,
             **({"rates": dict(rates)} if rates else {}),
             # a dropless expert layer's counters
@@ -434,7 +431,6 @@ class StepTrace:
             hbm_bytes=mem["bytes_in_use"] if mem else None,
             compiles=compiles,
         )
-        self._period = None
         return phases
 
     def finish(self, verbose: bool = True) -> list[dict]:

@@ -213,7 +213,7 @@ def _collect_step(streams, step):
                 tid=int(e.get("depth", 0)),
                 tname="phases" if not e.get("depth") else f"depth {e['depth']}",
                 key=f"h{host}/{e.get('name')}/{len(spans)}",
-                args=_slim_args(e, drop=("dur", "depth", "period")),
+                args=_slim_args(e, drop=("dur", "depth")),
             ))
     spans.extend(_schedule_lane_spans(sched, spans))
     return spans, marks, []

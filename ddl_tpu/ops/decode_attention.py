@@ -27,10 +27,12 @@ Masking is an additive f32 bias row (0 = attend, -1e30 = masked) built
 by the caller — the same mask math as the XLA path (ring-slot positions
 or linear positions), so rolling and full-cache decode share the kernel.
 
-Used automatically by ``models/transformer.Attention`` for single-device
-T=1 decode over the full cache (multi-device decode keeps the einsum
-path — GSPMD cannot partition a custom call); interpreter mode on the
-CPU backend, so CPU tests exercise the identical program.
+Used by every cache kind (``infer/kv_cache.py``, ``serve/kv_pool.PagedKV``)
+for T=1 decode over the full cache where ``decode_attention_path`` says
+``"kernel"``: one TPU device (multi-device decode keeps the einsum path —
+GSPMD cannot partition a custom call; on the CPU the caches take the
+einsum too, and ``tests/test_decode_attention.py`` runs the kernels
+interpreted against it).
 """
 
 from __future__ import annotations

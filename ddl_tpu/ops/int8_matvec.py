@@ -17,9 +17,9 @@ QDense/LMHead and A/B'd on chip at B=1 GQA+window kv+w: 3007 tok/s
 this kernel — the per-call overhead of ~84 extra pallas launches per
 decode step and the M=8 padding outweigh whatever stream-rate advantage
 the MXU path has.  The kernel and its parity tests stay as the
-experiment record (the same convention as the dense-block "buffer"
-impl); the next attempt at this lever should fuse the matvec with its
-neighbours instead of replacing one op.
+experiment record until ROADMAP D4 takes them; the next attempt at this
+lever should fuse the matvec with its neighbours instead of replacing
+one op.
 """
 
 from __future__ import annotations

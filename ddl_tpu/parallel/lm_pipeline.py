@@ -139,10 +139,10 @@ def _make_stage_fn(block_mod: nn.Module, dropout: bool = False):
 
         def stage_fn(stage_blocks, x):
             def layer(carry, p):
-                # full positional signature (x, kv_cache, offset,
-                # deterministic): nn.remat's static_argnums for
-                # `deterministic` indexes positional args
-                y, aux = block_mod.apply({"params": p}, carry, None, None, True)
+                # full positional signature (x, cache, deterministic):
+                # nn.remat's static_argnums for `deterministic` indexes
+                # positional args
+                y, aux = block_mod.apply({"params": p}, carry, None, True)
                 return y, aux
 
             y, auxs = lax.scan(layer, x, stage_blocks)
@@ -155,12 +155,11 @@ def _make_stage_fn(block_mod: nn.Module, dropout: bool = False):
 
         def layer(carry, xs):
             p, i = xs
-            # deterministic rides positionally (arg 4) so nn.remat's
+            # deterministic rides positionally (arg 3) so nn.remat's
             # static_argnums sees it as a Python bool, not a tracer
             y, aux = block_mod.apply(
                 {"params": p},
                 carry,
-                None,
                 None,
                 False,
                 rngs={"dropout": jax.random.fold_in(key, i)},

@@ -14,15 +14,16 @@ Two XLA programs, generalizing the PR-5 token-exact prefill/decode split
 * **decode** (one program per small bucket grid): K tokens for EVERY
   active lane in one dispatch — a ``lax.scan`` of single-token steps,
   the continuous-batching twin of ``make_lm_generator``'s fused scan.
-  Each step forwards the lanes' pending tokens through ``ServeDecode``
-  — the same parameter tree/submodule names as ``TransformerLM``, so
-  any training snapshot serves as-is — writing each lane's K/V row into
-  the pool at its block-table position AND appending it to the chunk's
-  contiguous per-lane view (each lane's table is gathered ONCE per
-  dispatch, not per layer per step), then attending that view with a
-  per-lane length mask (``ops.quant.kv_attend``: the einsum path off
-  TPU and on sharded meshes, the Pallas one-pass kernel with a
-  per-lane bias row on a single TPU).  The batch shape is static
+  Each step forwards the lanes' pending tokens through the same
+  ``LMDecode`` stack, over one ``kv_pool.PagedKV`` cache a layer — the
+  block every other path runs, so any training snapshot and any
+  configuration the block can express serves as-is.  The cache writes
+  each lane's K/V row into the pool at its block-table position AND
+  appends it to the chunk's contiguous per-lane view (each lane's table
+  is gathered ONCE per dispatch, not per layer per step), then attends
+  that view with a per-lane length mask (``ops.quant.kv_attend``: the
+  einsum path off TPU and on sharded meshes, the Pallas one-pass kernel
+  with a per-lane bias row on a single TPU).  The batch shape is static
   (``max_batch`` lanes; idle lanes write to a dropped block id and are
   masked), so admitting or retiring requests never recompiles; the two
   shape knobs that DO vary are bucketed to powers of two — the chunk
@@ -63,6 +64,7 @@ from __future__ import annotations
 import os
 import time
 from collections import deque, namedtuple
+from functools import partial
 from time import perf_counter
 from typing import Optional
 
@@ -73,21 +75,17 @@ import numpy as np
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ddl_tpu.infer.decode import DECODE_TOKEN_SPEC, LMDecode, init_kv_cache
-from ddl_tpu.models.transformer import (
-    LMConfig,
-    Mlp,
-    MoeMlp,
-    QDense,
-    RMSNorm,
-    _ambient_mesh_size,
-    _rope,
-    apply_final_norm_and_head,
-    make_embed,
+from ddl_tpu.infer.decode import (
+    DECODE_TOKEN_SPEC,
+    LMDecode,
+    init_kv_cache,
+    prefill_attn_core,
+    sample_token,
 )
-from ddl_tpu.ops.quant import QuantKV, kv_attend
+from ddl_tpu.infer.kv_cache import ContiguousKV, decode_attention_path
+from ddl_tpu.models.transformer import LMConfig, refuse_cache_over_layer_types
+from ddl_tpu.ops.quant import QuantKV, kv_slice
 from ddl_tpu.parallel.sharding import (
-    FLASH_AUTO_MIN_T,
     LMMeshSpec,
     build_lm_mesh,
     lm_logical_rules,
@@ -96,14 +94,13 @@ from ddl_tpu.parallel.sharding import (
 from ddl_tpu.serve.admission import AdmissionController
 from ddl_tpu.serve.kv_pool import (
     BlockAllocator,
+    PagedKV,
     PrefixIndex,
     apply_block_permutation,
     blocks_for,
-    cache_write_token,
     init_kv_pool,
     pool_copy_block,
     pool_gather,
-    pool_write_token,
     pool_write_prefill,
 )
 from ddl_tpu.serve.scheduler import (
@@ -144,182 +141,6 @@ def pow2_at_least(n: int) -> int:
     if n < 1:
         raise ValueError(f"need n >= 1, got {n}")
     return 1 << (n - 1).bit_length()
-
-
-def _constrain_pool(pool, on: bool):
-    """Sequence-parallel placement for the pool leaves: blocks (the
-    chopped sequence dim) over ``seq``, the fused feature dim over
-    ``model`` — skipped on a trivial mesh for the same in-place-aliasing
-    reason as ``transformer._constrain_cache``."""
-    if not on:
-        return pool
-    c = nn.with_logical_constraint
-    if isinstance(pool, QuantKV):
-        return QuantKV(
-            c(pool.kq, ("act_seq", None, "act_heads")),
-            c(pool.ks, ("act_seq", "act_heads", None)),
-            c(pool.vq, ("act_seq", None, "act_heads")),
-            c(pool.vs, ("act_seq", "act_heads", None)),
-        )
-    return tuple(c(a, ("act_seq", None, "act_heads")) for a in pool)
-
-
-def decode_attention_path(mesh_size: int) -> str:
-    """Which cached-attention path a decode program over a mesh of
-    ``mesh_size`` devices takes: ``"kernel"`` (the one-pass Pallas
-    kernel, ``ops/decode_attention.py``) or ``"einsum"``.  The kernel
-    only where it is a real kernel: on the CPU backend it would run
-    interpreted (orders of magnitude slower than the einsum), and the
-    CPU einsum path is also what keeps serve tokens bit-identical to the
-    sequential einsum reference (the pool's power-of-two width is
-    alignment-legal, so unlike the contiguous path ``pick_block_l``
-    would NOT bail us out here).  GSPMD cannot partition a custom call,
-    so any mesh larger than one device keeps the einsum too — a known
-    limit (ROADMAP S6), reported in ``ServeEngine.stats`` rather than
-    taken in silence."""
-    if mesh_size == 1 and jax.default_backend() == "tpu":
-        return "kernel"
-    return "einsum"
-
-
-class ServeAttention(nn.Module):
-    """One cached-attention step over the paged pool for every lane.
-
-    Parameters (q/k/v/out kernels) are byte-identical in name and shape
-    to ``models.transformer.Attention``, so the training tree — incl.
-    the weight-only int8 tree (``QDense`` sniffs the scales) — applies
-    unchanged."""
-
-    cfg: LMConfig
-
-    @nn.compact
-    def __call__(self, x, pool, cache, tables, lengths):
-        cfg = self.cfg
-        b, t, _ = x.shape  # t == 1: single pending token per lane
-        qkv_kernel = nn.with_logical_partitioning(
-            nn.initializers.lecun_normal(), ("embed", "heads")
-        )
-
-        def proj(name, heads):
-            y = QDense(
-                heads * cfg.head_dim, dtype=cfg.dtype,
-                kernel_init=qkv_kernel, name=name,
-            )(x)
-            return y.reshape(b, t, heads, cfg.head_dim)
-
-        q = proj("q", cfg.n_heads)
-        k = proj("k", cfg.kv_heads)
-        v = proj("v", cfg.kv_heads)
-        positions = lengths[:, None] + jnp.arange(t)[None, :]
-        q = _rope(q, cfg.rope_theta, positions)
-        k = _rope(k, cfg.rope_theta, positions)
-        spec = ("batch", "act_seq", "act_heads", None)
-        sharded = _ambient_mesh_size() > 1
-        if sharded:
-            q = nn.with_logical_constraint(q, spec)
-            k = nn.with_logical_constraint(k, spec)
-            v = nn.with_logical_constraint(v, spec)
-        bs = (pool.kq if isinstance(pool, QuantKV) else pool[0]).shape[1]
-        nmax = tables.shape[1]
-        # each lane's write target; idle lanes carry an out-of-range
-        # table entry, so their (garbage) row is dropped by the scatter
-        blk = jnp.take_along_axis(
-            tables, jnp.minimum(lengths // bs, nmax - 1)[:, None], axis=1
-        )[:, 0]
-        pool = pool_write_token(pool, k, v, blk, lengths % bs)
-        pool = _constrain_pool(pool, sharded)
-        # the same row lands in the chunk's contiguous gathered view:
-        # lane b's gathered index (lengths//bs)*bs + lengths%bs ==
-        # lengths, so attention here is bit-identical to a fresh gather
-        # — without paying the (B, L, fused) gather per layer per step
-        # (an idle lane writes row 0 of ITS OWN view: discarded output)
-        cache = cache_write_token(cache, k, v, lengths)
-        if sharded:
-            cache_spec = ("batch", "act_seq", "act_heads")
-            if isinstance(cache, QuantKV):
-                c = nn.with_logical_constraint
-                cache = QuantKV(
-                    c(cache.kq, cache_spec),
-                    c(cache.ks, ("batch", "act_heads", "act_seq")),
-                    c(cache.vq, cache_spec),
-                    c(cache.vs, ("batch", "act_heads", "act_seq")),
-                )
-            else:
-                cache = tuple(
-                    nn.with_logical_constraint(a, cache_spec) for a in cache
-                )
-        key_pos = jnp.arange(nmax * bs)
-        # lane b's query sits at position lengths[b] (its row was just
-        # written): attend everything at or before it — the identical
-        # mask the contiguous decode path builds, per lane
-        mask = key_pos[None, None, :] <= lengths[:, None, None]
-        if cfg.attn_window:
-            mask &= key_pos[None, None, :] > (
-                lengths[:, None, None] - cfg.attn_window
-            )
-        use_kernel = decode_attention_path(_ambient_mesh_size()) == "kernel"
-        o = kv_attend(q, cache, mask, use_kernel=use_kernel)
-        if sharded:
-            o = nn.with_logical_constraint(o, spec)
-        out = QDense(
-            cfg.d_model, dtype=cfg.dtype,
-            kernel_init=nn.with_logical_partitioning(
-                nn.initializers.lecun_normal(), ("heads", "embed")
-            ),
-            name="out",
-        )(o.reshape(b, t, cfg.n_heads * cfg.head_dim))
-        out = nn.with_logical_constraint(
-            out, ("batch", "act_seq", "act_embed")
-        )
-        return out, pool, cache
-
-
-class ServeBlock(nn.Module):
-    """Pre-norm decoder block over the paged pool — ``Block``'s decode
-    path with the contiguous cache swapped for (pool, tables, lengths)."""
-
-    cfg: LMConfig
-
-    @nn.compact
-    def __call__(self, x, pool, cache, tables, lengths):
-        cfg = self.cfg
-        h = RMSNorm(cfg.dtype, cfg.norm_eps, name="norm_attn")(x)
-        a, pool, cache = ServeAttention(cfg, name="attn")(
-            h, pool, cache, tables, lengths
-        )
-        x = x + a
-        h = RMSNorm(cfg.dtype, cfg.norm_eps, name="norm_mlp")(x)
-        if cfg.num_experts > 0:
-            y, _aux = MoeMlp(cfg, name="moe")(h)
-        else:
-            y = Mlp(cfg, name="mlp")(h)
-        return x + y, pool, cache
-
-
-class ServeDecode(nn.Module):
-    """One batched decode step over the full layer stack.  Submodule
-    names mirror ``TransformerLM``/``LMDecode`` exactly, so the training
-    param tree applies as-is."""
-
-    cfg: LMConfig
-
-    @nn.compact
-    def __call__(self, tokens, pools, caches, tables, lengths):
-        cfg = self.cfg
-        x = make_embed(cfg)(tokens)
-        x = nn.with_logical_constraint(x, ("batch", "act_seq", "act_embed"))
-        new_pools, new_caches = [], []
-        for i in range(cfg.n_layers):
-            x, p, c = ServeBlock(cfg, name=f"block{i}")(
-                x, pools[i], caches[i], tables, lengths
-            )
-            new_pools.append(p)
-            new_caches.append(c)
-        return (
-            apply_final_norm_and_head(cfg, x),
-            tuple(new_pools),
-            tuple(new_caches),
-        )
 
 
 ServeStepFns = namedtuple(
@@ -364,13 +185,7 @@ def make_serve_step_fns(
     spec = spec or LMMeshSpec()
     if not cfg.causal:
         raise ValueError("serving decode requires a causal LM")
-    if not cfg.layers_alike or cfg.qk_norm or cfg.attn_gate or cfg.sandwich_norm:
-        # ServeBlock/ServeAttention are the GPT-class block over the paged
-        # pool; they read none of these and would serve another model
-        raise NotImplementedError(
-            "the serving block is not built for layer_types, num_dense_layers, "
-            "qk_norm, attn_gate or sandwich_norm"
-        )
+    refuse_cache_over_layer_types(cfg)
     if spec.pipe > 1 or spec.expert > 1:
         raise ValueError(
             "serving meshes use data/seq/model axes only (pipe/expert "
@@ -394,20 +209,10 @@ def make_serve_step_fns(
         )
     rules = lm_logical_rules(cfg.fsdp)
 
-    def sample_one(logits, rng):
-        """(V,) logits -> sampled token; the same math per lane as
-        ``make_lm_generator``'s batched sample."""
-        if temperature == 0.0:
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        l = logits
-        if top_k is not None:
-            kth = lax.top_k(l, top_k)[0][..., -1:]
-            l = jnp.where(l < kth, -jnp.inf, l)
-        return jax.random.categorical(
-            rng, l / jnp.float32(temperature), axis=-1
-        ).astype(jnp.int32)
+    # (V,) logits -> sampled token: the generator's own sampling, a lane
+    sample_one = partial(sample_token, temperature=temperature, top_k=top_k)
 
-    model = ServeDecode(cfg)
+    model = LMDecode(cfg)
 
     def _decode_chunk(params, pools, tables, lengths, pending, rngs, *, k):
         """K fused single-token steps for every lane — same per-step
@@ -416,23 +221,27 @@ def make_serve_step_fns(
         per-lane cache ONCE here; the scan appends rows to that view (a
         (B, fused) scatter) instead of re-gathering (B, L, fused) per
         layer per step.  Returns toks (K, B)."""
-        caches = tuple(pool_gather(p, tables) for p in pools)
+        views = tuple(pool_gather(p, tables) for p in pools)
 
         def body(carry, _):
-            pools, caches, lengths, pending, rngs = carry
+            pools, views, lengths, pending, rngs = carry
+            caches = tuple(
+                PagedKV(p, c, tables, lengths) for p, c in zip(pools, views)
+            )
             with nn.logical_axis_rules(rules):
-                logits, pools, caches = model.apply(
-                    {"params": params}, pending[:, None], pools, caches,
-                    tables, lengths,
+                logits, caches = model.apply(
+                    {"params": params}, pending[:, None], caches
                 )
             last = logits[:, 0]  # (B, V) f32
             pair = jax.vmap(jax.random.split)(rngs)  # (B, 2, key)
             new_rngs, subs = pair[:, 0], pair[:, 1]
             toks = jax.vmap(sample_one)(last, subs)
-            return (pools, caches, lengths + 1, toks, new_rngs), toks
+            pools = tuple(c.pool for c in caches)
+            views = tuple(c.view for c in caches)
+            return (pools, views, caches[0].lengths, toks, new_rngs), toks
 
         (pools, _, _, _, rngs), toks = lax.scan(
-            body, (pools, caches, lengths, pending, rngs), None, length=k
+            body, (pools, views, lengths, pending, rngs), None, length=k
         )
         return toks, rngs, pools
 
@@ -446,8 +255,6 @@ def make_serve_step_fns(
         prog = _decode_cache.get((k, nmax))
         if prog is not None:
             return prog, False
-        from functools import partial
-
         prog = jax.jit(
             partial(_decode_chunk, k=k),
             in_shardings=(None, None, None, None, tok_sharding, None),
@@ -470,27 +277,15 @@ def make_serve_step_fns(
         prog = _prefill_cache.get(bucket_len)
         if prog is not None:
             return prog
-        # prefill is a training-style causal forward: ride the flash
-        # kernel exactly where make_lm_generator would
-        attn_core = None
-        if mesh.size == 1 and (
-            cfg.flash is True
-            or (cfg.flash == "auto" and bucket_len >= FLASH_AUTO_MIN_T)
-        ):
-            from functools import partial
-
-            from ddl_tpu.ops.flash_attention import flash_attention
-
-            attn_core = partial(
-                flash_attention, causal=True, window=cfg.attn_window
-            )
-        pre_model = LMDecode(cfg, attn_core=attn_core)
+        pre_model = LMDecode(
+            cfg, attn_core=prefill_attn_core(cfg, mesh, bucket_len)
+        )
 
         def _prefill(params, pools, prompt, block_ids, true_len, rng):
             caches = init_kv_cache(cfg, 1, bucket_len, quant=kv_quant)
             with nn.logical_axis_rules(rules):
                 logits, caches = pre_model.apply(
-                    {"params": params}, prompt, caches, 0,
+                    {"params": params}, prompt, caches,
                     last_index=true_len - 1,
                 )
             # logits at the TRUE prompt end — right-pad rows beyond it
@@ -502,7 +297,7 @@ def make_serve_step_fns(
             rng, sub = jax.random.split(rng)
             tok0 = sample_one(last, sub)
             pools = tuple(
-                pool_write_prefill(pools[i], caches[i], block_ids)
+                pool_write_prefill(pools[i], caches[i].kv, block_ids)
                 for i in range(cfg.n_layers)
             )
             return tok0, rng, pools
@@ -511,20 +306,7 @@ def make_serve_step_fns(
         _prefill_cache[bucket_len] = prog
         return prog
 
-    chunk_model = LMDecode(cfg)
     _chunk_cache: dict[tuple[int, int, str], object] = {}
-
-    def _slice_cache(cache, off, span):
-        """Rows [off, off+span) of a gathered contiguous cache — the
-        layout ``pool_write_prefill`` scatters (span static, off traced).
-        QuantKV scale leaves keep the sequence dim LAST."""
-        if isinstance(cache, QuantKV):
-            r = lambda a: lax.dynamic_slice_in_dim(a, off, span, axis=1)
-            s = lambda a: lax.dynamic_slice_in_dim(a, off, span, axis=2)
-            return QuantKV(r(cache.kq), s(cache.ks), r(cache.vq), s(cache.vs))
-        return tuple(
-            lax.dynamic_slice_in_dim(a, off, span, axis=1) for a in cache
-        )
 
     def chunk_for(cb: int, nmax: int, mode: str = "final"):
         """The jitted CHUNK prefill program over one request's block
@@ -562,10 +344,12 @@ def make_serve_step_fns(
 
         def _chunk(params, pools, tokens, table, off, last_index, rng):
             tables = table[None, :]
-            caches = tuple(pool_gather(p, tables) for p in pools)
+            caches = tuple(
+                ContiguousKV(pool_gather(p, tables), off) for p in pools
+            )
             with nn.logical_axis_rules(rules):
-                logits, caches = chunk_model.apply(
-                    {"params": params}, tokens, caches, off,
+                logits, caches = model.apply(
+                    {"params": params}, tokens, caches,
                     last_index=last_index if mode != "mid" else 0,
                 )
             ids = lax.dynamic_slice(
@@ -573,7 +357,7 @@ def make_serve_step_fns(
             )
             pools = tuple(
                 pool_write_prefill(
-                    pools[i], _slice_cache(caches[i], off, cb), ids
+                    pools[i], kv_slice(caches[i].kv, off, cb), ids
                 )
                 for i in range(cfg.n_layers)
             )

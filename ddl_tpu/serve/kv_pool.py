@@ -46,15 +46,26 @@ probe (``analysis/contracts.py``).
 from __future__ import annotations
 
 import bisect
+import dataclasses
 import hashlib
+from typing import Any
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ddl_tpu.ops.quant import QuantKV, quantize_q8
+from ddl_tpu.infer.kv_cache import (
+    CACHE_SPEC,
+    constrain_kv,
+    decode_attention_path,
+    zeros_kv,
+)
+from ddl_tpu.ops.quant import QuantKV, kv_attend, quantize_q8
 
 __all__ = [
     "BlockAllocator",
+    "PagedKV",
+    "POOL_SPEC",
     "PoolExhausted",
     "PrefixIndex",
     "blocks_for",
@@ -388,19 +399,8 @@ def init_kv_pool(
     block_size) f32 scales, the same per-(token, head) granularity as
     the contiguous int8 cache, so ``ops.quant.kv_attend`` reads a
     gathered pool without knowing it was paged."""
-    if quant and dtype is not None:
-        raise ValueError(
-            "quant=True fixes the pool layout (int8 + f32 scales); "
-            "dtype cannot be combined with it"
-        )
-    dtype = dtype or cfg.dtype
-    shape = (num_blocks, block_size, cfg.kv_heads * cfg.head_dim)
-    if quant:
-        q = jnp.zeros(shape, jnp.int8)
-        s = jnp.zeros((num_blocks, cfg.kv_heads, block_size), jnp.float32)
-        return tuple(QuantKV(q, s, q, s) for _ in range(cfg.n_layers))
-    zero = jnp.zeros(shape, dtype)
-    return tuple((zero, zero) for _ in range(cfg.n_layers))
+    pool = zeros_kv(cfg, num_blocks, block_size, dtype, quant)
+    return tuple(pool for _ in range(cfg.n_layers))
 
 
 def pool_write_prefill(pool_layer, cache_layer, block_ids):
@@ -413,10 +413,6 @@ def pool_write_prefill(pool_layer, cache_layer, block_ids):
     past the true prompt length carry pad-token K/V; they are always
     overwritten by ``pool_write_token`` before the length mask ever
     exposes them."""
-    nb = (
-        pool_layer.kq if isinstance(pool_layer, QuantKV) else pool_layer[0]
-    ).shape[0]
-    del nb  # shape-checked by the scatter itself; kept for readability
     if isinstance(pool_layer, QuantKV):
         bs = pool_layer.kq.shape[1]
         hkv = pool_layer.ks.shape[1]
@@ -553,6 +549,68 @@ def pool_gather(pool_layer, tables):
         b, nmax * bs, x.shape[-1]
     )
     return (rows(pk), rows(pv))
+
+
+# pool leaves are (num_blocks, block_size, Hkv*Dh): blocks (the chopped
+# sequence dim) over ``seq``, the fused feature dim over ``model``
+POOL_SPEC = ("act_seq", None, "act_heads")
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class PagedKV:
+    """One layer's K/V in the paged pool, as a decode chunk holds it: the
+    cache object (``infer/kv_cache.py``) of the continuous batch.
+
+    ``pool`` is the layer's block pool, ``view`` each lane's block table
+    gathered into a contiguous (B, L, Hkv*Dh) cache (``pool_gather``,
+    once a chunk and outside the layer), ``tables`` (B, nmax) the block
+    tables and ``lengths`` (B,) the rows each lane already holds.  One
+    new token a lane: chunked prefill goes through ``ContiguousKV`` over
+    the gathered view."""
+
+    pool: Any
+    view: Any
+    tables: Any
+    lengths: Any
+
+    def positions(self, t: int):
+        return self.lengths[:, None] + jnp.arange(t)[None, :]
+
+    def attend(self, q, k, v, *, window: int, core):
+        del core  # a prefill's; this cache takes decode steps only
+        if q.shape[1] != 1:
+            raise ValueError(
+                f"the paged cache takes one new token a lane, got {q.shape[1]}"
+            )
+        pool, tables, lengths = self.pool, self.tables, self.lengths
+        bs = pool[0].shape[1]
+        nmax = tables.shape[1]
+        # each lane's write target; idle lanes carry an out-of-range
+        # table entry, so their (garbage) row is dropped by the scatter
+        blk = jnp.take_along_axis(
+            tables, jnp.minimum(lengths // bs, nmax - 1)[:, None], axis=1
+        )[:, 0]
+        pool = pool_write_token(pool, k, v, blk, lengths % bs)
+        pool = constrain_kv(pool, POOL_SPEC)
+        # the same row lands in the chunk's contiguous gathered view:
+        # lane b's gathered index (lengths//bs)*bs + lengths%bs ==
+        # lengths, so attention here is bit-identical to a fresh gather
+        # — without paying the (B, L, fused) gather per layer per step
+        # (an idle lane writes row 0 of ITS OWN view: discarded output)
+        view = cache_write_token(self.view, k, v, lengths)
+        view = constrain_kv(view, CACHE_SPEC)
+        key_pos = jnp.arange(nmax * bs)
+        # lane b's query sits at position lengths[b] (its row was just
+        # written): attend everything at or before it — the identical
+        # mask the contiguous decode path builds, per lane
+        mask = key_pos[None, None, :] <= lengths[:, None, None]
+        if window:
+            mask &= key_pos[None, None, :] > lengths[:, None, None] - window
+        o = kv_attend(
+            q, view, mask, use_kernel=decode_attention_path() == "kernel"
+        )
+        return o, PagedKV(pool, view, tables, lengths + 1)
 
 
 def pool_copy_block(pools, src, dst):

@@ -539,7 +539,7 @@ class BaseTrainer:
             if period == profile_period:
                 jax.profiler.start_trace(self.profile_dir)
             if obs is not None:
-                obs.begin_period(period)
+                obs.begin_period()
             start = perf_counter()
             # where this period's data stream starts (nonzero only for
             # the first period after an exact mid-period resume) — a

@@ -550,11 +550,11 @@ def test_decode_builds_each_layer_as_its_own_kind():
     assert "mlp" in params["block0"] and "moe" in params["block1"]
     want, _ = lm.apply({"params": params}, tok, mutable=["intermediates"])[0]
     got, _ = LMDecode(cfg).apply(
-        {"params": params}, tok, init_kv_cache(cfg, 2, 16), 0, mutable=["intermediates"])[0]
+        {"params": params}, tok, init_kv_cache(cfg, 2, 16), mutable=["intermediates"])[0]
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
     with pytest.raises(NotImplementedError, match="mixed sliding and full"):
         patterned = lm_config(small_model(n_layers=2, layer_types=["sliding_attention", "full_attention"]))
-        LMDecode(patterned).init(jax.random.key(0), tok, init_kv_cache(patterned, 2, 16), 0)
+        LMDecode(patterned).init(jax.random.key(0), tok, init_kv_cache(patterned, 2, 16))
 
 
 @pytest.mark.parametrize("over", [
@@ -573,16 +573,41 @@ def test_the_pipeline_refuses_layers_it_would_build_alike(over):
             cfg, LMMeshSpec(pipe=2), build_optimizer(3e-4), jax.random.key(0), 2, 32, 2)
 
 
-@pytest.mark.parametrize("over", [
-    dict(layer_types=["sliding_attention", "full_attention"]),
-    dict(layer_types=[], sliding_window=0),  # dense layers, q/k norms, gate, four norms
-], ids=["pattern", "afmoe_block"])
-def test_the_serving_engine_refuses_a_block_it_does_not_build(over):
+def test_the_serving_engine_refuses_a_layer_pattern_and_says_why():
+    """What is left of the serving refusal: a cache over mixed windows, by
+    the one ``NotImplementedError`` that ``Attention`` raises for any
+    cache, raised by the factory before it builds a program."""
     from ddl_tpu.serve.engine import make_serve_step_fns
 
-    cfg = lm_config(small_model(n_layers=2, **over))
-    with pytest.raises(NotImplementedError, match="serving block"):
+    cfg = lm_config(small_model(
+        n_layers=2, layer_types=["sliding_attention", "full_attention"]))
+    with pytest.raises(NotImplementedError, match="mixed sliding and full.*ROADMAP R2"):
         make_serve_step_fns(cfg, block_size=8, num_blocks=8, max_batch=2)
+
+
+def test_the_serving_engine_serves_the_afmoe_block():
+    """One block: the dense layer before the dropless expert layer, the
+    shared expert, q/k norms, the output gate, four norms and the embedding
+    multiplier are served because the engine stacks ``Block`` itself; its
+    tokens are the sequential decoder's."""
+    from ddl_tpu.parallel.sharding import LMMeshSpec
+    from ddl_tpu.serve.engine import ServeEngine
+    from tests.test_serve import _clients, _sequential_tokens
+
+    cfg = lm_config(small_model(n_layers=2, layer_types=[], sliding_window=0))
+    assert cfg.moe_router == "sigmoid" and cfg.qk_norm and cfg.attn_gate
+    params = flax.core.meta.unbox(
+        TransformerLM(cfg).init(jax.random.key(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    assert "mlp" in params["block0"] and "moe" in params["block1"]
+    clients = [(cid, prompt % cfg.vocab_size, mn)
+               for cid, prompt, mn in _clients(3, np.random.default_rng(5), new_hi=8)]
+    eng = ServeEngine(cfg, params, LMMeshSpec(), block_size=8, num_blocks=32, max_batch=4)
+    for cid, prompt, mn in clients:
+        eng.submit(prompt, mn, request_id=cid, rng_seed=11)
+    got = eng.run()
+    want = _sequential_tokens(cfg, LMMeshSpec(), params, clients, seed=11)
+    for cid in want:
+        np.testing.assert_array_equal(got[cid], want[cid])
 
 
 def test_the_pipeline_head_and_the_serving_block_read_norm_eps():
@@ -676,11 +701,11 @@ def test_the_period_event_carries_the_dropless_counters(tmp_path):
 
     w = EventWriter(tmp_path, "job", host=0)
     trace = StepTrace(w)
-    trace.begin_period(0)
+    trace.begin_period()
     trace.end_period(0, 5, elapsed=1.0, steps=5, metrics={
         "loss": 9.5, "ce": 9.5, "moe_local_rows": 16384.0,
         "moe_load_max_over_mean": 1.25, "moe_rows_dropped": 0.0, "moe_buffer_fill": 0.09})
-    trace.begin_period(1)
+    trace.begin_period()
     trace.end_period(1, 10, elapsed=1.0, steps=5, metrics={"loss": 9.4, "moe_aux": 0.0})
     w.close()
     first, second = [e for e in read_events(w.path) if e["kind"] == "period"]

@@ -10,6 +10,7 @@ the loss-less eval schedule, ``pp.py:146-150``.)
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from ddl_tpu.infer import LMDecode, init_kv_cache, make_lm_generator
 from ddl_tpu.models.transformer import LMConfig, TransformerLM
@@ -50,7 +51,7 @@ def test_prefill_matches_full_forward():
     ref_logits, _ = TransformerLM(cfg, None).apply({"params": params}, toks)
 
     caches = init_kv_cache(cfg, b, p + 2)
-    dec_logits, _ = LMDecode(cfg).apply({"params": params}, toks, caches, 0)
+    dec_logits, _ = LMDecode(cfg).apply({"params": params}, toks, caches)
     np.testing.assert_allclose(
         np.asarray(ref_logits), np.asarray(dec_logits), atol=1e-5
     )
@@ -71,13 +72,72 @@ def test_incremental_matches_full_forward():
     got = []
     for i in range(t):
         logits, caches = dec.apply(
-            {"params": params}, toks[:, i : i + 1], caches, i
+            {"params": params}, toks[:, i : i + 1], caches
         )
         got.append(logits[:, 0])
     np.testing.assert_allclose(
         np.asarray(ref_logits), np.stack([np.asarray(g) for g in got], 1),
         atol=1e-5,
     )
+
+
+def _paged_after_prefill(cfg, caches, prompt_len, block_size, nmax):
+    """What the serving engine does between its prefill and its decode
+    chunk: each lane's contiguous prefill rows scattered into blocks of its
+    own, the tables gathered once into the per-lane view."""
+    from ddl_tpu.ops.quant import kv_map
+    from ddl_tpu.serve.kv_pool import (
+        PagedKV, init_kv_pool, pool_gather, pool_write_prefill,
+    )
+
+    b = caches[0].kv[0].shape[0]
+    filled = caches[0].kv[0].shape[1] // block_size
+    tables = jnp.arange(b * nmax, dtype=jnp.int32).reshape(b, nmax)
+    lengths = jnp.full((b,), prompt_len, jnp.int32)
+    paged = []
+    for layer, pool in zip(caches, init_kv_pool(cfg, b * nmax, block_size)):
+        for lane in range(b):
+            row = kv_map(lambda a: a[lane:lane + 1], layer.kv)
+            pool = pool_write_prefill(pool, row, tables[lane, :filled])
+        paged.append(PagedKV(pool, pool_gather(pool, tables), tables, lengths))
+    return tuple(paged)
+
+
+@pytest.mark.parametrize(
+    "kind,window",
+    [("contiguous", 0), ("contiguous", 6), ("rolling", 6), ("paged", 0),
+     ("paged", 6)],
+)
+def test_every_cache_kind_decodes_the_full_forwards_logits(kind, window):
+    """The seam: one ``LMDecode`` over each kind of cache.  A prefill then
+    eight single-token steps give the full forward's logits at the same
+    positions — a linear buffer (whole, and its O(window) read slice), a
+    ring shorter than the run, and the paged pool with blocks of 4 under a
+    prompt of 5 (the last block half filled, lanes in blocks of their
+    own)."""
+    cfg = _cfg(attn_window=window)
+    b, p, n, bs = 2, 5, 8, 4
+    params = _params(cfg, b, p + n)
+    toks = jnp.asarray(np.random.default_rng(4).integers(0, 32, (b, p + n)))
+    ref, _ = TransformerLM(cfg, None).apply({"params": params}, toks)
+
+    dec = LMDecode(cfg)
+    if kind == "paged":
+        caches = init_kv_cache(cfg, b, -(-p // bs) * bs)
+    else:
+        caches = init_kv_cache(cfg, b, p + n, rolling=kind == "rolling")
+    logits, caches = dec.apply({"params": params}, toks[:, :p], caches)
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(ref[:, :p]), atol=1e-5)
+    if kind == "paged":
+        caches = _paged_after_prefill(cfg, caches, p, bs, nmax=4)
+    for i in range(p, p + n):
+        logits, caches = dec.apply({"params": params}, toks[:, i:i + 1], caches)
+        np.testing.assert_allclose(
+            np.asarray(logits[:, 0]), np.asarray(ref[:, i]), atol=1e-5
+        )
+    if kind == "paged":
+        with pytest.raises(ValueError, match="one new token a lane"):
+            dec.apply({"params": params}, toks[:, :2], caches)
 
 
 def test_greedy_generate_matches_teacher_forcing():
@@ -210,11 +270,11 @@ def test_gqa_incremental_matches_full_forward():
     ref_logits, _ = TransformerLM(cfg, None).apply({"params": params}, toks)
 
     caches = init_kv_cache(cfg, b, t)
-    assert caches[0][0].shape == (b, t, 2 * 8)  # Hkv=2, half the MHA cache (fused Hkv*Dh storage)
+    assert caches[0].kv[0].shape == (b, t, 2 * 8)  # Hkv=2, half the MHA cache (fused Hkv*Dh storage)
     dec = LMDecode(cfg)
     for i in range(t):
         logits, caches = dec.apply(
-            {"params": params}, toks[:, i : i + 1], caches, i
+            {"params": params}, toks[:, i : i + 1], caches
         )
         np.testing.assert_allclose(
             np.asarray(logits[:, 0]), np.asarray(ref_logits[:, i]), atol=1e-5
@@ -271,7 +331,7 @@ def test_rolling_cache_matches_linear_and_is_o_window():
         )["params"]
     )
     caches = init_kv_cache(cfg, 2, 64, rolling=True)
-    assert caches[0][0].shape == (2, 6, 4 * 8)  # (B, window, Hkv*Dh fused)
+    assert caches[0].kv[0].shape == (2, 6, 4 * 8)  # (B, window, Hkv*Dh fused)
     rng = np.random.default_rng(0)
     for prompt_len, max_new in ((12, 10), (3, 15)):
         prompt = jnp.asarray(
@@ -289,7 +349,6 @@ def test_rolling_cache_matches_linear_and_is_o_window():
 
     # auto mode turns the ring on exactly when a window is set and smaller
     # than the cache; without a window it must reject rolling=True
-    import pytest
 
     with pytest.raises(ValueError, match="attn_window"):
         make_lm_generator(

@@ -99,11 +99,10 @@ def test_bad_split_rejected(tiny_model_cfg):
         build_stages(cfg)
 
 
-@pytest.mark.parametrize("alt_impl", ["buffer", "packed"])
+@pytest.mark.parametrize("alt_impl", ["packed"])
 def test_alt_block_impl_matches_concat(tiny_model_cfg, alt_impl):
-    """dense_block_impl='buffer' (preallocated feature buffer, in-place
-    strips) and 'packed' (lane-aligned packs, implicit concat via
-    per-pack 1x1 contraction, stats-once) are the same math as the
+    """dense_block_impl='packed' (lane-aligned packs, implicit concat via
+    per-pack 1x1 contraction, stats-once) is the same math as the
     textbook concat form: identical params, forward, train-mode batch
     stats, and gradients."""
     import dataclasses

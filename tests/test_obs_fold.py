@@ -778,7 +778,7 @@ def test_steptrace_emits_restart_latency_once(tmp_path, monkeypatch):
 
     w = EventWriter(tmp_path, "rl", host=0)
     trace = StepTrace(w, emit_step_spans=0)
-    trace.begin_period(0)
+    trace.begin_period()
     for step in range(3):
         with trace.phase("data_wait", step=step):
             pass

@@ -141,7 +141,7 @@ def test_children_stay_out_of_the_phase_totals_and_goodput_still_sums(tmp_path):
     trace = StepTrace(EventWriter(tmp_path, "sum", host=0))
     trace.writer.emit("run_start", family="cnn", job_id="sum")
     for period in range(2):
-        trace.begin_period(period)
+        trace.begin_period()
         out = _Out(ready=True)
         for i in range(3):
             _step(trace, 3 * period + i, out)

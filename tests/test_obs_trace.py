@@ -206,11 +206,11 @@ def test_step_trace_spans_phases(tmp_path):
     for h in range(2):
         _write(tmp_path, "steps", h, [
             _ev(h, "span", 10.0 + 0.1 * h, step=7, name="step",
-                dur=0.08, depth=0, period=0),
+                dur=0.08, depth=0),
             _ev(h, "span", 10.2 + 0.1 * h, step=7, name="data_wait",
-                dur=0.01, depth=0, period=0),
+                dur=0.01, depth=0),
             _ev(h, "span", 11.0, step=8, name="step", dur=0.08,
-                depth=0, period=0),
+                depth=0),
         ])
     trace = trace_job(tmp_path, "steps", step=7)
     _assert_valid_chrome_trace(trace)
@@ -229,10 +229,8 @@ def test_step_trace_renders_schedule_lanes(tmp_path):
     _write(tmp_path, "zbsteps", 0, [
         _ev(0, "pipe_schedule", 5.0, schedule="zb", pipe=2,
             microbatches=4, virtual=1),
-        _ev(0, "span", 10.0, step=3, name="step", dur=0.08, depth=0,
-            period=0),
-        _ev(0, "span", 10.2, step=3, name="fence", dur=0.01, depth=0,
-            period=0),
+        _ev(0, "span", 10.0, step=3, name="step", dur=0.08, depth=0),
+        _ev(0, "span", 10.2, step=3, name="fence", dur=0.01, depth=0),
     ])
     trace = trace_job(tmp_path, "zbsteps", step=3)
     _assert_valid_chrome_trace(trace)
@@ -258,8 +256,7 @@ def test_step_trace_renders_schedule_lanes(tmp_path):
     _write(tmp_path, "badsched", 0, [
         _ev(0, "pipe_schedule", 5.0, schedule="1f1b", pipe=2,
             microbatches=4, virtual=2),
-        _ev(0, "span", 10.0, step=1, name="step", dur=0.05, depth=0,
-            period=0),
+        _ev(0, "span", 10.0, step=1, name="step", dur=0.05, depth=0),
     ])
     t2 = trace_job(tmp_path, "badsched", step=1)
     assert not [e for e in t2["traceEvents"]

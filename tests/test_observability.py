@@ -139,7 +139,7 @@ def test_step_span_sampler_one_in_n(tmp_path, monkeypatch):
 
     w = EventWriter(tmp_path, "job", host=0)
     trace = StepTrace(w, emit_step_spans=4)
-    trace.begin_period(0)
+    trace.begin_period()
     for step in range(10):
         with trace.phase("step", step=step):
             pass

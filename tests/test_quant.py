@@ -94,11 +94,11 @@ def test_kv_quant_incremental_close_to_exact():
 
     dec = LMDecode(cfg)
     caches = init_kv_cache(cfg, b, t, quant=True)
-    assert isinstance(caches[0], QuantKV)
+    assert isinstance(caches[0].kv, QuantKV)
     got = []
     for i in range(t):
         logits, caches = dec.apply(
-            {"params": params}, toks[:, i : i + 1], caches, i
+            {"params": params}, toks[:, i : i + 1], caches
         )
         got.append(np.asarray(logits[:, 0]))
     got = np.stack(got, 1)
@@ -266,8 +266,10 @@ def test_quant_cache_bytes_halved():
     """The allocation claim behind the bench rows: int8 cache bytes ≈
     0.53x bf16 (int8 payload + 1 f32 scale per head_dim values)."""
     cfg = _cfg(compute_dtype="bfloat16")
-    bf16 = jax.eval_shape(lambda: init_kv_cache(cfg, 4, 128))
-    q8 = jax.eval_shape(lambda: init_kv_cache(cfg, 4, 128, quant=True))
+    bf16 = jax.eval_shape(lambda: [c.kv for c in init_kv_cache(cfg, 4, 128)])
+    q8 = jax.eval_shape(
+        lambda: [c.kv for c in init_kv_cache(cfg, 4, 128, quant=True)]
+    )
     nbytes = lambda tree: sum(
         int(np.prod(a.shape)) * a.dtype.itemsize
         for a in jax.tree_util.tree_leaves(tree)

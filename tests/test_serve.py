@@ -453,9 +453,7 @@ def _tiny_cfg(**kw):
     return LMConfig(**base)
 
 
-@pytest.fixture(scope="module")
-def lm():
-    """Tiny LM params shared by every engine test in this module."""
+def _init_lm(cfg):
     import flax.linen as nn
     import jax
     import jax.numpy as jnp
@@ -463,13 +461,18 @@ def lm():
     from ddl_tpu.models.transformer import TransformerLM
     from ddl_tpu.parallel.sharding import LMMeshSpec
 
-    cfg = _tiny_cfg()
     params = nn.meta.unbox(
         TransformerLM(cfg, None).init(
             jax.random.key(0), jnp.zeros((1, 8), jnp.int32)
         )["params"]
     )
     return cfg, params, LMMeshSpec()
+
+
+@pytest.fixture(scope="module")
+def lm():
+    """Tiny LM params shared by every engine test in this module."""
+    return _init_lm(_tiny_cfg())
 
 
 @pytest.mark.parametrize("quant", [False, True])
@@ -589,11 +592,23 @@ def _clients(n, rng, lo=5, hi=20, new_lo=4, new_hi=12):
     ]
 
 
-def test_engine_matches_sequential_decode(lm):
+@pytest.mark.parametrize("block", ["gpt", "afmoe_capacity"])
+def test_engine_matches_sequential_decode(lm, block):
     """THE acceptance e2e: 8 concurrent clients, mixed prompt/output
-    lengths, bit-identical to 8 one-at-a-time LMDecode runs."""
+    lengths, bit-identical to 8 one-at-a-time LMDecode runs.  The second
+    block is what a second serving stack could not run: q/k norms, the
+    output gate, four norms, gated MLPs, the embedding multiplier and a
+    dense layer before a capacity-routed expert layer (capacity E/k: no
+    choice is dropped, so the padded prefill routes as the unpadded)."""
     from ddl_tpu.serve.engine import ServeEngine
 
+    if block == "afmoe_capacity":
+        lm = _init_lm(_tiny_cfg(
+            qk_norm=True, attn_gate=True, sandwich_norm=True, mlp_gated=True,
+            embed_scale=True, num_experts=4, expert_top_k=2,
+            num_dense_layers=1, capacity_factor=2.0,
+        ))
+        assert "mlp" in lm[1]["block0"] and "moe" in lm[1]["block1"]
     cfg, params, spec = lm
     clients = _clients(8, np.random.default_rng(7))
     eng = ServeEngine(cfg, params, spec, block_size=8, num_blocks=64,

@@ -230,7 +230,7 @@ def test_lm_windowed_decode_matches_training_forward():
     dec = LMDecode(cfg)
     for i in range(t):
         logits, caches = dec.apply(
-            {"params": params}, toks[:, i : i + 1], caches, i
+            {"params": params}, toks[:, i : i + 1], caches
         )
         np.testing.assert_allclose(
             np.asarray(logits[:, 0]), np.asarray(ref_logits[:, i]), atol=1e-5

@@ -205,7 +205,8 @@ def op_digest(trace_dir: str, top: int = 8, scope: dict | None = None) -> dict:
 
     ``scope`` is ``{module name: {instruction name: tag}}``; left out,
     ``_scope_tables``.  An op joins the table of the module it ran in and
-    groups by its tag — ``bwd``, ``kernel/flash_bwd_dkv``, ``update`` —
+    groups by its tag — ``bwd``, ``kernel/flash_bwd_dkv`` (the one flash
+    backward kernel: dQ, dK and dV), ``update`` —
     and an op of a module without a table, or one its table does not
     know, by ``other (<opcode>)``.  When no op finds a tag (no table, or
     a CPU trace, which names no module) all group by opcode category as

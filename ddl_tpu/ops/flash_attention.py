@@ -6,22 +6,28 @@ never sees more than one (block_q x block_k) tile at a time: the grid's
 innermost dimension walks K/V blocks against a resident Q block while
 running row-max / row-sum statistics live in VMEM scratch across grid steps
 (the same online softmax the ring schedule uses *across* devices, here
-applied *within* one device's block loop).  Per-program VMEM is
-O(block_q x head_dim + block_k x head_dim) regardless of sequence length,
-and every matmul lands on the MXU at (block, head_dim) granularity.
+applied *within* one device's block loop).  The forward's per-program
+VMEM is O(block_q x head_dim + block_k x head_dim) regardless of sequence
+length, and every matmul lands on the MXU at (block, head_dim) granularity.
 
-The backward pass is the standard two-kernel flash decomposition with a
-saved per-row logsumexp: one grid accumulates dQ over K/V blocks, one
-accumulates dK/dV over Q blocks, both recomputing probabilities from the
-residuals instead of storing them (rematerialisation in kernel form).
+The backward pass is one kernel over the same band, with a saved per-row
+logsumexp: each run of sub-tiles recomputes its probabilities from the
+residuals instead of storing them (rematerialisation in kernel form) and
+makes dS once, from which dQ, dK and dV all accumulate (five matmuls a
+sub-tile; the two-kernel decomposition computes S and dP twice, seven).
+Its grid runs at K/V-head granularity: dQ accumulates over the innermost
+K/V walk in a block-sized scratch as the forward's output does, and the
+head's dK and dV stay resident in VMEM for the whole sequence
+(``2 * T * head_dim`` float32) across every Q block of every query head in
+the group, written once; no partial sum goes through HBM.
 
 A causal (or windowed) call does the band's work and little more, at two
 granularities.  The grid skips whole (block_q x block_k) tiles outside the
 band via predicated execution (``pl.when``), the block-level analog of the
 ring schedule masking future blocks; that alone halves the causal FLOPs
 only at long T, and never engages while ``T <= block_k`` (one K block:
-every tile touches the band).  So inside a live tile each kernel walks Q
-sub-blocks and computes, for each, only the run of K sub-blocks it can
+every tile touches the band).  So inside a live tile both kernels walk Q
+sub-blocks and compute, for each, only the run of K sub-blocks it can
 see, in one pass, masking only the sub-blocks the band's edge crosses
 (``_visible``, ``_scores``).  With the defaults at T=1024 that is 10 of
 the square's 16 sub-tiles of 256 x 256 (62.5%), 4 of them masked;
@@ -148,7 +154,7 @@ def _sub_tile(t, block_q, block_k, causal, window):
     return _pick_block(block_q, edge), _pick_block(block_k, edge)
 
 
-_KERNELS = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+_KERNELS = ("flash_fwd", "flash_bwd_dkv")
 
 
 @functools.lru_cache(maxsize=256)
@@ -213,16 +219,15 @@ def flash_tile_plan(
         ):
             tiles["computed"] += c_hi - c_lo
             tiles["masked"] += len(_masked(c_hi - c_lo, reach, diagonal))
-    # the three kernels walk the same sub-tiles today; the record is per
+    # both kernels walk the same sub-tiles today; the record is per
     # kernel so that a kernel with a walk of its own can say so
     return {"sub_tile": [sub_q, sub_k], **{name: dict(tiles) for name in _KERNELS}}
 
 
-def _walk(name_rows, t, block_q, block_k, causal, window, kv_offset):
-    """What a launcher hands its ``pallas_call``s for one call's band:
-    the kernels' static walk arguments, and for each ``(kernel name,
-    grid rows)`` its ``metadata=``, the tile plan over those rows as
-    strings."""
+def _walk(name, rows, t, block_q, block_k, causal, window, kv_offset):
+    """What a launcher hands its ``pallas_call`` for one call's band: the
+    kernel's static walk arguments, and its ``metadata=``, kernel
+    ``name``'s tile plan over ``rows`` (batch, head) rows as strings."""
     sub_q, sub_k = _sub_tile(t, block_q, block_k, causal, window)
     walk = dict(sub_q=sub_q, sub_k=sub_k, runs=None, reach=(0, 0))
     if causal:
@@ -231,10 +236,7 @@ def _walk(name_rows, t, block_q, block_k, causal, window, kv_offset):
             reach=_edge_reach(sub_q, sub_k, block_q, block_k, kv_offset, window),
         )
     plan = flash_tile_plan(t, block_q, block_k, causal, window, kv_offset)
-    metadata = {
-        name: {f"tiles_{key}": str(rows * n) for key, n in plan[name].items()}
-        for name, rows in name_rows
-    }
+    metadata = {f"tiles_{key}": str(rows * n) for key, n in plan[name].items()}
     return walk, metadata
 
 
@@ -403,80 +405,41 @@ def _fwd_kernel(
         lse_ref[0, 0] = (m_sc[:] + jnp.log(l))[:, 0]
 
 
-def _dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_sc, *,
-    scale, causal, window=0, kv_offset=0, sub_q, sub_k, runs, reach,
+def _bwd_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+    dq_sc, dk_sc, dv_sc, *, scale, causal, window=0, kv_offset=0, q_blocks=1,
+    sub_q, sub_k, runs, reach,
 ):
-    i, j = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
+    # grid: (b*kv_heads, group*q_blocks, k_blocks).  The innermost
+    # dimension walks K/V blocks against a resident Q block, so dQ
+    # accumulates in a block-sized scratch as the forward's output does;
+    # the middle one walks every (query head in the group, Q block) pair
+    # of the K/V head, whose dK and dV stay resident for all of it in
+    # whole-sequence scratch, one (block_k, d) slab a K block, and leave
+    # once, at the head's last step.
+    iz, j = pl.program_id(1), pl.program_id(2)
+    nz, nk = pl.num_programs(1), dk_sc.shape[0]
+    i = iz % q_blocks  # Q-block index within the current group member
     bq = q_ref.shape[1]
     bk = k_ref.shape[1]
+
+    @pl.when((iz == 0) & (j == 0))
+    def _():
+        dk_sc[:] = jnp.zeros_like(dk_sc)
+        dv_sc[:] = jnp.zeros_like(dv_sc)
 
     @pl.when(j == 0)
     def _():
         dq_sc[:] = jnp.zeros_like(dq_sc)
 
+    # K/V blocks outside the Q block's visible band contribute nothing
     live = _qk_live(i, j, bq, bk, causal, window, kv_offset)
 
     @pl.when(live)
     def _():
         k0 = j * bk - kv_offset
-        for a in range(bq // sub_q):
-            rows = slice(a * sub_q, (a + 1) * sub_q)
-            r0 = i * bq + a * sub_q
-
-            def step(c_lo, c_hi, diagonal, rows=rows, r0=r0):
-                keys = slice(c_lo * sub_k, c_hi * sub_k)
-                masked = _masked(c_hi - c_lo, reach, diagonal)
-                q = q_ref[0, rows, :].astype(jnp.float32) * scale
-                k_blk = k_ref[0, keys, :].astype(jnp.float32)
-                v_blk = v_ref[0, keys, :].astype(jnp.float32)
-                do = do_ref[0, rows, :].astype(jnp.float32)
-                s = _scores(q, k_blk, r0, k0 + c_lo * sub_k, sub_k, masked,
-                            window)
-                p = jnp.exp(s - lse_ref[0, 0, rows][:, None])
-                dp = jnp.dot(do, v_blk.T, preferred_element_type=jnp.float32)
-                ds = p * (dp - delta_ref[0, 0, rows][:, None])
-                dq_sc[rows] += jnp.dot(
-                    ds, k_blk, preferred_element_type=jnp.float32
-                )
-
-            _visible(step, _branches(runs, a), r0, sub_q, k0, sub_k,
-                     bk // sub_k, window)
-
-    @pl.when(j == nk - 1)
-    def _():
-        dq_ref[0] = (dq_sc[:] * scale).astype(dq_ref.dtype)
-
-
-def _dkdv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    dk_sc, dv_sc, *, scale, causal, window=0, kv_offset=0, q_blocks=1,
-    sub_q, sub_k, runs, reach,
-):
-    # grid: (b*kv_heads, k_blocks, group*q_blocks) — the innermost
-    # dimension walks every (query head in the group, Q block) pair, so
-    # dK/dV accumulate over the whole query-head group at Hkv granularity
-    j, iz = pl.program_id(1), pl.program_id(2)
-    nz = pl.num_programs(2)
-    i = iz % q_blocks  # Q-block index within the current group member
-    bk = k_ref.shape[1]
-    bq = q_ref.shape[1]
-
-    @pl.when(iz == 0)
-    def _():
-        dk_sc[:] = jnp.zeros_like(dk_sc)
-        dv_sc[:] = jnp.zeros_like(dv_sc)
-
-    # Q blocks outside this K/V block's visible band contribute nothing
-    live = _qk_live(i, j, bq, bk, causal, window, kv_offset)
-
-    @pl.when(live)
-    def _():
-        k0 = j * bk - kv_offset
-        # the same walk as the forward and dQ (the K sub-blocks each Q
-        # sub-block sees), so one bound serves all three kernels; dK/dV
-        # accumulate in their scratch, the run's rows at a time
+        # the forward's walk (the K sub-blocks each Q sub-block sees): S,
+        # P, dP and dS of a run are computed once and feed all three sums
         for a in range(bq // sub_q):
             rows = slice(a * sub_q, (a + 1) * sub_q)
             r0 = i * bq + a * sub_q
@@ -491,24 +454,33 @@ def _dkdv_kernel(
                 s = _scores(q_blk, k_blk, r0, k0 + c_lo * sub_k, sub_k,
                             masked, window)
                 p = jnp.exp(s - lse_ref[0, 0, rows][:, None])
-                dv_sc[keys, :] += jnp.dot(
+                dv_sc[j, keys, :] += jnp.dot(
                     p.T, do_blk, preferred_element_type=jnp.float32
                 )
                 dp = jnp.dot(
                     do_blk, v_blk.T, preferred_element_type=jnp.float32
                 )
                 ds = p * (dp - delta_ref[0, 0, rows][:, None])
-                dk_sc[keys, :] += jnp.dot(
+                dk_sc[j, keys, :] += jnp.dot(
                     ds.T, q_blk, preferred_element_type=jnp.float32
+                )
+                dq_sc[rows] += jnp.dot(
+                    ds, k_blk, preferred_element_type=jnp.float32
                 )
 
             _visible(step, _branches(runs, a), r0, sub_q, k0, sub_k,
                      bk // sub_k, window)
 
-    @pl.when(iz == nz - 1)
+    @pl.when(j == nk - 1)
     def _():
-        dk_ref[0] = dk_sc[:].astype(dk_ref.dtype)  # scale folded into q_blk
-        dv_ref[0] = dv_sc[:].astype(dv_ref.dtype)
+        dq_ref[0] = (dq_sc[:] * scale).astype(dq_ref.dtype)
+
+    @pl.when((iz == nz - 1) & (j == nk - 1))
+    def _():
+        for jj in range(nk):
+            blk = slice(jj * bk, (jj + 1) * bk)
+            dk_ref[0, blk, :] = dk_sc[jj].astype(dk_ref.dtype)  # scale folded into q_blk
+            dv_ref[0, blk, :] = dv_sc[jj].astype(dv_ref.dtype)
 
 
 def _kv_row(b, q_heads, kv_heads):
@@ -520,8 +492,9 @@ def _kv_row(b, q_heads, kv_heads):
 
 # The two launchers are jitted so that a program with many attention layers
 # traces and lowers each kernel once, not once a layer: the walk's branches
-# are several times the pre-walk body, and a 12-layer step paid for them 36
-# times at every start, cached executable or not (PERF.md section 6, PR 26).
+# are several times the pre-walk body, and a 12-layer step paid for them once
+# a kernel a layer at every start, cached executable or not (PERF.md section
+# 6, PR 26).
 @functools.partial(jax.jit, static_argnums=tuple(range(3, 11)))
 def _flash_fwd_impl(
     q, k, v, causal, window, kv_offset, block_q, block_k, interpret,
@@ -531,7 +504,7 @@ def _flash_fwd_impl(
     scale = 1.0 / (d ** 0.5)
     kv_idx = lambda b, i, j: (_kv_row(b, q_heads, kv_heads), j, 0)
     walk, metadata = _walk(
-        [("flash_fwd", bh)], t, block_q, block_k, causal, window, kv_offset
+        "flash_fwd", bh, t, block_q, block_k, causal, window, kv_offset
     )
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
@@ -560,97 +533,105 @@ def _flash_fwd_impl(
         ],
         interpret=interpret,
         name="flash_fwd",
-        metadata=metadata["flash_fwd"],
+        metadata=metadata,
     )(q, k, v)
     return out, lse
 
 
+# What the backward kernel may take of a core's 128 MiB of VMEM (Mosaic's
+# default scope is 16 MiB), and what of that is left for a K/V head's
+# resident gradients once the working tiles are counted: the Q, K, V and dO
+# blocks twice (the pipeline's buffers), dQ's scratch and block, and a
+# sub-tile run's float32 scores, probabilities and their two transposes
+# (about 14 MiB at 1024 x 1024 blocks walked in 512-row runs, head_dim 128).
+_BWD_VMEM_LIMIT = 96 * 1024 * 1024
+_BWD_RESIDENT_LIMIT = _BWD_VMEM_LIMIT - 24 * 1024 * 1024
+
+
 @functools.partial(jax.jit, static_argnums=tuple(range(7, 15)))
-def _flash_bwd_kernels(q, k, v, out, lse, do, dlse, causal, window,
-                       kv_offset, block_q, block_k, interpret, q_heads,
-                       kv_heads):
-    """Shared backward: the two flash kernels with
-    ``ds = p * (dp - (delta - dlse))``.
+def _flash_bwd_impl(q, k, v, out, lse, do, dlse, causal, window,
+                    kv_offset, block_q, block_k, interpret, q_heads,
+                    kv_heads):
+    """Shared backward: one kernel, one walk of the band, with
+    ``ds = p * (dp - (delta - dlse))`` feeding dQ, dK and dV alike.
 
     With ``dlse=None`` this is the classic flash backward (cotangent on the
     output only).  A nonzero ``dlse`` (cotangent on the per-row logsumexp,
     layout (bh, 1, t)) arises when the caller consumes lse — the ring
-    schedule's cross-block combination does — and enters the kernels purely
+    schedule's cross-block combination does — and enters the kernel purely
     through the delta term: d lse_i/d s_ij = p_ij, so the correction folds
-    into the same ``p * (...)`` product the kernels already compute.
+    into the same ``p * (...)`` product the kernel already computes.
 
-    Grouped K/V: dQ reads the group's shared K/V row per query head; the
-    dK/dV grid runs at K/V-head granularity with its innermost dimension
-    extended over every (group member, Q block) pair, accumulating the
-    whole group's contribution into one (bkv, t, d) gradient.
+    The grid runs at K/V-head granularity, ``(bkv, g * q_blocks,
+    k_blocks)``: the middle dimension walks every (group member, Q block)
+    pair of the head, the innermost the K/V blocks against it.  dQ leaves
+    a Q block at a time; the head's dK and dV (the whole group's
+    contribution, one (bkv, t, d) gradient) stay in VMEM until its last
+    step, ``2 * t * d`` float32 beside their output blocks.  A
+    sequence too long for that is refused from the shapes: it belongs on
+    the ring schedule, which hands this kernel ``T_local``.
     """
     bh, t, d = q.shape
     bkv = k.shape[0]
     g = q_heads // kv_heads
     scale = 1.0 / (d ** 0.5)
+    lanes = -(-d // 128) * 128  # VMEM rows are whole 128-lane tiles
+    resident = 2 * t * lanes * (4 + 2 * k.dtype.itemsize)
+    if resident > _BWD_RESIDENT_LIMIT:
+        raise ValueError(
+            f"flash backward keeps a K/V head's dK and dV resident: T={t}, "
+            f"head_dim={d} need {resident} bytes of VMEM, over "
+            f"{_BWD_RESIDENT_LIMIT}; shard the sequence (attn_impl='ring')"
+        )
     delta = jnp.sum(
         do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
     )[:, None, :]  # (bh, 1, t) — same row-stat layout as lse
     if dlse is not None:
         delta = delta - dlse.astype(jnp.float32)
 
-    kv_idx = lambda b, i, j: (_kv_row(b, q_heads, kv_heads), j, 0)
-    q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    kv_spec = pl.BlockSpec((1, block_k, d), kv_idx)
-    row_spec = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i))
-    walk, metadata = _walk(
-        [("flash_bwd_dq", bh), ("flash_bwd_dkv", bh)], t, block_q, block_k,
-        causal, window, kv_offset,
-    )
-
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          window=window, kv_offset=kv_offset, **walk),
-        out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-        grid=(bh, t // block_q, t // block_k),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=q_spec,
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
-        name="flash_bwd_dq",
-        metadata=metadata["flash_bwd_dq"],
-    )(q, k, v, do, lse, delta)
-
-    # grid (bkv, k_blocks, g * q_blocks): outermost at K/V-head
-    # granularity, innermost walking every (group member, Q block) pair
-    nq = t // block_q
+    nq, nk = t // block_q, t // block_k
 
     def q_row(b, iz):
         return (b // kv_heads) * q_heads + (b % kv_heads) * g + iz // nq
 
-    q_spec_t = pl.BlockSpec(
-        (1, block_q, d), lambda b, j, iz: (q_row(b, iz), iz % nq, 0)
+    q_spec = pl.BlockSpec(
+        (1, block_q, d), lambda b, iz, j: (q_row(b, iz), iz % nq, 0)
     )
-    kv_spec_t = pl.BlockSpec((1, block_k, d), lambda b, j, iz: (b, j, 0))
-    row_spec_t = pl.BlockSpec(
-        (1, 1, block_q), lambda b, j, iz: (q_row(b, iz), 0, iz % nq)
+    row_spec = pl.BlockSpec(
+        (1, 1, block_q), lambda b, iz, j: (q_row(b, iz), 0, iz % nq)
     )
-    dk, dv = pl.pallas_call(
+    kv_spec = pl.BlockSpec((1, block_k, d), lambda b, iz, j: (b, j, 0))
+    head_spec = pl.BlockSpec((1, t, d), lambda b, iz, j: (b, 0, 0))
+    # the kernel is named for its grid, the K/V head's; the plan counts
+    # (batch, query head) rows like the forward's
+    walk, metadata = _walk(
+        "flash_bwd_dkv", bh, t, block_q, block_k, causal, window, kv_offset
+    )
+    return pl.pallas_call(
         functools.partial(
-            _dkdv_kernel, scale=scale, causal=causal, window=window,
+            _bwd_kernel, scale=scale, causal=causal, window=window,
             kv_offset=kv_offset, q_blocks=nq, **walk,
         ),
         out_shape=(
+            jax.ShapeDtypeStruct((bh, t, d), q.dtype),
             jax.ShapeDtypeStruct((bkv, t, d), k.dtype),
             jax.ShapeDtypeStruct((bkv, t, d), v.dtype),
         ),
-        grid=(bkv, t // block_k, g * nq),
-        in_specs=[q_spec_t, kv_spec_t, kv_spec_t, q_spec_t, row_spec_t, row_spec_t],
-        out_specs=(kv_spec_t, kv_spec_t),
+        grid=(bkv, g * nq, nk),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=(q_spec, head_spec, head_spec),
         scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((nk, block_k, d), jnp.float32),
+            pltpu.VMEM((nk, block_k, d), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_BWD_VMEM_LIMIT
+        ),
         interpret=interpret,
         name="flash_bwd_dkv",
-        metadata=metadata["flash_bwd_dkv"],
+        metadata=metadata,
     )(q, k, v, do, lse, delta)
-    return dq, dk, dv
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
@@ -681,7 +662,7 @@ def _flash_lse_vjp_bwd(
 ):
     do, dlse = cts
     q, k, v, out, lse = residuals
-    return _flash_bwd_kernels(
+    return _flash_bwd_impl(
         q, k, v, out, lse, do, dlse, causal, window, kv_offset, block_q,
         block_k, interpret, q_heads, kv_heads,
     )
@@ -749,7 +730,10 @@ def flash_attention(
     7.03); ``block_q=2048`` loses (16.3).  ``block_k`` was not swept
     again: the older sweep that chose 1024 (PERF_HISTORY.md) found 512
     slower at every T, and a K step costs what it did.  The T^2 score
-    tile stays out of HBM either way.
+    tile stays out of HBM either way.  Those backward figures are the two
+    kernels' of that PR; the one kernel since PR 30 takes 1.15 ms a call
+    at the cell's shape where they took 1.55 (kernel events of a device
+    trace, v5e), and 1.65 against 1.93 at B=1, H=12, T=8192, window 1024.
     ``interpret=None`` interprets on the CPU backend (tests on the
     simulated mesh) and compiles on a TPU (``ops/interpret.py``).
     """

@@ -675,7 +675,7 @@ def test_a_kernel_takes_the_part_it_is_called_in():
 ENTRY %main () -> f32[] {
   %p = bf16[8,8]{1,0} parameter(0)
   %moe_gmm_fwd.1 = bf16[8,8]{1,0} custom-call(%p), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/jvp(LM)/block1/moe/moe._dropless/moe/experts/moe_gmm_fwd/pallas_call"}
-  %flash.2 = bf16[8,8]{1,0} custom-call(%p), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/transpose(jvp(LM))/block1/attn/flash_bwd_dq/pallas_call"}
+  %flash.2 = bf16[8,8]{1,0} custom-call(%p), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/transpose(jvp(LM))/block1/attn/flash_bwd_dkv/pallas_call"}
   %rows.6 = bf16[8,8]{1,0} custom-call(%p), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/jvp(LM)/block1/moe/moe._dropless/moe/dispatch/jit(_gather)/moe_rows_gather/pallas_call"}
   %rows.7 = bf16[8,8]{1,0} custom-call(%rows.6), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/transpose(jvp(LM))/block1/moe/moe._dropless/moe/combine/jit(_gather)/moe_rows_combine_bwd/pallas_call"}
   %f.3 = f32[] fusion(%flash.2), kind=kLoop, metadata={op_name="jit(step)/jvp(LM)/head/reduce_sum"}

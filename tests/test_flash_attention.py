@@ -61,20 +61,32 @@ def test_flash_matches_dense_forward(qkv, causal, blocks):
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5, rtol=1e-4)
 
 
-@pytest.mark.parametrize("causal", [False, True])
+# (causal, window): no band, the causal band, and a window that is / is not
+# a multiple of every sub-tile edge the cases below walk (8 and 16)
+BANDS = [(False, 0), (True, 0), (True, 16), (True, 20)]
+
+
+@pytest.mark.parametrize("causal,window", BANDS)
 @pytest.mark.parametrize(
-    "blocks", [(16, 32, None), CELL, TWO_Q, (64, 32, 8)], indirect=True
+    "blocks",
+    [(16, 32, None), CELL, TWO_Q, (64, 32, 8), (16, 16, 8), (32, 16, None)],
+    indirect=True,
 )
-def test_flash_matches_dense_grads(qkv, causal, blocks):
+def test_flash_matches_dense_grads(qkv, causal, window, blocks):
+    """dQ, dK and dV of the one backward kernel against the dense
+    reference.  The block cases cover its grid: one tile (CELL), dQ
+    crossing K steps (one Q block, two K blocks), dK/dV crossing Q steps
+    (TWO_Q) and both at once, with and without sub-tiles in the tile."""
     q, k, v = qkv
     rng = np.random.default_rng(1)
     cot = jnp.asarray(rng.normal(size=q.shape), jnp.float32)
 
     def loss_flash(q, k, v):
-        return (flash_attention(q, k, v, causal=causal, **blocks) * cot).sum()
+        out = flash_attention(q, k, v, causal=causal, window=window, **blocks)
+        return (out * cot).sum()
 
     def loss_dense(q, k, v):
-        return (dense_attention(q, k, v, causal=causal) * cot).sum()
+        return (dense_attention(q, k, v, causal=causal, window=window) * cot).sum()
 
     gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
     gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
@@ -240,33 +252,44 @@ def test_flash_auto_short_seq_trains_dense():
         assert np.isfinite(float(m["loss"]))
 
 
-def test_flash_with_lse_matches_dense_logsumexp():
+@pytest.mark.parametrize("kv_offset", [0, 8, 32])
+@pytest.mark.parametrize("kv_heads", [2, 1])
+def test_flash_with_lse_matches_dense_logsumexp(kv_offset, kv_heads):
     """flash_attention_with_lse: out == dense attention, lse == the true
     per-row logsumexp of the scaled scores; both differentiable including
-    a nonzero lse cotangent (the ring-combination consumption pattern)."""
+    a nonzero lse cotangent (the ring-combination consumption pattern),
+    also where the K/V block lies ``kv_offset`` positions back (a ring
+    hop: part of the band at 8, all of it at T) and is shared by two
+    query heads."""
     from ddl_tpu.ops.flash_attention import flash_attention_with_lse
 
     rng = np.random.default_rng(5)
     b, t, h, d = 2, 32, 2, 8
-    q, k, v = (
-        jnp.asarray(rng.normal(size=(b, t, h, d)), jnp.float32)
-        for _ in range(3)
+    q = jnp.asarray(rng.normal(size=(b, t, h, d)), jnp.float32)
+    k, v = (
+        jnp.asarray(rng.normal(size=(b, t, kv_heads, d)), jnp.float32)
+        for _ in range(2)
     )
+    visible = (np.arange(t)[None, :] - kv_offset) <= np.arange(t)[:, None]
 
     def dense_ref(q, k, v):
+        k, v = (jnp.repeat(x, h // kv_heads, axis=2) for x in (k, v))
         s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(
             jnp.asarray(d, jnp.float32)
         )
-        s = jnp.where(jnp.tril(jnp.ones((t, t), bool))[None, None], s, -1e30)
+        s = jnp.where(jnp.asarray(visible)[None, None], s, -1e30)
         lse = jax.scipy.special.logsumexp(s, axis=-1)  # (B, H, T)
         out = jnp.einsum(
             "bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v
         )
         return out, lse
 
-    out_f, lse_f = flash_attention_with_lse(
-        q, k, v, causal=True, block_q=16, block_k=16
-    )
+    def flash(q, k, v):
+        return flash_attention_with_lse(
+            q, k, v, causal=True, block_q=16, block_k=16, kv_offset=kv_offset
+        )
+
+    out_f, lse_f = flash(q, k, v)
     out_d, lse_d = dense_ref(q, k, v)
     np.testing.assert_allclose(np.asarray(out_f), np.asarray(out_d), atol=1e-5)
     np.testing.assert_allclose(np.asarray(lse_f), np.asarray(lse_d), atol=1e-5)
@@ -275,20 +298,18 @@ def test_flash_with_lse_matches_dense_logsumexp():
     co = jnp.asarray(rng.normal(size=out_d.shape), jnp.float32)
     cl = jnp.asarray(rng.normal(size=lse_d.shape), jnp.float32)
 
-    def loss_flash(q, k, v):
-        o, l = flash_attention_with_lse(
-            q, k, v, causal=True, block_q=16, block_k=16
+    def loss(attend):
+        def f(q, k, v):
+            o, l = attend(q, k, v)
+            return (o * co).sum() + (l * cl).sum()
+        return f
+
+    gf = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    gd = jax.grad(loss(dense_ref), argnums=(0, 1, 2))(q, k, v)
+    for a, b_, name in zip(gf, gd, "qkv"):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b_), atol=2e-4, err_msg=name
         )
-        return (o * co).sum() + (l * cl).sum()
-
-    def loss_dense(q, k, v):
-        o, l = dense_ref(q, k, v)
-        return (o * co).sum() + (l * cl).sum()
-
-    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
-    for a, b_ in zip(gf, gd):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=2e-4)
 
 
 @pytest.mark.parametrize("causal", [True, False])
@@ -353,21 +374,25 @@ def test_lm_ring_flash_matches_dense():
     assert err < 1e-4
 
 
+@pytest.mark.parametrize("hkv", [2, 1], ids=["group4", "group8"])
 @pytest.mark.parametrize("blocks", [32, (64, 128, 32)], indirect=True)
-def test_flash_gqa_matches_dense_and_repeated(blocks):
+def test_flash_gqa_matches_dense_and_repeated(blocks, hkv):
     """Grouped K/V through the Pallas kernel: forward equals the grouped
     dense core; gradients equal the repeat-then-attend formulation with
-    dK/dV accumulated over the query-head group at Hkv granularity (the
-    second case walks sub-tiles inside dK/dV's group walk)."""
+    dK/dV accumulated over the query-head group at Hkv granularity, at 4
+    and at Trinity-Mini's 8 query heads a K/V head.  At blocks of 32 the
+    head's dK/dV stay resident over 4 K blocks while 4 Q blocks of each
+    group member cross them; the second case walks sub-tiles inside the
+    group walk."""
     from ddl_tpu.ops.attention import dense_attention
 
     rng = np.random.default_rng(12)
-    b, t, hq, hkv, d = 2, 128, 8, 2, 16
+    b, t, hq, d = 2, 128, 8, 16
     g = hq // hkv
     q = jnp.asarray(rng.normal(size=(b, t, hq, d)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(b, t, hkv, d)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(b, t, hkv, d)), jnp.float32)
-    for window in (0, 32):
+    for window in (0, 32, 40):
         out = flash_attention(q, k, v, causal=True, window=window, **blocks)
         ref = dense_attention(q, k, v, causal=True, window=window)
         np.testing.assert_allclose(
@@ -446,14 +471,23 @@ def test_flash_kv_offset_empty_band_rows_are_zero(blocks):
     want = dense_attention(q, k, v, mask=jnp.asarray(mask))
     got = np.asarray(out[:, :7])
     np.testing.assert_allclose(got, np.asarray(want)[:, :7], atol=2e-5)
-    # backward stays finite and zero for the empty rows
+    # backward stays finite and zero for the empty rows, and they add
+    # nothing to dK and dV: all three equal the dense band's over the rows
+    # that see a key
     g = jax.grad(
-        lambda x: flash_attention_with_lse(
-            x, k, v, causal=True, window=8, kv_offset=t, **blocks
-        )[0].sum()
-    )(q)
-    assert bool(jnp.isfinite(g).all())
-    np.testing.assert_array_equal(np.asarray(g[:, 7:]), 0.0)
+        lambda *x: flash_attention_with_lse(
+            *x, causal=True, window=8, kv_offset=t, **blocks
+        )[0].sum(),
+        argnums=(0, 1, 2),
+    )(q, k, v)
+    assert all(bool(jnp.isfinite(x).all()) for x in g)
+    np.testing.assert_array_equal(np.asarray(g[0][:, 7:]), 0.0)
+    gd = jax.grad(
+        lambda *x: dense_attention(*x, mask=jnp.asarray(mask))[:, :7].sum(),
+        argnums=(0, 1, 2),
+    )(q, k, v)
+    for got, want in ((g[0][:, :7], gd[0][:, :7]), (g[1], gd[1]), (g[2], gd[2])):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
 
 def _brute_plan(t, sub_q, sub_k, causal, window, kv_offset):
@@ -550,6 +584,9 @@ def test_flash_tile_plan_engages_in_the_benchmark_cell():
     two arms: a long T and a window walk coarser squares."""
     plan = fa.flash_tile_plan(1024, causal=True)
     assert plan["sub_tile"] == [256, 256]
+    # one forward and one backward kernel, the latter named for its grid
+    assert fa._KERNELS == ("flash_fwd", "flash_bwd_dkv")
+    assert sorted(plan) == ["flash_bwd_dkv", "flash_fwd", "sub_tile"]
     for name in fa._KERNELS:
         assert plan[name] == {"total": 16, "computed": 10, "masked": 4}
         assert plan[name]["computed"] <= 0.75 * plan[name]["total"]
@@ -558,3 +595,19 @@ def test_flash_tile_plan_engages_in_the_benchmark_cell():
     assert full["flash_fwd"] == {"total": 1, "computed": 1, "masked": 0}
     assert fa.flash_tile_plan(8192, causal=True)["sub_tile"] == [512, 512]
     assert fa.flash_tile_plan(1024, causal=True, window=256)["sub_tile"] == [512, 512]
+
+
+def test_flash_backward_refuses_a_sequence_its_vmem_cannot_hold():
+    """The backward keeps a K/V head's dK and dV in VMEM for the whole
+    sequence; past what a core can hold it says so from the shapes (the
+    forward has no such limit), and names the ring schedule."""
+    q = jax.ShapeDtypeStruct((1, 65536, 1, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(jnp.float32).sum()
+
+    jax.eval_shape(loss, q, q, q)
+    with pytest.raises(ValueError, match="resident.*ring"):
+        jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+    half = jax.ShapeDtypeStruct((1, 32768, 1, 128), jnp.bfloat16)
+    jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2)), half, half, half)

@@ -73,15 +73,19 @@ def _sum_f32(x):
 
 
 @pytest.mark.parametrize(
-    "batch,kv_heads,seq,window",
+    "batch,heads,kv_heads,head_dim,seq,window",
     [
-        pytest.param(8, 12, 1024, 0, id="gpt2s-mha"),
-        pytest.param(8, 4, 1024, 0, id="gpt2s-gqa4"),
-        pytest.param(1, 12, 8192, 1024, id="t8192-window1024"),
+        pytest.param(8, 12, 12, 64, 1024, 0, id="gpt2s-mha"),
+        pytest.param(8, 12, 4, 64, 1024, 0, id="gpt2s-gqa4"),
+        pytest.param(1, 12, 12, 64, 8192, 1024, id="t8192-window1024"),
+        # the Trinity-Mini cell's two kinds of layer: 8 query heads a K/V
+        # head, whose dK and dV stay in VMEM over 4 K blocks (4 MiB)
+        pytest.param(2, 32, 4, 128, 4096, 2048, id="trinity-sliding"),
+        pytest.param(2, 32, 4, 128, 4096, 0, id="trinity-full"),
     ],
 )
 def test_flash_attention_fwd_and_grad(
-    compile_for_chip, batch, kv_heads, seq, window
+    compile_for_chip, batch, heads, kv_heads, head_dim, seq, window
 ):
     from ddl_tpu.ops.flash_attention import flash_attention
 
@@ -90,22 +94,23 @@ def test_flash_attention_fwd_and_grad(
             q, k, v, causal=True, window=window, interpret=False
         )
 
-    q = _s((batch, seq, 12, 64), BF16)
-    kv = _s((batch, seq, kv_heads, 64), BF16)
+    q = _s((batch, seq, heads, head_dim), BF16)
+    kv = _s((batch, seq, kv_heads, head_dim), BF16)
     compile_for_chip(attend, q, kv, kv)
     text = compile_for_chip(
         jax.grad(lambda q, k, v: _sum_f32(attend(q, k, v)), argnums=(0, 1, 2)),
         q, kv, kv,
     )
     # the compiled kernels say what they walk (what ``hbm_plan`` records):
-    # the pure plan, over the call's batch x heads rows
+    # the pure plan, over the call's batch x heads rows; the gradients are
+    # one kernel's, named for its grid (the K/V head's)
     from ddl_tpu.obs.scope import kernel_tiles
     from ddl_tpu.ops.flash_attention import flash_tile_plan
 
     plan = flash_tile_plan(seq, causal=True, window=window)
     assert kernel_tiles(text) == {
-        name: {"calls": 1, **{k: batch * 12 * n for k, n in plan[name].items()}}
-        for name in ("flash_bwd_dkv", "flash_bwd_dq", "flash_fwd")
+        name: {"calls": 1, **{k: batch * heads * n for k, n in plan[name].items()}}
+        for name in ("flash_bwd_dkv", "flash_fwd")
     }
     assert plan["flash_fwd"]["computed"] < plan["flash_fwd"]["total"]
 

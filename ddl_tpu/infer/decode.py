@@ -40,6 +40,7 @@ from ddl_tpu.models.transformer import (
     LMConfig,
     apply_final_norm_and_head,
     make_embed,
+    refuse_cache_over_layer_types,
 )
 # Jit-boundary spec + the family rule table come from the partition-rule
 # engine (parallel/rules.py); re-exported here for the generator's
@@ -81,7 +82,8 @@ class LMDecode(nn.Module):
         self, tokens, caches, last_only: bool = False, last_index=None,
     ):
         cfg = self.cfg
-        x = make_embed(cfg)(tokens)
+        embed = make_embed(cfg)
+        x = embed(tokens)
         x = nn.with_logical_constraint(x, ("batch", "act_seq", "act_embed"))
         new_caches = []
         for i in range(cfg.n_layers):
@@ -100,7 +102,7 @@ class LMDecode(nn.Module):
             x = lax.dynamic_slice_in_dim(x, last_index, 1, axis=1)
         elif last_only:  # prefill only needs the next-token logits
             x = x[:, -1:]
-        return apply_final_norm_and_head(cfg, x), tuple(new_caches)
+        return apply_final_norm_and_head(cfg, x, embed), tuple(new_caches)
 
 
 def prefill_attn_core(cfg: LMConfig, mesh, prompt_len: int):
@@ -209,6 +211,7 @@ def make_lm_generator(
     timestamp enqueue: the gap to dispatch is emitted as ``queue_delay``
     (0.0 for callers that dispatch inline).
     """
+    refuse_cache_over_layer_types(cfg)
     if max_len is None:
         max_len = prompt_len + max_new
     elif max_len < prompt_len + max_new:

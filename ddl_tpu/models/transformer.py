@@ -27,6 +27,17 @@ product of ``ops/grouped_matmul.py``) that is told which experts it holds
 part.  That is the afmoe block of ``benchmark/configs/trinity-mini.json``;
 the defaults are the block above, unchanged.
 
+A layer's mixer need not be attention at all (``layer_types`` again): a
+Mamba-1 layer (``Mamba``: causal depthwise convolution, the selective scan
+of ``ops/selective_scan.py``, a gate), a Gated Memory Unit that gates an
+earlier Mamba layer's scan output (``Gmu``), differential attention over
+head pairs (``DiffAttention``), and cross-attention over an earlier full
+layer's K and V.  The two values that travel down the stack are an
+explicit argument and result of ``Block`` (``carry``), so a rematerialised
+layer is recomputed from them and their layers are not.  LayerNorm with
+bias for RMSNorm, no positions, and a head tied to the embedding complete
+the SambaY stack of ``benchmark/configs/phi4-mini-flash.json``.
+
 No torch/CUDA analog exists in the reference; parity citations therefore
 point at the subsystems this family plugs into: the mesh backbone
 (SURVEY.md §2 C10), the trainer (C3), and the checkpointing layout (C8).
@@ -35,6 +46,7 @@ point at the subsystems this family plugs into: the mesh backbone
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import Any, Callable, Optional
 
@@ -45,6 +57,7 @@ import jax.numpy as jnp
 from ddl_tpu.ops.attention import dense_attention
 
 __all__ = [
+    "LAYER_KINDS",
     "LMConfig",
     "REMAT_POLICIES",
     "TransformerLM",
@@ -56,6 +69,12 @@ __all__ = [
     "moe_routing_plan",
     "remat_block",
 ]
+
+
+LAYER_KINDS = ("sliding_attention", "full_attention", "mamba", "gmu",
+               "cross_attention")
+# {a kind of layer that reads a carried value: the kind that keeps it}
+_CARRIED = {"gmu": "mamba", "cross_attention": "full_attention"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -236,15 +255,57 @@ class LMConfig:
     # experts held elsewhere add nothing here (their owners' exchange is
     # not this program's).  (0, 1) holds them all.  'sigmoid' only.
     expert_share: tuple = (0, 1)
+    # --- a hybrid stack: layer_types may also name "mamba" (a Mamba-1
+    # mixer), "gmu" (a Gated Memory Unit over the scan output of the
+    # nearest mamba layer before it) and "cross_attention" (queries of its
+    # own over the K/V of the nearest full_attention layer before it) ---
+    # "layer": LayerNorm with scale and bias where "rms" has RMSNorm
+    norm: str = "rms"
+    # logits = x E^T with the embedding's own rows: one leaf, no lm_head
+    tie_embeddings: bool = False
+    # differential attention: heads pair up (2j, 2j+1), a pair's output is
+    # (softmax(q1 k1^T) - lam softmax(q2 k2^T)) [v1, v2], RMSNorm over the
+    # pair's 2 * head_dim, times 1 - lam0; lam0 = 0.8 - 0.6 exp(-0.3 i) with
+    # i the layer's index in the published model (layer_indices; () = its
+    # index here).  Its q, k, v and out projections have biases and it
+    # rotates nothing: such a stack has no positions at all.
+    diff_attn: bool = False
+    layer_indices: tuple = ()
+    # the Mamba mixer: state a channel, convolution taps, d_inner / d_model
+    # (the rank of the step's projection is ceil(d_model / 16), ssm_rank)
+    ssm_state: int = 16
+    ssm_conv: int = 4
+    ssm_expand: int = 2
 
     def __post_init__(self):
         if self.layer_types:
-            kinds = {"sliding_attention", "full_attention"}
-            if len(self.layer_types) != self.n_layers or set(self.layer_types) - kinds:
+            if len(self.layer_types) != self.n_layers or set(self.layer_types) - set(LAYER_KINDS):
                 raise ValueError(
                     f"layer_types must name each of the {self.n_layers} layers "
-                    f"as one of {sorted(kinds)}, got {self.layer_types!r}"
+                    f"as one of {sorted(LAYER_KINDS)}, got {self.layer_types!r}"
                 )
+            for reader, source in _CARRIED.items():
+                if reader in self.layer_types and source not in self.layer_types[
+                        :self.layer_types.index(reader)]:
+                    raise ValueError(
+                        f"a {reader} layer reads what a {source} layer before it "
+                        f"keeps; {self.layer_types!r} has none there"
+                    )
+            if "cross_attention" in self.layer_types and not self.diff_attn:
+                raise ValueError("cross_attention layers are built as differential "
+                                 "attention (diff_attn=True)")
+        if self.diff_attn and (self.n_heads % 2 or self.kv_heads % 2
+                               or self.qk_norm or self.attn_gate):
+            raise ValueError("diff_attn pairs heads up: n_heads and n_kv_heads must "
+                             "be even; it has no q/k norms and no gate")
+        if self.layer_indices and len(self.layer_indices) != self.n_layers:
+            raise ValueError(f"layer_indices must give each of the {self.n_layers} "
+                             f"layers' published index, got {self.layer_indices!r}")
+        if self.norm not in ("rms", "layer"):
+            raise ValueError(f"norm must be 'rms' or 'layer', got {self.norm!r}")
+        if self.tie_embeddings and (self.ce_chunk or self.ce_vocab_chunk):
+            raise ValueError("tie_embeddings with a chunked loss edge is not built: "
+                             "the chunked paths read lm_head's own kernel")
         if self.moe_router not in ("softmax", "sigmoid"):
             raise ValueError(
                 f"moe_router must be 'softmax' or 'sigmoid', got {self.moe_router!r}"
@@ -319,14 +380,57 @@ class LMConfig:
         block's parameters for all layers (the pipeline) can run."""
         return not self.layer_types and not (self.num_experts and self.num_dense_layers)
 
+    def layer_kind(self, i: int) -> str:
+        """Layer ``i``'s mixer, one of ``LAYER_KINDS``; without a pattern
+        every layer is attention, windowed iff ``attn_window``."""
+        if self.layer_types:
+            return self.layer_types[i]
+        return "sliding_attention" if self.attn_window else "full_attention"
+
     def layer_window(self, i: int) -> int:
         """Layer ``i``'s attention window (0 = all of the past)."""
-        if self.layer_types and self.layer_types[i] == "full_attention":
+        if self.layer_types and self.layer_types[i] != "sliding_attention":
             return 0
         return self.attn_window
 
     def layer_rope(self, i: int) -> bool:
-        return not self.layer_types or self.layer_types[i] == "sliding_attention"
+        """Whether layer ``i`` rotates q and k: never under ``diff_attn``."""
+        return not self.diff_attn and (
+            not self.layer_types or self.layer_types[i] == "sliding_attention")
+
+    def layer_is_kept(self, i: int) -> bool:
+        """Whether a later layer reads what layer ``i`` computes: the scan
+        output of the last mamba layer before a gmu, the K and V of the
+        last full_attention layer before a cross_attention."""
+        kind = self.layer_kind(i)
+        reader = {src: rd for rd, src in _CARRIED.items()}.get(kind)
+        for later in self.layer_types[i + 1:] if reader else ():
+            if later in (reader, kind):
+                return later == reader
+        return False
+
+    def layer_lam0(self, i: int) -> float:
+        index = self.layer_indices[i] if self.layer_indices else i
+        return 0.8 - 0.6 * math.exp(-0.3 * index)
+
+    @property
+    def carries(self) -> bool:
+        """The stack hands values from layer to layer beside ``x``."""
+        return any(kind in _CARRIED for kind in self.layer_types)
+
+    @property
+    def recurrent(self) -> tuple:
+        """The kinds of layer in the pattern that no decode cache holds."""
+        return tuple(k for k in ("mamba", "gmu", "cross_attention")
+                     if k in self.layer_types)
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_expand * self.d_model
+
+    @property
+    def ssm_rank(self) -> int:
+        return -(-self.d_model // 16)
 
     def layer_is_moe(self, i: int) -> bool:
         return self.num_experts > 0 and i >= self.num_dense_layers
@@ -340,8 +444,9 @@ def remat_block(cfg) -> type:
     construction every builder (TransformerLM, ViT, the pipeline step
     factories) must use so remat semantics cannot drift between paths.
     ``static_argnums=(3,)`` (``Block.__call__(self, x, cache,
-    deterministic)``) keeps ``deterministic`` a Python bool through the
-    checkpoint wrapper.  Valid policy names: ``REMAT_POLICIES`` (the
+    deterministic, carry)``) keeps ``deterministic`` a Python bool through
+    the checkpoint wrapper; ``carry`` is an input like ``x``, so what
+    earlier layers handed down is saved and not recomputed.  Valid policy names: ``REMAT_POLICIES`` (the
     CLIs use it for their argparse choices)."""
     if not cfg.remat:
         return Block
@@ -399,6 +504,30 @@ class RMSNorm(nn.Module):
         return (y * scale).astype(self.dtype)
 
 
+class LayerNorm(nn.Module):
+    """LayerNorm with a learned scale and bias, in float32."""
+
+    dtype: Any = jnp.float32
+    eps: float = 1e-5
+
+    @nn.compact
+    def __call__(self, x):
+        def vec(name, init):
+            return self.param(name, nn.with_logical_partitioning(init, ("norm",)),
+                              (x.shape[-1],), jnp.float32)
+
+        scale = vec("scale", nn.initializers.ones_init())
+        bias = vec("bias", nn.initializers.zeros_init())
+        x32 = x.astype(jnp.float32)
+        mean = jnp.mean(x32, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(x32 - mean), axis=-1, keepdims=True)
+        return ((x32 - mean) * jax.lax.rsqrt(var + self.eps) * scale + bias).astype(self.dtype)
+
+
+def block_norm(cfg, name: str) -> nn.Module:
+    """The configuration's norm over d_model (``LMConfig.norm``)."""
+    cls = LayerNorm if cfg.norm == "layer" else RMSNorm
+    return cls(cfg.dtype, cfg.norm_eps, name=name)
 
 
 class QDense(nn.Module):
@@ -420,6 +549,13 @@ class QDense(nn.Module):
     features: int
     dtype: Any
     kernel_init: Any
+    # a float32 ``bias`` (features,) on the logical axis ``bias_axis``
+    # (the kernel's output axis)
+    use_bias: bool = False
+    bias_axis: Any = None
+    # the product's result type, where it is not the operands' (float32
+    # out of bfloat16 operands: the MXU's own accumulation, kept)
+    out_dtype: Any = None
 
     @nn.compact
     def __call__(self, x):
@@ -429,7 +565,19 @@ class QDense(nn.Module):
             (x.shape[-1], self.features),
             jnp.float32,
         )
-        y = x.astype(self.dtype) @ kernel.astype(self.dtype)
+        if self.out_dtype is None:
+            y = x.astype(self.dtype) @ kernel.astype(self.dtype)
+        else:
+            y = jnp.dot(x.astype(self.dtype), kernel.astype(self.dtype),
+                        preferred_element_type=self.out_dtype)
+        if self.use_bias:
+            bias = self.param(
+                "bias",
+                nn.with_logical_partitioning(
+                    nn.initializers.zeros_init(), (self.bias_axis,)),
+                (self.features,), jnp.float32,
+            )
+            y = y + bias.astype(y.dtype)
         if self.has_variable("params", "scale"):
             # dequant in f32, matching LMHead: casting the per-channel
             # scale to bf16 first adds up to ~0.4% systematic error on
@@ -444,8 +592,15 @@ class QDense(nn.Module):
 
 def refuse_cache_over_layer_types(cfg: LMConfig) -> None:
     """The one refusal of incremental decode over a layer pattern, raised
-    by ``Attention`` for any cache and by the serving factory before it
-    builds a program."""
+    by ``Block`` and ``Attention`` for any cache and by the serving
+    factory before it builds a program."""
+    if cfg.recurrent:
+        raise NotImplementedError(
+            f"this configuration trains only: its {', '.join(cfg.recurrent)} "
+            "layers keep a recurrent state (a convolution's tail, a scan's "
+            "state, a kept layer's output) that no decode cache or KV pool "
+            "holds a lane of; serving it is not built (ROADMAP R4)"
+        )
     if cfg.layer_types:
         raise NotImplementedError(
             "a decode cache over mixed sliding and full layers is not "
@@ -453,6 +608,17 @@ def refuse_cache_over_layer_types(cfg: LMConfig) -> None:
             "full layer all of them, and the caches and the KV pool hold "
             "one kind for every layer (ROADMAP R2)"
         )
+
+
+def _layer_core(cfg: LMConfig, attn_core, window: int) -> Callable:
+    """The attention core ``(q, k, v) -> o`` of a layer with ``window``:
+    the dense one where none was injected; a core built for a pattern
+    takes the layer's window; any other is bound to its own already."""
+    if attn_core is None:
+        return partial(dense_attention, causal=cfg.causal, window=window)
+    if cfg.layer_types:
+        return partial(attn_core, window=window)
+    return attn_core
 
 
 class Attention(nn.Module):
@@ -517,13 +683,7 @@ class Attention(nn.Module):
         # ppermutes and Ulysses all-to-alls Hkv-head K/V) — K/V are
         # never broadcast to H heads, so the manual cores' HBM and
         # collective traffic keep GQA's Hkv/H savings.
-        if self.attn_core is None:
-            core = partial(dense_attention, causal=cfg.causal, window=window)
-        elif cfg.layer_types:
-            # a core built for a pattern takes the layer's window
-            core = partial(self.attn_core, window=window)
-        else:
-            core = self.attn_core
+        core = _layer_core(cfg, self.attn_core, window)
         if cache is None:
             o = core(q, k, v)
         else:
@@ -547,6 +707,180 @@ class Attention(nn.Module):
         )(o)
         out = nn.with_logical_constraint(out, ("batch", "act_seq", "act_embed"))
         return out if cache is None else (out, cache)
+
+
+class DiffAttention(nn.Module):
+    """Differential attention over head pairs, self or cross.
+
+    Heads pair up ``(2j, 2j+1)``: a pair's output is ``(A1 - lam A2)
+    [v1, v2]`` with ``A1 = softmax(q1 k1^T / sqrt(head_dim))``, ``A2`` of
+    the pair's second heads, ``lam = exp(lq1 . lk1) - exp(lq2 . lk2) +
+    lam0`` from four learned vectors, then RMSNorm over the pair's
+    ``2 * head_dim`` and a factor ``1 - lam0``.  With ``kv`` (a kept
+    layer's K and V) the layer projects only its own queries.
+
+    Both score matrices against ``V = [v1, v2]`` are one call of the
+    attention core: the heads are laid out first halves then second
+    halves, so grouped-query indexing puts ``q1`` on ``k1`` and ``q2`` on
+    ``k2``, and V, twice as wide as Q and K, is held once under each half
+    (the cores take a V head of its own width; on a v5e the flash kernels
+    take 7.1 and 11.1 ms a window-512 and a full layer of the benchmark's
+    cell this way, forward and backward, against 14.4 and 22.4 as two
+    calls of one head size over ``[v1, v1]`` and ``[v2, v2]``: PERF.md
+    section 6, PR 31).  Returns ``(out, (k, v))``.
+    """
+
+    cfg: LMConfig
+    attn_core: Optional[Callable] = None
+    window: int = 0
+    lam0: float = 0.0
+    cross: bool = False
+
+    @nn.compact
+    def __call__(self, x, kv=None):
+        cfg = self.cfg
+        b, t, _ = x.shape
+        h, hkv, dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+
+        def proj(name, heads):
+            y = QDense(
+                heads * dh, dtype=cfg.dtype, use_bias=True, bias_axis="heads",
+                kernel_init=nn.with_logical_partitioning(
+                    nn.initializers.lecun_normal(), ("embed", "heads")),
+                name=name,
+            )(x)
+            return y.reshape(b, t, heads, dh)
+
+        q = proj("q", h)
+        k, v = kv if self.cross else (proj("k", hkv), proj("v", hkv))
+
+        def lam_vec(name):
+            return self.param(
+                name, nn.with_logical_partitioning(nn.initializers.normal(0.1), (None,)),
+                (dh,), jnp.float32)
+
+        lam = (jnp.exp(jnp.sum(lam_vec("lambda_q1") * lam_vec("lambda_k1")))
+               - jnp.exp(jnp.sum(lam_vec("lambda_q2") * lam_vec("lambda_k2")))
+               + self.lam0)
+
+        def halves(a):
+            # heads (2j, 2j+1) -> every first head, then every second
+            n = a.shape[2] // 2
+            return a.reshape(b, t, n, 2, dh).transpose(0, 1, 3, 2, 4).reshape(b, t, 2 * n, dh)
+
+        spec = ("batch", "act_seq", "act_heads", None)
+        qh = nn.with_logical_constraint(halves(q), spec)
+        kh = nn.with_logical_constraint(halves(k), spec)
+        # a K/V pair's [v1, v2], under the pair's k1 and again under its k2
+        vv = v.reshape(b, t, hkv // 2, 2 * dh)
+        vv = nn.with_logical_constraint(jnp.concatenate([vv, vv], axis=2), spec)
+        # (B, T, H, 2 head_dim): every pair's A1 V, then every pair's A2 V
+        o = _layer_core(cfg, self.attn_core, self.window)(qh, kh, vv).astype(jnp.float32)
+        o = o[:, :, : h // 2] - lam * o[:, :, h // 2:]
+        o = RMSNorm(jnp.float32, cfg.norm_eps, name="subln")(o) * (1.0 - self.lam0)
+        out = QDense(
+            # the bias is d_model-sized: whole on every device, as a norm's
+            cfg.d_model, dtype=cfg.dtype, use_bias=True, bias_axis="norm",
+            kernel_init=nn.with_logical_partitioning(
+                nn.initializers.lecun_normal(), ("heads", "embed")),
+            name="out",
+        )(o.astype(cfg.dtype).reshape(b, t, h * dh))
+        out = nn.with_logical_constraint(out, ("batch", "act_seq", "act_embed"))
+        return out, (k, v)
+
+
+class CausalConv(nn.Module):
+    """Causal depthwise convolution over time with a bias, in float32:
+    ``y_t = b + sum_j w_j x_{t - (taps - 1) + j}``."""
+
+    taps: int
+
+    @nn.compact
+    def __call__(self, x):
+        t, c = x.shape[1], x.shape[2]
+        w = self.param(
+            "kernel",
+            nn.with_logical_partitioning(
+                nn.initializers.variance_scaling(1.0, "fan_in", "normal", in_axis=0,
+                                                 out_axis=1), (None, "mlp")),
+            (self.taps, c), jnp.float32)
+        bias = self.param(
+            "bias", nn.with_logical_partitioning(nn.initializers.zeros_init(), ("mlp",)),
+            (c,), jnp.float32)
+        padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (self.taps - 1, 0), (0, 0)))
+        return bias + sum(padded[:, j:j + t] * w[j] for j in range(self.taps))
+
+
+def _ssm_dense(cfg, features, axes, name, **kw):
+    return QDense(
+        features, dtype=cfg.dtype, name=name,
+        kernel_init=nn.with_logical_partitioning(nn.initializers.lecun_normal(), axes),
+        **kw,
+    )
+
+
+class Mamba(nn.Module):
+    """The Mamba-1 mixer: ``[xs, z]`` from the input, a causal depthwise
+    convolution and silu on ``xs``, the step ``dt``, ``B`` and ``C`` from
+    the result, the selective scan (``ops/selective_scan.py``: float32
+    state and decay), the gate ``silu(z)``, the out projection.  The
+    projections' operands are in the compute type; what feeds the scan
+    stays float32.  Returns ``(out, s)`` with ``s`` the scan's output
+    before the gate, what a later ``Gmu`` reads."""
+
+    cfg: LMConfig
+
+    @nn.compact
+    def __call__(self, x):
+        from ddl_tpu.ops.selective_scan import selective_scan
+
+        cfg = self.cfg
+        d_in, n, r = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_rank
+        wide = ("batch", "act_seq", "act_mlp")
+        xs = nn.with_logical_constraint(
+            _ssm_dense(cfg, d_in, ("embed", "mlp"), "in_x")(x), wide)
+        z = nn.with_logical_constraint(
+            _ssm_dense(cfg, d_in, ("embed", "mlp"), "in_z")(x), wide)
+        xc = jax.nn.silu(CausalConv(cfg.ssm_conv, name="conv")(xs))
+        proj = _ssm_dense(cfg, r + 2 * n, ("mlp", None), "x_proj",
+                          out_dtype=jnp.float32)(xc)
+        dt = jax.nn.softplus(_ssm_dense(
+            cfg, d_in, (None, "mlp"), "dt_proj", out_dtype=jnp.float32,
+            use_bias=True, bias_axis="mlp")(proj[..., :r]))
+        a_log = self.param(
+            "A_log",
+            nn.with_logical_partitioning(
+                lambda key, shape, dtype: jnp.broadcast_to(
+                    jnp.log(jnp.arange(1, shape[1] + 1, dtype=dtype)), shape),
+                ("mlp", None)),
+            (d_in, n), jnp.float32)
+        skip = self.param(
+            "D", nn.with_logical_partitioning(nn.initializers.ones_init(), ("mlp",)),
+            (d_in,), jnp.float32)
+        with jax.named_scope("scan"):
+            s, absmax = selective_scan(xc, dt, -jnp.exp(a_log), proj[..., r:r + n],
+                             proj[..., r + n:], skip)
+        self.sow("intermediates", "ssm_state_absmax", absmax)
+        gated = (s * jax.nn.silu(z.astype(jnp.float32))).astype(cfg.dtype)
+        out = _ssm_dense(cfg, cfg.d_model, ("mlp", "embed"), "out_proj")(gated)
+        out = nn.with_logical_constraint(out, ("batch", "act_seq", "act_embed"))
+        return out, s.astype(cfg.dtype)
+
+
+class Gmu(nn.Module):
+    """Gated Memory Unit: ``W_out(m * silu(W_in x))`` with ``m`` the scan
+    output an earlier Mamba layer kept, at the same positions."""
+
+    cfg: LMConfig
+
+    @nn.compact
+    def __call__(self, x, m):
+        cfg = self.cfg
+        g = _ssm_dense(cfg, cfg.ssm_inner, ("embed", "mlp"), "in_proj")(x)
+        g = nn.with_logical_constraint(g, ("batch", "act_seq", "act_mlp"))
+        gated = (m.astype(jnp.float32) * jax.nn.silu(g.astype(jnp.float32))).astype(cfg.dtype)
+        out = _ssm_dense(cfg, cfg.d_model, ("mlp", "embed"), "out_proj")(gated)
+        return nn.with_logical_constraint(out, ("batch", "act_seq", "act_embed"))
 
 
 class Mlp(nn.Module):
@@ -1243,36 +1577,63 @@ class MoeMlp(nn.Module):
 
 
 class Block(nn.Module):
-    """Pre-norm decoder block.  With ``cache`` (incremental decode) the
-    return gains the updated cache: ``(x, aux, new_cache)``."""
+    """Pre-norm decoder block: ``x + mixer(norm(x))``, then ``x +
+    mlp(norm(x))``.  The mixer is the layer's kind (``LMConfig.layer_kind``):
+    attention (``Attention``, or ``DiffAttention`` under ``diff_attn``), a
+    ``Mamba`` layer, a ``Gmu`` or cross-attention.  With ``cache``
+    (incremental decode) the return gains the updated cache: ``(x, aux,
+    new_cache)``.  With ``carry`` (a stack in which layers read what
+    earlier ones kept, ``LMConfig.carries``: a dict, empty before the
+    first kept layer) it gains the carry, with this layer's scan output
+    under ``"ssm"`` or its K and V under ``"kv"`` where a later layer
+    reads them: ``(x, aux, carry)``."""
 
     cfg: LMConfig
     attn_core: Optional[Callable] = None
-    # which layer of the model this is: its kind (window or full, rotary
-    # or none, dense or expert MLP) is the configuration's, LMConfig.layer_*
+    # which layer of the model this is: its kind (the mixer, window or
+    # full, rotary or none, dense or expert MLP) is the configuration's,
+    # LMConfig.layer_*
     layer: int = 0
 
     @nn.compact
-    def __call__(self, x, cache=None, deterministic=True):
+    def __call__(self, x, cache=None, deterministic=True, carry=None):
         cfg = self.cfg
         drop = nn.Dropout(cfg.dropout_rate, deterministic=deterministic)
+        kind, kept = cfg.layer_kind(self.layer), cfg.layer_is_kept(self.layer)
+        if cache is not None:
+            refuse_cache_over_layer_types(cfg)
 
         def norm(name):
-            return RMSNorm(cfg.dtype, cfg.norm_eps, name=name)
+            return block_norm(cfg, name)
 
         def post(name, y):
             # the residual branch's own norm, where a block has four
             return norm(name)(y) if cfg.sandwich_norm else y
 
-        attn = Attention(
-            cfg, self.attn_core, cfg.layer_window(self.layer),
-            cfg.layer_rope(self.layer), name="attn",
-        )
         h = norm("norm_attn")(x)
-        if cache is None:
-            a = attn(h)
+        if kind == "mamba":
+            a, s = Mamba(cfg, name="ssm")(h)
+            if kept:
+                carry = dict(carry, ssm=s)
+        elif kind == "gmu":
+            a = Gmu(cfg, name="gmu")(h, carry["ssm"])
+        elif cfg.diff_attn:
+            cross = kind == "cross_attention"
+            a, kv = DiffAttention(
+                cfg, self.attn_core, cfg.layer_window(self.layer),
+                cfg.layer_lam0(self.layer), cross, name="xattn" if cross else "attn",
+            )(h, carry["kv"] if cross else None)
+            if kept:
+                carry = dict(carry, kv=kv)
         else:
-            a, cache = attn(h, cache)
+            attn = Attention(
+                cfg, self.attn_core, cfg.layer_window(self.layer),
+                cfg.layer_rope(self.layer), name="attn",
+            )
+            if cache is None:
+                a = attn(h)
+            else:
+                a, cache = attn(h, cache)
         x = x + drop(post("norm_post_attn", a))
         h = norm("norm_mlp")(x)
         if cfg.layer_is_moe(self.layer):
@@ -1280,6 +1641,8 @@ class Block(nn.Module):
         else:
             y, aux = Mlp(cfg, name="mlp")(h), jnp.zeros((), jnp.float32)
         x = x + drop(post("norm_post_mlp", y))
+        if carry is not None:
+            return x, aux, carry
         return (x, aux) if cache is None else (x, aux, cache)
 
 
@@ -1298,10 +1661,9 @@ class TokenEmbed(nn.Module):
 
     cfg: LMConfig
 
-    @nn.compact
-    def __call__(self, tokens):
+    def setup(self):
         cfg = self.cfg
-        table = self.param(
+        self.embedding = self.param(
             "embedding",
             nn.with_logical_partitioning(
                 nn.initializers.normal(0.02), ("vocab", "embed")
@@ -1309,11 +1671,20 @@ class TokenEmbed(nn.Module):
             (cfg.vocab_size, cfg.d_model),
             jnp.float32,
         )
-        table = nn.with_logical_constraint(table, (None, None))
+
+    def __call__(self, tokens):
+        cfg = self.cfg
+        table = nn.with_logical_constraint(self.embedding, (None, None))
         x = jnp.take(table, tokens, axis=0)
         if cfg.embed_scale:
             x = x * jnp.sqrt(jnp.float32(cfg.d_model))
         return x.astype(cfg.dtype)
+
+    def attend(self, x):
+        """The tied head (``LMConfig.tie_embeddings``): float32 logits of
+        ``x`` against the table's own rows, as ``LMHead`` against its
+        kernel; the head's gradient adds into the embedding's."""
+        return jnp.einsum("...d,vd->...v", x, self.embedding)
 
 
 def make_embed(cfg: LMConfig) -> TokenEmbed:
@@ -1371,11 +1742,16 @@ def make_lm_head(cfg: LMConfig) -> "LMHead":
     return LMHead(cfg, name="lm_head")
 
 
-def apply_final_norm_and_head(cfg: LMConfig, x):
-    """Final RMSNorm ('norm_f') + lm_head -> constrained f32 logits.
-    Call inside an ``nn.compact`` method."""
-    x = RMSNorm(cfg.dtype, cfg.norm_eps, name="norm_f")(x)
-    logits = make_lm_head(cfg)(x.astype(jnp.float32))
+def apply_final_norm_and_head(cfg: LMConfig, x, embed=None):
+    """Final norm ('norm_f') + lm_head -> constrained f32 logits; under
+    ``tie_embeddings`` the head is ``embed``'s own table.  Call inside an
+    ``nn.compact`` method."""
+    x = block_norm(cfg, "norm_f")(x).astype(jnp.float32)
+    if cfg.tie_embeddings:
+        with jax.named_scope("head"):
+            logits = embed.attend(x)
+    else:
+        logits = make_lm_head(cfg)(x)
     return nn.with_logical_constraint(logits, ("batch", "act_seq", "act_vocab"))
 
 
@@ -1397,18 +1773,23 @@ class TransformerLM(nn.Module):
     def __call__(self, tokens, deterministic: bool = True,
                  return_hidden: bool = False):
         cfg = self.cfg
-        x = make_embed(cfg)(tokens)
+        embed = make_embed(cfg)
+        x = embed(tokens)
         x = nn.with_logical_constraint(x, ("batch", "act_seq", "act_embed"))
         block = remat_block(cfg)
         aux_total = jnp.zeros((), jnp.float32)
+        # what kept layers hand down the stack, beside x (LMConfig.carries)
+        carry = {} if cfg.carries else None
         for i in range(cfg.n_layers):
-            x, aux = block(cfg, self.attn_core, i, name=f"block{i}")(
-                x, None, deterministic
-            )
+            layer = block(cfg, self.attn_core, i, name=f"block{i}")
+            if carry is None:
+                x, aux = layer(x, None, deterministic)
+            else:
+                x, aux, carry = layer(x, None, deterministic, carry)
             aux_total = aux_total + aux
         if return_hidden:
-            return RMSNorm(cfg.dtype, cfg.norm_eps, name="norm_f")(x), aux_total
-        return apply_final_norm_and_head(cfg, x), aux_total
+            return block_norm(cfg, "norm_f")(x), aux_total
+        return apply_final_norm_and_head(cfg, x, embed), aux_total
 
 
 def count_lm_params(params) -> int:

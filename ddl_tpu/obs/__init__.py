@@ -60,6 +60,7 @@ from ddl_tpu.obs.anomaly import (
     AnomalyMonitor,
     HBMGrowthDetector,
     LossSpikeDetector,
+    StateGrowthDetector,
     ThroughputRegressionDetector,
 )
 from ddl_tpu.obs.events import EventWriter, events_path, read_events
@@ -85,6 +86,7 @@ __all__ = [
     "PHASES",
     "QuantileAccumulator",
     "ServingStats",
+    "StateGrowthDetector",
     "StepTrace",
     "StreamFold",
     "TDigest",

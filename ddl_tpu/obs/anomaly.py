@@ -1,6 +1,6 @@
 """Rolling anomaly detectors over per-period training signals.
 
-Three detectors, all trailing-window so a long run's drift doesn't
+Four detectors, all trailing-window so a long run's drift doesn't
 stale the baseline:
 
 * ``LossSpikeDetector`` — loss above ``mean + sigma * std`` of the
@@ -13,6 +13,10 @@ stale the baseline:
   window and up by more than ``min_growth`` over it: the signature of a
   leak (a cache that never evicts, stale buffer references), not of
   steady-state training, whose footprint is flat after warmup.
+* ``StateGrowthDetector`` — a Mamba stack's largest ``|h|`` (the step
+  metric ``ssm_state_absmax``) above the trailing window by the spike
+  rule, floored at twice its mean: a decay nearing 1 under a growing
+  step, which the state shows before the loss does.
 
 ``AnomalyMonitor`` bundles them: the trainer feeds each period's
 metrics, anomalies are emitted as events the moment they fire and
@@ -29,6 +33,7 @@ __all__ = [
     "AnomalyMonitor",
     "HBMGrowthDetector",
     "LossSpikeDetector",
+    "StateGrowthDetector",
     "ThroughputRegressionDetector",
 ]
 
@@ -71,6 +76,13 @@ class LossSpikeDetector:
                 }
         self.values.append(loss)
         return out
+
+
+class StateGrowthDetector(LossSpikeDetector):
+    kind = "ssm_state_growth"
+
+    def __init__(self, rel_floor: float = 0.25, **kwargs) -> None:
+        super().__init__(rel_floor=rel_floor, **kwargs)
 
 
 class ThroughputRegressionDetector:
@@ -155,6 +167,9 @@ class AnomalyMonitor:
             **detector_kwargs.get("throughput_regression", {})
         )
         self.hbm = HBMGrowthDetector(**detector_kwargs.get("hbm_growth", {}))
+        self.state = StateGrowthDetector(
+            **detector_kwargs.get("ssm_state_growth", {})
+        )
         self.anomalies: list[dict] = []
 
     def observe_period(
@@ -164,6 +179,7 @@ class AnomalyMonitor:
         steps_per_sec: float | None = None,
         hbm_bytes: float | None = None,
         compiles: int = 0,
+        ssm_state: float | None = None,
     ) -> list[dict]:
         """``compiles`` is the period's XLA backend-compile count (from
         ``StepTrace``): a period that recompiled has a known, explained
@@ -183,6 +199,10 @@ class AnomalyMonitor:
         a = self.hbm.observe(hbm_bytes)
         if a:
             found.append(a)
+        if ssm_state is not None:
+            a = self.state.observe(ssm_state)
+            if a:
+                found.append(a)
         for a in found:
             a["idx"] = idx
             self.anomalies.append(a)

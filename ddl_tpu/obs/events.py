@@ -148,6 +148,7 @@ EVENT_KINDS = (
 # the rolling detectors in obs/anomaly.py).
 ANOMALY_TYPES = (
     "loss_spike", "throughput_regression", "hbm_growth", "nonfinite_loss",
+    "ssm_state_growth",
 )
 
 _warned_kinds: set[str] = set()

@@ -634,6 +634,15 @@ def render_hbm(account: dict, job_id: str = "") -> str:
                 counts = ", ".join(f"{t} {n}" for t, n in p["scope_counts"].items())
                 lines.append(f"    scope ({p.get('scope_file')}): {counts}")
             for kernel, n in (p.get("kernel_tiles") or {}).items():
+                if "steps" in n:
+                    # a selective-scan kernel: its grid and the time steps
+                    # a grid step walks (the chunk's length)
+                    lines.append(
+                        f"    tiles {kernel}: {n.get('calls')} call(s), "
+                        f"{n.get('total')} grid steps of "
+                        f"{n['steps'] // max(n.get('total', 0), 1)} time steps"
+                    )
+                    continue
                 if "computed" not in n:
                     # a grouped product or a row kernel of the dropless
                     # shuffle: its grid's worst case and the fewest steps

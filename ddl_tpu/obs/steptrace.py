@@ -141,9 +141,11 @@ class _CompileCounter:
         return cls._shared
 
 
-# step metrics of a dropless expert layer that the period event copies
-_DROPLESS_COUNTERS = (
+# step metrics of a dropless expert layer, and of a stack with Mamba
+# layers (``ssm_state_absmax``), that the period event copies
+_SOWN_COUNTERS = (
     "moe_local_rows", "moe_load_max_over_mean", "moe_rows_dropped", "moe_buffer_fill",
+    "ssm_state_absmax",
 )
 
 
@@ -418,10 +420,10 @@ class StepTrace:
             compile_s=compile_s,
             hbm_peak_bytes=mem["peak_bytes_in_use"] if mem else None,
             **({"rates": dict(rates)} if rates else {}),
-            # a dropless expert layer's counters
-            # (lm_steps.moe_router_metrics), as the period's last step
-            # read them; absent for every other program
-            **{k: float(metrics[k]) for k in _DROPLESS_COUNTERS
+            # a dropless expert layer's counters and a Mamba stack's
+            # largest state (lm_steps.sown_metrics), as the period's last
+            # step read them; absent for every other program
+            **{k: float(metrics[k]) for k in _SOWN_COUNTERS
                if metrics and metrics.get(k) is not None},
         )
         self.anomaly.observe_period(
@@ -430,6 +432,7 @@ class StepTrace:
             steps_per_sec=steps_per_sec,
             hbm_bytes=mem["bytes_in_use"] if mem else None,
             compiles=compiles,
+            ssm_state=metrics.get("ssm_state_absmax") if metrics else None,
         )
         return phases
 

@@ -18,7 +18,7 @@ __all__ = ["dense_attention"]
 
 def dense_attention(q, k, v, causal: bool = False, mask=None, window: int = 0):
     """Full softmax attention. q: (B, Tq, H, D), k/v: (B, Tk, Hkv, D) ->
-    (B, Tq, H, D).  ``mask`` is an explicit (Tq, Tk) bool mask (True =
+    (B, Tq, H, D) (V's head may have its own width, which the output takes).  ``mask`` is an explicit (Tq, Tk) bool mask (True =
     attend) for cross-length cases like KV-cache decode — or (B, Tq, Tk)
     when every batch row has its own visibility, e.g. the serving
     engine's continuous decode batch where each lane sits at a different
@@ -69,4 +69,4 @@ def dense_attention(q, k, v, causal: bool = False, mask=None, window: int = 0):
         )
         scores = jnp.where(m, scores, -1e30)
     probs = jax.nn.softmax(scores.astype(jnp.float32), -1).astype(q.dtype)
-    return jnp.einsum("bhgqk,bkhd->bqhgd", probs, v).reshape(b, tq, h, d)
+    return jnp.einsum("bhgqk,bkhd->bqhgd", probs, v).reshape(b, tq, h, v.shape[-1])

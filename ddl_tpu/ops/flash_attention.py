@@ -501,6 +501,7 @@ def _flash_fwd_impl(
     q_heads, kv_heads,
 ):
     bh, t, d = q.shape
+    dv = v.shape[-1]  # V's head may be wider than Q's and K's
     scale = 1.0 / (d ** 0.5)
     kv_idx = lambda b, i, j: (_kv_row(b, q_heads, kv_heads), j, 0)
     walk, metadata = _walk(
@@ -510,7 +511,7 @@ def _flash_fwd_impl(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           window=window, kv_offset=kv_offset, **walk),
         out_shape=(
-            jax.ShapeDtypeStruct((bh, t, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, t, dv), q.dtype),
             # row stats ride in a (bh, 1, t) layout: the (1, 1, block_q)
             # block then satisfies Mosaic's tiling rule (second-to-last
             # block dim == array dim; last dim a 128-multiple or == t)
@@ -520,14 +521,14 @@ def _flash_fwd_impl(
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, d), kv_idx),
-            pl.BlockSpec((1, block_k, d), kv_idx),
+            pl.BlockSpec((1, block_k, dv), kv_idx),
         ],
         out_specs=(
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
         ),
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
@@ -572,11 +573,13 @@ def _flash_bwd_impl(q, k, v, out, lse, do, dlse, causal, window,
     the ring schedule, which hands this kernel ``T_local``.
     """
     bh, t, d = q.shape
+    dv = v.shape[-1]
     bkv = k.shape[0]
     g = q_heads // kv_heads
     scale = 1.0 / (d ** 0.5)
-    lanes = -(-d // 128) * 128  # VMEM rows are whole 128-lane tiles
-    resident = 2 * t * lanes * (4 + 2 * k.dtype.itemsize)
+    # VMEM rows are whole 128-lane tiles
+    lanes = -(-d // 128) * 128 + -(-dv // 128) * 128
+    resident = t * lanes * (4 + 2 * k.dtype.itemsize)
     if resident > _BWD_RESIDENT_LIMIT:
         raise ValueError(
             f"flash backward keeps a K/V head's dK and dV resident: T={t}, "
@@ -600,8 +603,13 @@ def _flash_bwd_impl(q, k, v, out, lse, do, dlse, causal, window,
     row_spec = pl.BlockSpec(
         (1, 1, block_q), lambda b, iz, j: (q_row(b, iz), 0, iz % nq)
     )
-    kv_spec = pl.BlockSpec((1, block_k, d), lambda b, iz, j: (b, j, 0))
-    head_spec = pl.BlockSpec((1, t, d), lambda b, iz, j: (b, 0, 0))
+    do_spec = pl.BlockSpec(
+        (1, block_q, dv), lambda b, iz, j: (q_row(b, iz), iz % nq, 0)
+    )
+    kv_spec = lambda width: pl.BlockSpec(  # noqa: E731
+        (1, block_k, width), lambda b, iz, j: (b, j, 0))
+    head_spec = lambda width: pl.BlockSpec(  # noqa: E731
+        (1, t, width), lambda b, iz, j: (b, 0, 0))
     # the kernel is named for its grid, the K/V head's; the plan counts
     # (batch, query head) rows like the forward's
     walk, metadata = _walk(
@@ -615,15 +623,15 @@ def _flash_bwd_impl(q, k, v, out, lse, do, dlse, causal, window,
         out_shape=(
             jax.ShapeDtypeStruct((bh, t, d), q.dtype),
             jax.ShapeDtypeStruct((bkv, t, d), k.dtype),
-            jax.ShapeDtypeStruct((bkv, t, d), v.dtype),
+            jax.ShapeDtypeStruct((bkv, t, dv), v.dtype),
         ),
         grid=(bkv, g * nq, nk),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=(q_spec, head_spec, head_spec),
+        in_specs=[q_spec, kv_spec(d), kv_spec(dv), do_spec, row_spec, row_spec],
+        out_specs=(q_spec, head_spec(d), head_spec(dv)),
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
             pltpu.VMEM((nk, block_k, d), jnp.float32),
-            pltpu.VMEM((nk, block_k, d), jnp.float32),
+            pltpu.VMEM((nk, block_k, dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_BWD_VMEM_LIMIT
@@ -706,7 +714,9 @@ def flash_attention(
     interpret: bool | None = None,
     kv_offset: int = 0,
 ):
-    """Flash attention. q: (B, T, H, D), k/v: (B, T, Hkv, D) -> (B, T, H, D).
+    """Flash attention. q: (B, T, H, D), k: (B, T, Hkv, D), v: (B, T, Hkv,
+    Dv) -> (B, T, H, Dv); Dv is D unless V's head is wider than Q's and K's
+    (differential attention's ``[v1, v2]``).
 
     Grouped-query attention is native: ``Hkv < H`` (``H % Hkv == 0``) makes
     each K/V head serve ``H/Hkv`` query heads via BlockSpec indexing — the
@@ -749,7 +759,7 @@ def flash_attention(
         _fold_heads(q), _fold_heads(k), _fold_heads(v), causal, window,
         kv_offset, bq, bk, interpret, h, hkv,
     )
-    return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+    return out.reshape(b, h, t, v.shape[-1]).transpose(0, 2, 1, 3)
 
 
 def flash_attention_with_lse(
@@ -786,6 +796,6 @@ def flash_attention_with_lse(
         kv_offset, bq, bk, interpret, h, hkv,
     )
     return (
-        out.reshape(b, h, t, d).transpose(0, 2, 1, 3),
+        out.reshape(b, h, t, v.shape[-1]).transpose(0, 2, 1, 3),
         lse.reshape(b, h, t),
     )

@@ -306,7 +306,30 @@ def _transformer_block_rules(E) -> tuple[tuple[str, P], ...]:
         (r"moe/bias$", P()),
         (r"moe/(wi|wg)$", P("expert", E, "model")),
         (r"moe/wo$", P("expert", "model", E)),
-        (r"norm\w*/scale$", P()),
+        (r"norm\w*/(scale|bias)$", P()),
+        # a hybrid stack's mixers (models/transformer.py: DiffAttention,
+        # Mamba, Gmu).  Differential and cross attention split by heads as
+        # attention does (a cross layer's ``xattn/q/kernel`` and
+        # ``xattn/out/kernel`` are found by the attention rules above: the
+        # patterns search), biases with their kernels' output axis; the
+        # lambda vectors and the pair norm's scale are head_dim-sized,
+        # replicated.  The Mamba layer and the GMU split d_inner over
+        # 'model' as an MLP splits its hidden width: the scan's channels
+        # are independent, the in projections column-parallel, x_proj and
+        # the out projections row-parallel.
+        (r"x?attn/(q|k|v)/bias$", P("model")),
+        (r"x?attn/out/bias$", P()),
+        (r"x?attn/lambda_(q|k)[12]$", P()),
+        (r"x?attn/subln/scale$", P()),
+        (r"ssm/in_(x|z)/kernel$", P(E, "model")),
+        (r"ssm/conv/kernel$", P(None, "model")),
+        (r"ssm/(conv|dt_proj)/bias$", P("model")),
+        (r"ssm/x_proj/kernel$", P("model", None)),
+        (r"ssm/dt_proj/kernel$", P(None, "model")),
+        (r"ssm/A_log$", P("model", None)),
+        (r"ssm/D$", P("model")),
+        (r"(ssm|gmu)/out_proj/kernel$", P("model", E)),
+        (r"gmu/in_proj/kernel$", P(E, "model")),
     )
 
 

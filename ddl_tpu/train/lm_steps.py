@@ -166,6 +166,10 @@ STEP_PARTS = {
     **{f"moe/{part}": f"moe/{part}"
        for part in ("route", "dispatch", "experts", "shared", "combine")},
     "attn": "attn", "mlp": "mlp", "lm_head": "head", "head": "head",
+    # a hybrid stack's mixers: the Mamba layer (projections, convolution,
+    # gate) and inside it the scan kernels' calls, the Gated Memory Unit,
+    # the cross-attention layers (``attn`` stays the self layers')
+    "ssm": "ssm", "ssm/scan": "ssm/scan", "gmu": "gmu", "xattn": "xattn",
 }
 
 
@@ -211,6 +215,20 @@ def moe_router_metrics(intermediates) -> dict:
         "moe_load_max": load.max(),
         "moe_load_min": load.min(),
     }
+
+
+def sown_metrics(intermediates) -> dict:
+    """The step metrics out of what the layers sow: the router's
+    (``moe_router_metrics``) and, for a stack with Mamba layers,
+    ``ssm_state_absmax``, the largest ``|h|`` at a chunk's start of any
+    layer's scan (a state that grows without bound shows here steps
+    before the loss does)."""
+    out = moe_router_metrics(intermediates)
+    peaks = [leaf for path, leaf in jax.tree_util.tree_leaves_with_path(intermediates)
+             if "ssm_state_absmax" in jax.tree_util.keystr(path)]
+    if peaks:
+        out["ssm_state_absmax"] = jnp.stack(peaks).max()
+    return out
 
 
 def _token_ce(logits, targets):
@@ -647,8 +665,10 @@ def make_lm_step_fns(
     def loss_fn(params, inputs, targets, step=None):
         kw = dropout_kwargs(rng, step, cfg.dropout_rate)
         # MoE runs also collect the router stats MoeMlp sows (drop
-        # fraction, expert load) into the step metrics
-        mutable = ["intermediates"] if cfg.num_experts else False
+        # fraction, expert load) into the step metrics, and a stack with
+        # Mamba layers its scans' largest state
+        sows = bool(cfg.num_experts) or "mamba" in cfg.layer_types
+        mutable = ["intermediates"] if sows else False
         router = {}
         with nn.logical_axis_rules(rules):
             if cfg.ce_chunk or cfg.ce_vocab_chunk:
@@ -666,9 +686,9 @@ def make_lm_step_fns(
                     return_hidden=True,
                     mutable=mutable,
                 )
-                if cfg.num_experts:
+                if sows:
                     (hidden, aux), col = out
-                    router = moe_router_metrics(col["intermediates"])
+                    router = sown_metrics(col["intermediates"])
                 else:
                     hidden, aux = out
                 loss, (none, metrics) = chunked_ce_loss(
@@ -683,9 +703,9 @@ def make_lm_step_fns(
                 rngs=kw["rngs"],
                 mutable=mutable,
             )
-            if cfg.num_experts:
+            if sows:
                 (logits, aux), col = out
-                router = moe_router_metrics(col["intermediates"])
+                router = sown_metrics(col["intermediates"])
             else:
                 logits, aux = out
         with jax.named_scope("head"):

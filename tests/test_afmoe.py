@@ -656,7 +656,9 @@ def test_parts_table_of_a_compiled_step_names_every_tag(tmp_path):
     hbm.plan_program(writer, "train_step", fns.train, (state, tok, tok), parts=STEP_PARTS)
     parts, direction = hbm.scope_table("train_step.parts"), hbm.scope_table("train_step")
     assert set(parts) == set(direction)  # every ENTRY instruction, both ways
-    assert set(parts.values()) == {*STEP_PARTS.values(), "other"}
+    # every tag but the hybrid stack's mixers' (tests/test_sambay.py has those)
+    hybrid = {"ssm", "ssm/scan", "gmu", "xattn"}
+    assert set(parts.values()) == {*STEP_PARTS.values(), "other"} - hybrid
     assert set(direction.values()) <= {"fwd", "bwd", "update"}  # interpreted kernels: no custom call
     # the parts table does not displace the direction table by module
     assert list(hbm.scope_tables().values()) == [direction]

@@ -96,6 +96,30 @@ def test_flash_matches_dense_grads(qkv, causal, window, blocks):
         )
 
 
+@pytest.mark.parametrize("window", [0, 16], ids=["causal", "window16"])
+def test_flash_with_a_wider_v_head_matches_dense(window):
+    """V's head twice as wide as Q's and K's (differential attention's
+    ``[v1, v2]``), grouped: the output and dV take V's width, forward and
+    every gradient agree with the dense core."""
+    ks = jax.random.split(jax.random.key(4), 4)
+    q = jax.random.normal(ks[0], (2, 64, 4, 8))
+    k = jax.random.normal(ks[1], (2, 64, 2, 8))
+    v = jax.random.normal(ks[2], (2, 64, 2, 16))
+    w = jax.random.normal(ks[3], (2, 64, 4, 16))
+    flash = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, causal=True, window=window, block_q=32, block_k=32)
+    dense = lambda q, k, v: dense_attention(q, k, v, causal=True, window=window)  # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        out = flash(q, k, v)
+        assert out.shape == (2, 64, 4, 16)
+        np.testing.assert_allclose(out, dense(q, k, v), rtol=1e-5, atol=1e-5)
+        got = jax.grad(lambda *a: jnp.sum(flash(*a) * w), argnums=(0, 1, 2))(q, k, v)
+        want = jax.grad(lambda *a: jnp.sum(dense(*a) * w), argnums=(0, 1, 2))(q, k, v)
+    for g, r in zip(got, want):
+        assert g.shape == r.shape
+        np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-5)
+
+
 def test_flash_mismatched_block_sizes_clamp():
     rng = np.random.default_rng(2)
     q = jnp.asarray(rng.normal(size=(1, 48, 2, 8)), jnp.float32)  # T=48

@@ -160,6 +160,47 @@ def test_afmoe_table_matches_logical_resolution(fsdp):
 
 
 @pytest.mark.parametrize("fsdp", [False, True])
+def test_sambay_table_matches_logical_resolution(fsdp):
+    """The hybrid stack's leaves (the Mamba layer's projections,
+    convolution, step bias, A and D; the GMU's two matrices; differential
+    and cross attention's biases, lambda vectors and pair norm; LayerNorm's
+    bias; a tied embedding with no lm_head) each have a rule, and it is
+    the one their logical annotations resolve to: d_inner splits over
+    'model' as an MLP's hidden width does."""
+    import flax.linen as nn
+
+    from ddl_tpu.models.transformer import LMConfig, TransformerLM
+
+    cfg = LMConfig(
+        vocab_size=512, d_model=64, n_layers=5, n_heads=4, n_kv_heads=2,
+        head_dim=16, d_ff=192, compute_dtype="float32", fsdp=fsdp,
+        layer_types=("sliding_attention", "mamba", "full_attention", "gmu",
+                     "cross_attention"),
+        attn_window=4, norm="layer", tie_embeddings=True,
+        diff_attn=True, mlp_gated=True, ssm_state=4,
+    )
+    abs_params = jax.eval_shape(
+        lambda r: TransformerLM(cfg, None).init(
+            r, jnp.zeros((4, 8), jnp.int32)
+        )["params"],
+        jax.random.key(0),
+    )
+    names = {R.tree_path_str(p) for p, _ in
+             jtu.tree_leaves_with_path(nn.meta.unbox(abs_params))}
+    for leaf in ("block0/attn/q/bias", "block0/attn/lambda_q1", "block0/attn/subln/scale",
+                 "block0/norm_attn/bias", "block1/ssm/in_x/kernel", "block1/ssm/conv/kernel",
+                 "block1/ssm/conv/bias", "block1/ssm/x_proj/kernel",
+                 "block1/ssm/dt_proj/bias", "block1/ssm/A_log", "block1/ssm/D",
+                 "block1/ssm/out_proj/kernel", "block3/gmu/in_proj/kernel",
+                 "block3/gmu/out_proj/kernel", "block4/xattn/q/kernel",
+                 "block4/xattn/out/bias", "norm_f/bias"):
+        assert leaf in names
+    assert "lm_head" not in {n.split("/")[0] for n in names}
+    assert not any(n.startswith("block4/xattn/k") for n in names)
+    _assert_table_matches_logical(abs_params, R.lm_rules(fsdp), fsdp, _lm_mesh())
+
+
+@pytest.mark.parametrize("fsdp", [False, True])
 def test_vit_table_matches_logical_resolution(fsdp):
     from ddl_tpu.models.vit import ViT, ViTConfig
 

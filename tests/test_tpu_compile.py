@@ -115,6 +115,50 @@ def test_flash_attention_fwd_and_grad(
     assert plan["flash_fwd"]["computed"] < plan["flash_fwd"]["total"]
 
 
+@pytest.mark.parametrize("window", [512, 0], ids=["phi4flash-sliding", "phi4flash-full-and-cross"])
+def test_flash_attention_with_a_wider_v_head(compile_for_chip, window):
+    """The SambaY cell's differential attention: a layer's 40 query heads
+    of 64 on 20 K heads of 64, V = [v1, v2] 128 wide under each K head;
+    the output and dV take V's width."""
+    from ddl_tpu.obs.scope import kernel_tiles
+    from ddl_tpu.ops.flash_attention import flash_attention
+
+    def attend(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window, interpret=False)
+
+    q, k, v = _s((2, 4096, 40, 64), BF16), _s((2, 4096, 20, 64), BF16), _s((2, 4096, 20, 128), BF16)
+    assert jax.eval_shape(attend, q, k, v).shape == (2, 4096, 40, 128)
+    compile_for_chip(attend, q, k, v)
+    text = compile_for_chip(
+        jax.grad(lambda q, k, v: _sum_f32(attend(q, k, v)), argnums=(0, 1, 2)), q, k, v)
+    assert set(kernel_tiles(text)) == {"flash_bwd_dkv", "flash_fwd"}
+
+
+def test_selective_scan_fwd_and_grad_at_the_cell_s_widths(compile_for_chip):
+    """The SambaY cell's Mamba layer: B=2, T=4096, d_inner 5120, state 16.
+    Both kernels compile (128 unrolled steps a chunk; the backward's
+    recomputed states are 8 MiB of VMEM at 1024 channels a block), and
+    each says its grid and the time steps it walks."""
+    from ddl_tpu.obs.scope import kernel_tiles
+    from ddl_tpu.ops.selective_scan import BLOCK_D, CHUNK, selective_scan
+
+    b, t, d_in, n = 2, 4096, 5120, 16
+    args = (_s((b, t, d_in), F32), _s((b, t, d_in), F32), _s((d_in, n), F32),
+            _s((b, t, n), F32), _s((b, t, n), F32), _s((d_in,), F32))
+
+    def scan(*a):
+        return selective_scan(*a, interpret=False)[0]
+
+    compile_for_chip(scan, *args)
+    text = compile_for_chip(
+        jax.grad(lambda *a: scan(*a).sum(), argnums=tuple(range(6))), *args)
+    steps = b * (d_in // BLOCK_D) * (t // CHUNK)
+    assert kernel_tiles(text) == {
+        name: {"calls": 1, "total": steps, "steps": steps * CHUNK}
+        for name in ("ssm_scan_bwd", "ssm_scan_fwd")
+    }
+
+
 @pytest.mark.parametrize(
     "k,n", [pytest.param(2048, 1024, id="gate-up"), pytest.param(1024, 2048, id="down")]
 )

@@ -655,10 +655,16 @@ def render_hbm(account: dict, job_id: str = "") -> str:
                     )
                     continue
                 share = 100.0 * n.get("computed", 0) / max(n.get("total", 0), 1)
+                # a flash kernel: its sub-tiles, and the (row, K step)
+                # pairs its walk makes (absent from an older record)
+                row_steps = (
+                    f", {n['row_steps']} row-steps" if "row_steps" in n else ""
+                )
                 lines.append(
                     f"    tiles {kernel}: {n.get('calls')} call(s), "
                     f"{n.get('computed')} of {n.get('total')} sub-tiles "
                     f"computed ({share:.1f}%), {n.get('masked')} masked"
+                    f"{row_steps}"
                 )
         if dropped:
             lines.append(f"  (+{dropped} plan(s) beyond the retained cap)")

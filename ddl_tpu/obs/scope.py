@@ -246,9 +246,10 @@ def tag_counts(table: dict[str, str]) -> dict[str, int]:
 def kernel_tiles(text: str) -> dict[str, dict[str, int]]:
     """What the Pallas kernels of an optimized module's ENTRY say they
     compute, summed by kernel: ``{kernel name: {"calls", "total",
-    "computed", "masked"}}`` from each custom call's ``kernel_metadata``
-    (``pallas_call(metadata={"tiles_total": ...})``: the flash kernels'
-    sub-tiles in the square, visited, and masked,
+    "computed", "masked", "row_steps"}}`` from each custom call's
+    ``kernel_metadata`` (``pallas_call(metadata={"tiles_total": ...})``:
+    the flash kernels' sub-tiles in the square, visited, and masked, and
+    the (row, K step) pairs of their walk,
     ``ops/flash_attention.flash_tile_plan``).  Kernels that carry none,
     and programs without kernels (or interpreted ones), give ``{}``."""
     out: dict[str, dict[str, int]] = {}

@@ -10,6 +10,23 @@ applied *within* one device's block loop).  The forward's per-program
 VMEM is O(block_q x head_dim + block_k x head_dim) regardless of sequence
 length, and every matmul lands on the MXU at (block, head_dim) granularity.
 
+The forward keeps those statistics a lane tile wide, ``(block_q, 128)``
+float32 each.  A row's running max is held equal along its lanes, so
+``s - m`` and ``acc * corr`` take it by repeating the tile (or a slice of
+it, where V's head is narrower than 128), never by a broadcast out of one
+lane; its running sum is held as 128 per-lane partial sums, to which a K
+step adds the probabilities' lane tiles with vector adds.  A K step then
+costs whole-vreg loads, stores and vector ops and one reduction across
+lanes, the max; the sum across lanes happens once a Q block, at its
+close, which also turns the finished ``m + log l`` from rows to lanes by
+reading each 128-row square's diagonal down its sublanes (``lse`` leaves
+as ``(bh, 1, t)``: the backward and the ring merge read it so).  As
+``(block_q, 1)`` columns the same bookkeeping was one-lane masked loads
+and stores, two reductions, two lane broadcasts a K step and a strided
+store a row at the close, and the forward's time followed the (row, K
+step) pairs a call makes, not its area (``flash_tile_plan``'s
+``row_steps``; PERF.md section 6, PR 26 and 32).
+
 The backward pass is one kernel over the same band, with a saved per-row
 logsumexp: each run of sub-tiles recomputes its probabilities from the
 residuals instead of storing them (rematerialisation in kernel form) and
@@ -202,16 +219,23 @@ def flash_tile_plan(
     ``_band`` calls live, the runs it walks) and how many of those it
     masks (``masked``: those the band's edge crosses, and where sub-tiles
     and band are not aligned the neighbour an edge can reach), with the
-    sub-tile's shape.  Pure arithmetic on the arguments, by the predicate
+    sub-tile's shape, and ``row_steps``: the (row, K step) pairs it makes,
+    ``sub_q`` rows for every run it walks (a K step is one pass of the
+    online softmax over a run: a row's statistics are read, updated and
+    written once, whatever the run's width; it is what the forward's time
+    followed before its statistics were lane-dense, PERF.md section 6, PR
+    26 and 32).  Pure arithmetic on the arguments, by the predicate
     and the mask rule the kernels' walk uses; each ``pallas_call`` carries
     its kernel's numbers (times its rows) as ``metadata``, which the
     compiled step's ``hbm_plan`` record sums
     (``obs/scope.kernel_tiles``)."""
     bq, bk = _pick_block(t, block_q), _pick_block(t, block_k)
     sub_q, sub_k = _sub_tile(t, bq, bk, causal, window)
-    tiles = {"total": (t // sub_q) * (t // sub_k), "computed": 0, "masked": 0}
+    tiles = {"total": (t // sub_q) * (t // sub_k), "computed": 0, "masked": 0,
+             "row_steps": 0}
     if not causal:
         tiles["computed"] = tiles["total"]
+        tiles["row_steps"] = tiles["total"] * sub_q
     else:
         reach = _edge_reach(sub_q, sub_k, bq, bk, kv_offset, window)
         for _, c_lo, c_hi, diagonal in _grid_runs(
@@ -219,6 +243,7 @@ def flash_tile_plan(
         ):
             tiles["computed"] += c_hi - c_lo
             tiles["masked"] += len(_masked(c_hi - c_lo, reach, diagonal))
+            tiles["row_steps"] += sub_q
     # both kernels walk the same sub-tiles today; the record is per
     # kernel so that a kernel with a walk of its own can say so
     return {"sub_tile": [sub_q, sub_k], **{name: dict(tiles) for name in _KERNELS}}
@@ -307,9 +332,9 @@ def _masked(n_run, reach, diagonal):
 def _visible(step, branches, r0, sub_q, k0, sub_k, n, window):
     """Run ``step(c_lo, c_hi, diagonal)`` once, on the run ``[c_lo, c_hi)``
     of the resident K block's sub-blocks that rows ``[r0, r0 + sub_q)``
-    can see, in ONE pass over the whole run (a K step costs the forward
-    more in per-row bookkeeping than in area: PERF.md section 6, PR 26),
-    and not at all when they see none.  The run's bounds are program-id
+    can see, in ONE pass over the whole run (a K step has a price a row
+    beside its area's: PERF.md section 6, PR 26 and 32), and not at all
+    when they see none.  The run's bounds are program-id
     arithmetic and a score tile's shape is static, so each run in
     ``branches`` (the distinct runs this Q sub-block meets anywhere in
     the grid, ``_grid_runs``) is its own ``pl.when`` branch, of which at
@@ -352,10 +377,60 @@ def _scores(q, k_blk, r0, k_first, sub_k, masked, window):
     return jnp.concatenate(parts, axis=1) if len(parts) > 1 else parts[0]
 
 
+# A vreg's lanes: the width the forward keeps a row's running max and sum at
+_LANES = 128
+
+
+def _lanes(x, n):
+    """``x`` (rows, 128), equal along a row's lanes, at ``n`` lanes: whole
+    lane tiles by repetition and a slice of the tile for the rest, so
+    nothing is broadcast from one lane."""
+    reps, rest = divmod(n, _LANES)
+    parts = []
+    if reps:
+        parts.append(pltpu.repeat(x, reps, axis=1) if reps > 1 else x)
+    if rest:
+        parts.append(x[:, :rest])
+    return jnp.concatenate(parts, axis=1) if len(parts) > 1 else parts[0]
+
+
+def _lane_sums(p):
+    """``p`` (rows, n) as 128 partial sums a row: its lane tiles added up
+    by vector adds, no reduction across lanes (a ragged last tile is
+    padded with zeros)."""
+    rows, n = p.shape
+    if n % _LANES:
+        p = jnp.concatenate(
+            [p, jnp.zeros((rows, -n % _LANES), p.dtype)], axis=1
+        )
+    return sum(p[:, c:c + _LANES] for c in range(0, p.shape[1], _LANES))
+
+
+def _rows_to_lanes(x):
+    """``x`` (rows, 128), equal along a row's lanes, as (1, rows): row r on
+    lane r, the layout lse leaves in.  Each square of 128 rows keeps its
+    diagonal and is summed down its sublanes (one value and zeros:
+    exact): vector work on 16 vregs a square, where relaying a (rows, 1)
+    column out to lanes costs a strided store and a rotate a row."""
+    out = []
+    for r0 in range(0, x.shape[0], _LANES):
+        square = x[r0:r0 + _LANES]
+        diagonal = (
+            lax.broadcasted_iota(jnp.int32, square.shape, 0)
+            == lax.broadcasted_iota(jnp.int32, square.shape, 1)
+        )
+        row = jnp.where(diagonal, square, 0.0).sum(axis=0, keepdims=True)
+        out.append(row[:, :square.shape[0]])
+    return jnp.concatenate(out, axis=1) if len(out) > 1 else out[0]
+
+
 def _fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, acc_sc, m_sc, l_sc, *, scale,
     causal, window=0, kv_offset=0, sub_q, sub_k, runs, reach,
 ):
+    # m_sc: a row's running max, equal along its 128 lanes; l_sc: its
+    # running sum as 128 per-lane partial sums, summed at the close (the
+    # module's text says why)
     i, j = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
     bq = q_ref.shape[1]
@@ -387,12 +462,12 @@ def _fwd_kernel(
                             window)
                 m = m_sc[rows]
                 new_m = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-                p = jnp.exp(s - new_m)
+                p = jnp.exp(s - _lanes(new_m, s.shape[1]))
                 corr = jnp.exp(m - new_m)
-                l_sc[rows] = l_sc[rows] * corr + p.sum(axis=-1, keepdims=True)
-                acc_sc[rows] = acc_sc[rows] * corr + jnp.dot(
-                    p, v_blk, preferred_element_type=jnp.float32
-                )
+                l_sc[rows] = l_sc[rows] * corr + _lane_sums(p)
+                acc_sc[rows] = acc_sc[rows] * _lanes(
+                    corr, acc_sc.shape[1]
+                ) + jnp.dot(p, v_blk, preferred_element_type=jnp.float32)
                 m_sc[rows] = new_m
 
             _visible(step, _branches(runs, a), r0, sub_q, k0, sub_k,
@@ -400,9 +475,9 @@ def _fwd_kernel(
 
     @pl.when(j == nk - 1)
     def _():
-        l = jnp.maximum(l_sc[:], 1e-30)
+        l = jnp.maximum(l_sc[:].sum(axis=-1, keepdims=True), 1e-30)
         o_ref[0] = (acc_sc[:] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_sc[:] + jnp.log(l))[:, 0]
+        lse_ref[0] = _rows_to_lanes(m_sc[:] + jnp.log(l))
 
 
 def _bwd_kernel(
@@ -529,8 +604,8 @@ def _flash_fwd_impl(
         ),
         scratch_shapes=[
             pltpu.VMEM((block_q, dv), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
         interpret=interpret,
         name="flash_fwd",
@@ -739,11 +814,19 @@ def flash_attention(
     and with a window of 1024 1.19 / 4.97 (pre-walk, 512x1024: 1.32 /
     7.03); ``block_q=2048`` loses (16.3).  ``block_k`` was not swept
     again: the older sweep that chose 1024 (PERF_HISTORY.md) found 512
-    slower at every T, and a K step costs what it did.  The T^2 score
-    tile stays out of HBM either way.  Those backward figures are the two
-    kernels' of that PR; the one kernel since PR 30 takes 1.15 ms a call
-    at the cell's shape where they took 1.55 (kernel events of a device
-    trace, v5e), and 1.65 against 1.93 at B=1, H=12, T=8192, window 1024.
+    slower at every T.  The T^2 score tile stays out of HBM either way.
+    Those backward figures are the two kernels' of that PR; the one
+    kernel since PR 30 takes 1.15 ms a call at the cell's shape where
+    they took 1.55 (kernel events of a device trace, v5e), and 1.65
+    against 1.93 at B=1, H=12, T=8192, window 1024.  Those forward
+    figures are the ``(block_q, 1)`` statistics' of that PR; lane-dense
+    (PR 32's sweep, same method, forward ms a call before / after) the
+    cell's shape takes 0.931 / 0.534 and its non-causal square 1.020 /
+    0.741, so what does not depend on area fell from 0.78 ms to 0.19;
+    B=2, T=4096, H=32 on 4 K/V heads of 128: 2.77 / 2.15 at window 2048
+    and 2.98 / 2.38 full; B=2, T=4096, H=40 on 20 K heads of 64 under V
+    heads of 128: 2.83 / 2.09 at window 512 and 3.99 / 3.36 full.  Block
+    sizes were not swept again under it.
     ``interpret=None`` interprets on the CPU backend (tests on the
     simulated mesh) and compiles on a TPU (``ops/interpret.py``).
     """

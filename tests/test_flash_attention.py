@@ -514,17 +514,21 @@ def test_flash_kv_offset_empty_band_rows_are_zero(blocks):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
 
-def _brute_plan(t, sub_q, sub_k, causal, window, kv_offset):
-    """Sub-tile counts from ``_qk_live`` over single (row, key) pairs."""
+def _brute_plan(t, sub_q, sub_k, bk, causal, window, kv_offset):
+    """Sub-tile counts from ``_qk_live`` over single (row, key) pairs, and
+    the (row, K step) pairs: a Q sub-block's rows for every K block that
+    holds a pair they see (the kernels make one pass a run)."""
     rows, keys = np.arange(t)[:, None], np.arange(t)[None, :]
     pair = np.broadcast_to(
         fa._qk_live(rows, keys, 1, 1, causal, window, kv_offset), (t, t)
     )
     tiles = pair.reshape(t // sub_q, sub_q, t // sub_k, sub_k)
     live, full = tiles.any(axis=(1, 3)), tiles.all(axis=(1, 3))
+    meets = pair.reshape(t // sub_q, sub_q, t // bk, bk).any(axis=(1, 3))
     return {
         "total": live.size, "computed": int(live.sum()),
         "masked": int((live & ~full).sum()),
+        "row_steps": int(meets.sum()) * sub_q,
     }
 
 
@@ -562,11 +566,12 @@ def test_flash_tile_plan_is_the_brute_force_count_and_the_kernels_walk(
     bq, bk = fa._pick_block(t, bq), fa._pick_block(t, bk)
     sub_q, sub_k = plan["sub_tile"]
     assert (sub_q, sub_k) == fa._sub_tile(t, bq, bk, causal, window)
-    want = _brute_plan(t, sub_q, sub_k, causal, window, kv_offset)
+    want = _brute_plan(t, sub_q, sub_k, bk, causal, window, kv_offset)
     aligned = not window and kv_offset % sub_k == 0 and sub_q == sub_k
     for name in fa._KERNELS:
         got = plan[name]
         assert (got["total"], got["computed"]) == (want["total"], want["computed"])
+        assert got["row_steps"] == want["row_steps"]
         assert want["masked"] <= got["masked"] <= got["computed"]
         assert got["masked"] == want["masked"] or not aligned
     if not causal:
@@ -599,6 +604,8 @@ def test_flash_tile_plan_is_the_brute_force_count_and_the_kernels_walk(
             assert edges <= cols and (cols == edges or not aligned)
             visited, masked = visited + hi - lo, masked + len(cols)
     assert (visited, masked) == (plan["flash_fwd"]["computed"], plan["flash_fwd"]["masked"])
+    # one K step a run the grid reaches, of the Q sub-block's rows
+    assert plan["flash_fwd"]["row_steps"] == sub_q * len(runs)
 
 
 def test_flash_tile_plan_engages_in_the_benchmark_cell():
@@ -612,13 +619,108 @@ def test_flash_tile_plan_engages_in_the_benchmark_cell():
     assert fa._KERNELS == ("flash_fwd", "flash_bwd_dkv")
     assert sorted(plan) == ["flash_bwd_dkv", "flash_fwd", "sub_tile"]
     for name in fa._KERNELS:
-        assert plan[name] == {"total": 16, "computed": 10, "masked": 4}
+        assert plan[name] == {"total": 16, "computed": 10, "masked": 4, "row_steps": 1024}
         assert plan[name]["computed"] <= 0.75 * plan[name]["total"]
     full = fa.flash_tile_plan(1024, causal=False)
     assert full["sub_tile"] == [1024, 1024]
-    assert full["flash_fwd"] == {"total": 1, "computed": 1, "masked": 0}
+    assert full["flash_fwd"] == {"total": 1, "computed": 1, "masked": 0, "row_steps": 1024}
     assert fa.flash_tile_plan(8192, causal=True)["sub_tile"] == [512, 512]
     assert fa.flash_tile_plan(1024, causal=True, window=256)["sub_tile"] == [512, 512]
+
+
+@pytest.mark.parametrize(
+    "rows,t,window,row_steps",
+    [
+        pytest.param(16 * 12, 1024, 0, 196_608, id="gpt2s"),
+        pytest.param(2 * 32, 4096, 2048, 589_824, id="trinity-sliding"),
+        pytest.param(2 * 32, 4096, 0, 655_360, id="trinity-full"),
+        pytest.param(2 * 40, 4096, 512, 450_560, id="phi4flash-sliding"),
+        pytest.param(2 * 40, 4096, 0, 819_200, id="phi4flash-full-and-cross"),
+    ],
+)
+def test_flash_row_steps_a_call_in_the_benchmark_cells(rows, t, window, row_steps):
+    """The (row, K step) pairs one call makes at the three LM cells'
+    shapes, as each launcher writes them into its ``pallas_call``'s
+    metadata: what the forward's time followed (ISSUE 32's counts)."""
+    for name in fa._KERNELS:
+        _, metadata = fa._walk(name, rows, t, 1024, 1024, True, window, 0)
+        assert metadata["tiles_row_steps"] == str(row_steps)
+
+
+# (T, blocks, band, kv_offset, K/V heads, V's width): a row makes up to four
+# K steps (T = 4 x block_k), so its statistics cross grid steps and runs
+SEVERAL_K_STEPS = [
+    pytest.param(64, (16, 16, None), (True, 0), 0, 2, 8, id="causal"),
+    pytest.param(64, (32, 16, 8), (True, 0), 0, 2, 8, id="causal-sub-tiles"),
+    pytest.param(64, (16, 16, 8), (True, 16), 0, 2, 8, id="window-on-a-boundary"),
+    pytest.param(64, (32, 16, 8), (True, 20), 0, 2, 8, id="window-off-a-boundary"),
+    pytest.param(64, (16, 16, None), (False, 0), 0, 2, 8, id="non-causal"),
+    pytest.param(64, (32, 16, 8), (True, 40), 64, 2, 8, id="kv-offset-empty-rows"),
+    pytest.param(64, (16, 16, 8), (True, 0), 0, 1, 8, id="gqa"),
+    pytest.param(64, (32, 16, 8), (True, 20), 0, 1, 16, id="gqa-wide-v"),
+    pytest.param(1024, (512, 256, 128), (True, 300), 0, 1, 16, id="whole-lane-tiles"),
+]
+
+
+@pytest.mark.parametrize("t,blocks,band,kv_offset,hkv,dv", SEVERAL_K_STEPS, indirect=["blocks"])
+def test_flash_over_several_k_steps_matches_dense(t, blocks, band, kv_offset, hkv, dv):
+    """Out, lse and the three gradients against the dense reference where
+    a row's running max and sum cross several K steps: the forward keeps
+    them a lane tile wide (``m`` equal along a row's lanes, ``l`` as
+    per-lane partial sums closed once a Q block), and ``lse`` keeps its
+    values to float32 rounding, because the backward and the ring merge
+    read it.  Rows with an empty band (``kv_offset``) read 0 and the floor."""
+    from ddl_tpu.ops.flash_attention import flash_attention_with_lse
+
+    causal, window = band
+    h, d = 2, 8
+    rng = np.random.default_rng(32)
+    q = jnp.asarray(rng.normal(size=(1, t, h, d)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(1, t, hkv, d)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(1, t, hkv, dv)), jnp.float32)
+    co = jnp.asarray(rng.normal(size=(1, t, h, dv)), jnp.float32)
+    cl = jnp.asarray(rng.normal(size=(1, h, t)), jnp.float32)
+    rows, keys = np.arange(t)[:, None], np.arange(t)[None, :]
+    visible = np.broadcast_to(
+        fa._qk_live(rows, keys, 1, 1, causal, window, kv_offset), (t, t)
+    )
+    seen = jnp.asarray(visible.any(axis=1))  # rows with a key in their band
+
+    def dense(q, k, v):
+        k, v = (jnp.repeat(x, h // hkv, axis=2) for x in (k, v))
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(jnp.float32(d))
+        s = jnp.where(jnp.asarray(visible)[None, None], s, -1e30)
+        lse = jax.scipy.special.logsumexp(s, axis=-1)
+        out = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
+        return out * seen[None, :, None, None], lse * seen
+
+    def flash(q, k, v):
+        out, lse = flash_attention_with_lse(
+            q, k, v, causal=causal, window=window, kv_offset=kv_offset, **blocks
+        )
+        return out, lse * seen  # an empty row's lse is the floor: compared below
+
+    def loss(attend):
+        def f(q, k, v):
+            out, lse = attend(q, k, v)
+            return (out * co).sum() + (lse * cl).sum()
+        return f
+
+    with jax.default_matmul_precision("highest"):
+        out, lse = flash_attention_with_lse(
+            q, k, v, causal=causal, window=window, kv_offset=kv_offset, **blocks
+        )
+        want_out, want_lse = dense(q, k, v)
+        got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+        want = jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
+    empty = ~np.asarray(seen)
+    assert empty.any() == bool(kv_offset)
+    np.testing.assert_array_equal(np.asarray(out)[:, empty], 0.0)
+    assert np.all(np.asarray(lse)[:, :, empty] < -1e29)
+    np.testing.assert_allclose(out, want_out, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(lse * seen, want_lse, rtol=1e-6, atol=1e-6)
+    for g, r, name in zip(got, want, "qkv"):
+        np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-5, err_msg=name)
 
 
 def test_flash_backward_refuses_a_sequence_its_vmem_cannot_hold():

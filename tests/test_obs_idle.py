@@ -264,6 +264,7 @@ ENTRY %main.7 (p.1: f32[128,128], x.1: bf16[8,128]) -> (f32[128,128], f32[]) {
   %jvp_flash_fwd_.2 = (bf16[8,128]{1,0:T(8,128)(2,1)S(1)}, f32[8,1]{1,0:T(1,128)}) custom-call(%fusion.12), custom_call_target="tpu_custom_call", frontend_attributes={kernel_metadata={
 "tiles_computed":"1920",
 "tiles_masked":"768",
+"tiles_row_steps":"196608",
 "tiles_total":"3072"
 }}, metadata={op_name="jit(train_step)/jvp(flash_fwd)/pallas_call" stack_frame_id=37}, backend_config={"custom_call_config":{"body":"TUzvUgFN(%notanoperand)"}}
   %get-tuple-element.4 = bf16[8,128]{1,0:T(8,128)(2,1)S(1)} get-tuple-element(%jvp_flash_fwd_.2), index=0
@@ -308,7 +309,7 @@ def test_kernel_tiles_on_a_recorded_text():
     kernel without metadata, and a text without kernels, give nothing."""
     from ddl_tpu.obs.scope import kernel_tiles
 
-    one = {"calls": 1, "computed": 1920, "masked": 768, "total": 3072}
+    one = {"calls": 1, "computed": 1920, "masked": 768, "row_steps": 196608, "total": 3072}
     assert kernel_tiles(_HLO) == {"flash_fwd": one}
     again = _HLO.replace("%jvp_flash_fwd_.2 = ", "%jvp_flash_fwd_.3 = ")
     entry = _HLO.index("ENTRY")
@@ -472,7 +473,9 @@ def test_obs_hbm_prints_the_events_scope_counts_and_file(tmp_path):
            output_bytes=4096, temp_bytes=512, alias_bytes=0, code_bytes=64,
            scope_counts={"bwd": 7, "fwd": 5, "kernel/flash_fwd": 1, "update": 2},
            scope_file="scope-h000-train_step.json",
-           kernel_tiles={"flash_fwd": {"calls": 12, "computed": 23040, "masked": 9216, "total": 36864},
+           kernel_tiles={"flash_fwd": {"calls": 12, "computed": 23040, "masked": 9216, "total": 36864,
+                                       "row_steps": 2359296},
+                         "flash_bwd_dkv": {"calls": 12, "computed": 23040, "masked": 9216, "total": 36864},
                          "moe_rows_gather": {"calls": 4, "total": 2048, "floor": 32}})
     w.emit("hbm_plan", label="eval_step", analysis="aval", argument_bytes=64, output_bytes=8)
     w.emit("hbm_sample", params_bytes=600, watermark=2000, peak=2000, limit=4096, synthetic=True)
@@ -481,11 +484,15 @@ def test_obs_hbm_prints_the_events_scope_counts_and_file(tmp_path):
     assert ("scope (scope-h000-train_step.json): bwd 7, fwd 5, kernel/flash_fwd 1, update 2"
             in out)
     assert out.count("scope (") == 1  # a plan without a table gets no such line
-    assert ("tiles flash_fwd: 12 call(s), 23040 of 36864 sub-tiles computed (62.5%), 9216 masked"
+    # a flash kernel's sub-tiles and the (row, K step) pairs of its walk; a
+    # record from before the count was carried prints without it
+    assert ("tiles flash_fwd: 12 call(s), 23040 of 36864 sub-tiles computed (62.5%), 9216 masked, "
+            "2359296 row-steps\n" in out)
+    assert ("tiles flash_bwd_dkv: 12 call(s), 23040 of 36864 sub-tiles computed (62.5%), 9216 masked\n"
             in out)
     # a kernel whose steps the routing decides says its grid's two ends
     assert "tiles moe_rows_gather: 4 call(s), 2048 grid steps at most, 32 at least" in out
-    assert out.count("    tiles ") == 2
+    assert out.count("    tiles ") == 3
 
 
 # the benchmark's cases (tests/benchmark: test_trace_reduction_on_a_hand_built_trace)

@@ -113,6 +113,8 @@ def test_flash_attention_fwd_and_grad(
         for name in ("flash_bwd_dkv", "flash_fwd")
     }
     assert plan["flash_fwd"]["computed"] < plan["flash_fwd"]["total"]
+    # among the counts held above, the (row, K step) pairs of each walk
+    assert all(plan[name]["row_steps"] > 0 for name in ("flash_bwd_dkv", "flash_fwd"))
 
 
 @pytest.mark.parametrize("window", [512, 0], ids=["phi4flash-sliding", "phi4flash-full-and-cross"])
@@ -132,6 +134,12 @@ def test_flash_attention_with_a_wider_v_head(compile_for_chip, window):
     text = compile_for_chip(
         jax.grad(lambda q, k, v: _sum_f32(attend(q, k, v)), argnums=(0, 1, 2)), q, k, v)
     assert set(kernel_tiles(text)) == {"flash_bwd_dkv", "flash_fwd"}
+    from ddl_tpu.ops.flash_attention import flash_tile_plan
+
+    plan = flash_tile_plan(4096, causal=True, window=window)
+    assert plan["flash_fwd"]["row_steps"] == (5632 if window else 10240)
+    for name in ("flash_bwd_dkv", "flash_fwd"):
+        assert kernel_tiles(text)[name]["row_steps"] == 2 * 40 * plan[name]["row_steps"]
 
 
 def test_selective_scan_fwd_and_grad_at_the_cell_s_widths(compile_for_chip):

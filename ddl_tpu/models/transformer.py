@@ -27,6 +27,14 @@ product of ``ops/grouped_matmul.py``) that is told which experts it holds
 part.  That is the afmoe block of ``benchmark/configs/trinity-mini.json``;
 the defaults are the block above, unchanged.
 
+The expert layer is said in two statements: how a token scores the experts
+(``moe_router``: softmax or sigmoid) and what happens to its choices
+(``moe_layer``: a token capacity, or dropless).  Softmax scores through the
+dropless layer, every MLP sparse, no shared expert, and rotary positions
+said by kind of layer (``rope_by_kind``: plain in the sliding layers,
+YaRN-scaled in the full ones, ``Rope``) are the mellum block of
+``benchmark/configs/mellum2-12b-a2.5b.json``.
+
 A layer's mixer need not be attention at all (``layer_types`` again): a
 Mamba-1 layer (``Mamba``: causal depthwise convolution, the selective scan
 of ``ops/selective_scan.py``, a gate), a Gated Memory Unit that gates an
@@ -59,6 +67,7 @@ from ddl_tpu.ops.attention import dense_attention
 __all__ = [
     "LAYER_KINDS",
     "LMConfig",
+    "Rope",
     "REMAT_POLICIES",
     "TransformerLM",
     "count_lm_params",
@@ -75,6 +84,55 @@ LAYER_KINDS = ("sliding_attention", "full_attention", "mamba", "gmu",
                "cross_attention")
 # {a kind of layer that reads a carried value: the kind that keeps it}
 _CARRIED = {"gmu": "mamba", "cross_attention": "full_attention"}
+
+
+@dataclasses.dataclass(frozen=True)
+class Rope:
+    """One kind of layer's rotary positions: plain (``factor`` 1:
+    ``inv_freq_i = theta^(-i/half)``) or YaRN (Peng et al. 2023, as the
+    ``transformers`` ``rope_type: yarn`` computes it): the plain
+    frequencies ``ext_i`` and the interpolated ones ``ext_i / factor``
+    blended by a ramp over the frequency index, ``int_i r_i + ext_i (1 -
+    r_i)`` with ``r_i = clip((i - low) / (high - low), 0, 1)``, ``low``
+    and ``high`` the indices whose wavelengths make ``beta_fast`` and
+    ``beta_slow`` turns over ``original`` positions; cos and sin times
+    ``attention_factor``."""
+
+    theta: float = 10000.0
+    factor: float = 1.0
+    original: int = 0  # positions the model was first trained at
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def __post_init__(self):
+        if self.factor < 1.0 or (self.factor > 1.0 and self.original <= 0):
+            raise ValueError(
+                f"a scaled rotary needs factor >= 1 and the original length "
+                f"it scales from, got {self!r}"
+            )
+
+    def ramp_ends(self, head_dim: int) -> tuple[int, int]:
+        """``(low, high)``: the frequency indices between which YaRN's
+        ramp goes from the plain frequencies to the interpolated ones."""
+        def index(turns):
+            return (head_dim * math.log(self.original / (turns * 2 * math.pi))
+                    / (2 * math.log(self.theta)))
+
+        return (max(math.floor(index(self.beta_fast)), 0),
+                min(math.ceil(index(self.beta_slow)), head_dim - 1))
+
+    def inv_freq(self, half: int):
+        """(half,) float32 angular frequencies a position."""
+        ext = self.theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+        if self.factor == 1.0:
+            return ext
+        low, high = self.ramp_ends(2 * half)
+        ramp = jnp.clip(
+            (jnp.arange(half, dtype=jnp.float32) - low) / max(high - low, 0.001),
+            0.0, 1.0,
+        )
+        return (ext / self.factor) * ramp + ext * (1.0 - ramp)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -221,9 +279,15 @@ class LMConfig:
     # attn_window positions) or "full_attention" (all of the past);
     # () = every layer alike, windowed iff attn_window.  With a pattern
     # attn_window is the sliding layers' window, and only the sliding
-    # layers rotate q and k: a full_attention layer of a pattern has no
-    # positional signal of its own.
+    # layers rotate q and k (rope_theta): a full_attention layer of a
+    # pattern has no positional signal of its own, unless rope_by_kind
+    # gives it one.
     layer_types: tuple = ()
+    # Rotary positions said by kind of layer: ((kind, Rope or None), ...)
+    # for "sliding_attention" and/or "full_attention".  A kind named here
+    # rotates q and k by its own Rope (plain, or YaRN-scaled), or not at
+    # all (None); a kind not named keeps the rule above.
+    rope_by_kind: tuple = ()
     # RMSNorm over each head's head_dim on q and k (learned scale) before
     # the rotation; a sigmoid gate on the attention output from a
     # projection of the block's input (d_model -> n_heads * head_dim).
@@ -239,21 +303,29 @@ class LMConfig:
     embed_scale: bool = False
     # With num_experts > 0, the leading layers that keep the dense MLP.
     num_dense_layers: int = 0
-    # 'softmax': top-k of softmax gates under a token capacity (choices
-    # past it are dropped), the path above.  'sigmoid': dropless: sigmoid
-    # scores, selection by score + a bias that is not trained, the chosen
-    # scores normalised and scaled by route_scale, every choice computed
-    # (rows sorted by expert, ops/grouped_matmul.py), beside
-    # num_shared_experts experts that every token passes through.
+    # How a token scores the experts: 'softmax' over all of them, or
+    # 'sigmoid' of each, with selection by score + a bias leaf that is not
+    # trained (the leaf exists under 'sigmoid' only).
     moe_router: str = "softmax"
+    # What happens to the top-k choices: 'capacity': a token capacity an
+    # expert, choices past it dropped, a balance loss (the GShard path
+    # above with its own knobs, moe_dispatch, moe_ep, moe_group and
+    # capacity_*; softmax scores only); 'dropless': every choice computed
+    # (rows sorted by expert, ops/grouped_matmul.py), the chosen scores
+    # normalised to sum to 1 and scaled by route_scale, beside
+    # num_shared_experts experts that every token passes through, no
+    # balance loss.  '' = the score function's first pairing: 'capacity'
+    # under 'softmax', 'dropless' under 'sigmoid'.
+    moe_layer: str = ""
     moe_d_ff: int = 0  # an expert's width (0 = d_ff)
+    # the three below are the dropless layer's, under either score
     num_shared_experts: int = 0
     route_scale: float = 1.0
     # (share index, shares): this program holds experts [i * E / n,
     # (i + 1) * E / n) of each layer's num_experts, routes over all of
     # them and computes its own experts' part of the result; choices of
     # experts held elsewhere add nothing here (their owners' exchange is
-    # not this program's).  (0, 1) holds them all.  'sigmoid' only.
+    # not this program's).  (0, 1) holds them all.
     expert_share: tuple = (0, 1)
     # --- a hybrid stack: layer_types may also name "mamba" (a Mamba-1
     # mixer), "gmu" (a Gated Memory Unit over the scan output of the
@@ -316,8 +388,30 @@ class LMConfig:
                 f"expert_share {self.expert_share!r} must be (index, shares) with "
                 f"index < shares and shares dividing num_experts {self.num_experts}"
             )
-        if shares > 1 and self.moe_router != "sigmoid":
-            raise ValueError("expert_share is the dropless ('sigmoid') router's")
+        if self.moe_layer not in ("", "capacity", "dropless"):
+            raise ValueError(
+                f"moe_layer must be 'capacity', 'dropless' or '' (the score "
+                f"function's first pairing), got {self.moe_layer!r}"
+            )
+        if self.moe_router == "sigmoid" and not self.moe_dropless:
+            raise ValueError("sigmoid scores under a token capacity are not built: "
+                             "moe_router='sigmoid' takes moe_layer='dropless'")
+        if not self.moe_dropless and (
+                shares > 1 or self.num_shared_experts or self.route_scale != 1.0):
+            raise ValueError(
+                "expert_share, num_shared_experts and route_scale are the dropless "
+                "layer's (moe_layer='dropless', under either score function); "
+                "the capacity layer would ignore them"
+            )
+        for kind, rope in self.rope_by_kind:
+            if kind not in ("sliding_attention", "full_attention") or not (
+                    rope is None or isinstance(rope, Rope)):
+                raise ValueError(
+                    f"rope_by_kind pairs 'sliding_attention' or 'full_attention' "
+                    f"with a Rope or None, got {(kind, rope)!r}"
+                )
+        if self.rope_by_kind and self.diff_attn:
+            raise ValueError("diff_attn rotates nothing: it takes no rope_by_kind")
         if self.moe_ep not in ("auto", "gspmd", "alltoall"):
             raise ValueError(
                 f"moe_ep must be 'auto', 'gspmd' or 'alltoall', got "
@@ -367,6 +461,13 @@ class LMConfig:
         return jnp.dtype(self.compute_dtype)
 
     @property
+    def moe_dropless(self) -> bool:
+        """Whether the expert layer computes every choice (``moe_layer``)."""
+        if self.moe_layer:
+            return self.moe_layer == "dropless"
+        return self.moe_router == "sigmoid"
+
+    @property
     def experts_held(self) -> int:
         return self.num_experts // self.expert_share[1]
 
@@ -393,10 +494,18 @@ class LMConfig:
             return 0
         return self.attn_window
 
-    def layer_rope(self, i: int) -> bool:
-        """Whether layer ``i`` rotates q and k: never under ``diff_attn``."""
-        return not self.diff_attn and (
-            not self.layer_types or self.layer_types[i] == "sliding_attention")
+    def layer_rope(self, i: int) -> Optional[Rope]:
+        """Layer ``i``'s rotary positions, None where it rotates nothing:
+        its kind's entry of ``rope_by_kind``, else plain rotary at
+        ``rope_theta`` in every layer of a stack without a pattern and in
+        a pattern's sliding layers; never under ``diff_attn``."""
+        kind = self.layer_kind(i)
+        by_kind = dict(self.rope_by_kind)
+        if kind in by_kind:
+            return by_kind[kind]
+        if self.diff_attn or (self.layer_types and kind != "sliding_attention"):
+            return None
+        return Rope(self.rope_theta)
 
     def layer_is_kept(self, i: int) -> bool:
         """Whether a later layer reads what layer ``i`` computes: the scan
@@ -467,22 +576,29 @@ def remat_block(cfg) -> type:
     return nn.remat(Block, static_argnums=(3,), policy=policy)
 
 
-def _rope(x, theta: float, positions=None):
-    """Rotary embeddings. x: (B, T, H, D); ``positions`` overrides the
+def _rope(x, rope: Rope, positions=None):
+    """Rotary embeddings by the layer's table (``LMConfig.layer_rope``).
+    x: (B, T, H, D); ``positions`` overrides the
     default global positions 0..T-1 — (T,) shared across the batch
     (incremental decode passes ``offset + arange(T)``) or (B, T)
     per-row (the serving engine's continuous decode batch, where each
     lane sits at its own sequence offset)."""
     _, t, _, d = x.shape
     half = d // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    freqs = rope.inv_freq(half)
     if positions is None:
         positions = jnp.arange(t, dtype=jnp.float32)
     angles = positions.astype(jnp.float32)[..., None] * freqs  # (..., T, half)
     if angles.ndim == 2:  # shared row broadcasts over the batch
         angles = angles[None]
-    cos = jnp.cos(angles)[:, :, None, :].astype(x.dtype)
-    sin = jnp.sin(angles)[:, :, None, :].astype(x.dtype)
+
+    def table(fn):
+        y = fn(angles)
+        if rope.attention_factor != 1.0:  # YaRN scales the table
+            y = y * rope.attention_factor
+        return y[:, :, None, :].astype(x.dtype)
+
+    cos, sin = table(jnp.cos), table(jnp.sin)
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
 
@@ -638,9 +754,9 @@ class Attention(nn.Module):
     cfg: LMConfig
     attn_core: Optional[Callable] = None
     # this layer's kind (LMConfig.layer_window / layer_rope); None = the
-    # model-wide attn_window
+    # model-wide attn_window, and no rotation
     window: Optional[int] = None
-    rope: bool = True
+    rope: Optional[Rope] = None
 
     @nn.compact
     def __call__(self, x, cache=None):
@@ -670,10 +786,10 @@ class Attention(nn.Module):
         if cfg.qk_norm:
             q = RMSNorm(cfg.dtype, cfg.norm_eps, name="q_norm")(q)
             k = RMSNorm(cfg.dtype, cfg.norm_eps, name="k_norm")(k)
-        if self.rope:
+        if self.rope is not None:
             positions = None if cache is None else cache.positions(t)
-            q = _rope(q, cfg.rope_theta, positions)
-            k = _rope(k, cfg.rope_theta, positions)
+            q = _rope(q, self.rope, positions)
+            k = _rope(k, self.rope, positions)
         spec = ("batch", "act_seq", "act_heads", None)
         q = nn.with_logical_constraint(q, spec)
         k = nn.with_logical_constraint(k, spec)
@@ -1330,7 +1446,7 @@ class MoeMlp(nn.Module):
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        if cfg.moe_router == "sigmoid":
+        if cfg.moe_dropless:
             return self._dropless(x), jnp.zeros((), jnp.float32)
         b0, s0, d = x.shape
         # split the sequence into routing groups (moe_routing_plan):
@@ -1490,12 +1606,13 @@ class MoeMlp(nn.Module):
         return y, aux_loss
 
     def _dropless(self, x):
-        """The dropless layer (``moe_router='sigmoid'``): sigmoid scores
-        over all ``num_experts``, the top ``expert_top_k`` of score + bias,
-        the chosen scores normalised and scaled, every choice on an
-        expert held here computed, the shared experts beside them.
-        Router in float32; the experts' products in the compute type
-        with float32 accumulation."""
+        """The dropless layer (``moe_layer='dropless'``): scores over
+        all ``num_experts`` (``moe_router``: a softmax, or sigmoids with a
+        selection bias that is not trained), the top ``expert_top_k`` of
+        them, the chosen scores normalised and scaled, every choice on an
+        expert held here computed, the shared experts (if any) beside
+        them.  Router in float32; the experts' products in the compute
+        type with float32 accumulation."""
         # imported here: Pallas loads only where a dropless layer is built
         from ddl_tpu.ops.grouped_matmul import ROW_TILE, grouped_matmul
 
@@ -1513,16 +1630,24 @@ class MoeMlp(nn.Module):
                 ),
                 name="router",
             )(flat.astype(jnp.float32))
-            scores = jax.nn.sigmoid(logits)
-            # the bias a training recipe moves to balance load: not
-            # trained by the loss, part of the selection only
-            bias = jax.lax.stop_gradient(self.param(
-                "bias",
-                nn.with_logical_partitioning(nn.initializers.zeros_init(), (None,)),
-                (e,), jnp.float32,
-            ))
-            _, idx = jax.lax.top_k(scores + bias, k)
-            picked = jnp.take_along_axis(scores, idx, axis=-1)
+            if cfg.moe_router == "sigmoid":
+                scores = jax.nn.sigmoid(logits)
+                # the bias a training recipe moves to balance load: not
+                # trained by the loss, part of the selection only
+                bias = jax.lax.stop_gradient(self.param(
+                    "bias",
+                    nn.with_logical_partitioning(nn.initializers.zeros_init(), (None,)),
+                    (e,), jnp.float32,
+                ))
+                _, idx = jax.lax.top_k(scores + bias, k)
+                picked = jnp.take_along_axis(scores, idx, axis=-1)
+            else:
+                scores = jax.nn.softmax(logits, axis=-1)
+                picked, idx = jax.lax.top_k(scores, k)
+                # the share of the softmax's mass the chosen hold before
+                # they are normalised: k / E under uniform scores, toward
+                # 1 as the router sharpens (it collapses before the rows do)
+                self.sow("intermediates", "moe_topk_mass", picked.sum(-1).mean())
             weights = cfg.route_scale * picked / (
                 picked.sum(-1, keepdims=True) + 1e-20
             )

@@ -35,6 +35,7 @@ from pathlib import Path
 __all__ = [
     "EVENT_KINDS",
     "ANOMALY_TYPES",
+    "SOWN_COUNTERS",
     "EventWriter",
     "events_path",
     "read_events",
@@ -149,6 +150,16 @@ EVENT_KINDS = (
 ANOMALY_TYPES = (
     "loss_spike", "throughput_regression", "hbm_growth", "nonfinite_loss",
     "ssm_state_growth",
+)
+
+# Step metrics that the layers of a model sow (``train/lm_steps.sown_metrics``:
+# a dropless expert layer's counters, under softmax scores with the top-k
+# mass; a Mamba stack's largest state) and that the ``period`` event copies
+# as its last step read them; the fold keeps the latest of each for
+# ``obs summarize``.
+SOWN_COUNTERS = (
+    "moe_local_rows", "moe_load_max_over_mean", "moe_rows_dropped", "moe_buffer_fill",
+    "moe_topk_mass", "ssm_state_absmax",
 )
 
 _warned_kinds: set[str] = set()

@@ -47,6 +47,7 @@ import statistics
 import threading
 from pathlib import Path
 
+from ddl_tpu.obs.events import SOWN_COUNTERS
 from ddl_tpu.obs.hbm import PLAN_FIELDS, sample_categories
 from ddl_tpu.obs.serving import ServingStats, tenant_of
 
@@ -76,8 +77,10 @@ SIDECAR_NAME = ".obs_fold.json"
 # over); v10 adds the HBM-ledger reducer (per-repoch memory cells:
 # peak-watermark category breakdown off hbm_sample, bounded last-wins
 # static plans off hbm_plan, and the hbm_oom_dump forensic cell —
-# obs/hbm.py renders the account) — older sidecars rebuild cleanly
-VERSION = 10
+# obs/hbm.py renders the account); v11 adds the per-host last-wins map of
+# the model's sown step counters off the period event (events.SOWN_COUNTERS)
+# — older sidecars rebuild cleanly
+VERSION = 11
 
 # the serving-cursor sidecar this module's cache superseded; removed
 # opportunistically when the fold sidecar is written so a job dir does
@@ -148,7 +151,7 @@ def _new_host_rec() -> dict:
 def _new_period_agg() -> dict:
     return {
         "n": 0, "steps": 0, "elapsed": 0.0, "compiles": 0,
-        "hbm": None, "phases": {}, "sps": [],
+        "hbm": None, "phases": {}, "sps": [], "sown": {},
     }
 
 
@@ -683,6 +686,8 @@ class StreamFold:
         hbm = e.get("hbm_peak_bytes")
         if hbm is not None:
             agg["hbm"] = hbm if agg["hbm"] is None else max(agg["hbm"], hbm)
+        # the model's own step counters, as the latest period read them
+        agg["sown"].update({k: e[k] for k in SOWN_COUNTERS if e.get(k) is not None})
 
         br = self.by_repoch.setdefault(repoch, _new_repoch_agg())
         br["periods"] += 1

@@ -648,10 +648,15 @@ def render_hbm(account: dict, job_id: str = "") -> str:
                     # shuffle: its grid's worst case and the fewest steps
                     # any routing leaves it (the steps computed are the
                     # routing's, not the text's)
+                    cols = ", ".join(
+                        f"{v} of {k[3:]}" for k, v in sorted(n.items())
+                        if k.startswith("col")
+                    )
                     lines.append(
                         f"    tiles {kernel}: {n.get('calls')} call(s), "
                         f"{n.get('total')} grid steps at most, "
                         f"{n.get('floor')} at least"
+                        + (f", column blocks {cols}" if cols else "")
                     )
                     continue
                 share = 100.0 * n.get("computed", 0) / max(n.get("total", 0), 1)

@@ -156,7 +156,7 @@ def summarize_from_fold(fold) -> dict:
         for h, agg in fold.streams[n].phost.items():
             m = phost.setdefault(h, {
                 "n": 0, "steps": 0, "elapsed": 0.0, "compiles": 0,
-                "hbm": None, "phases": {}, "sps": [],
+                "hbm": None, "phases": {}, "sps": [], "sown": {},
             })
             m["n"] += agg["n"]
             m["steps"] += agg["steps"]
@@ -170,13 +170,14 @@ def summarize_from_fold(fold) -> dict:
             for ph, dur in agg["phases"].items():
                 m["phases"][ph] = m["phases"].get(ph, 0.0) + dur
             m["sps"].extend(agg["sps"])
+            m["sown"].update(agg["sown"])
 
     if phost:
         rep = phost[min(phost)]
         phases = dict(rep["phases"])
         periods_n, steps = rep["n"], rep["steps"]
         elapsed, compiles = rep["elapsed"], rep["compiles"]
-        hbm, sps = rep["hbm"], rep["sps"]
+        hbm, sps, sown = rep["hbm"], rep["sps"], rep["sown"]
     else:
         # span-only streams (e.g. decode) still get a phase breakdown
         # from top-level spans (a parent's duration already contains its
@@ -186,7 +187,7 @@ def summarize_from_fold(fold) -> dict:
             for ph, dur in fold.streams[n].span_sums.items():
                 phases[ph] = phases.get(ph, 0.0) + dur
         periods_n = steps = compiles = 0
-        elapsed, hbm, sps = 0.0, None, []
+        elapsed, hbm, sps, sown = 0.0, None, [], {}
 
     half = len(sps) // 2
     trend = None
@@ -358,6 +359,9 @@ def summarize_from_fold(fold) -> dict:
         "pipe_schedule": fold.pipe_schedule(),
         "goodput": goodput,
         "hbm": hbm_section,
+        # the model's sown step counters as the latest period read them
+        # (events.SOWN_COUNTERS); absent for a program that sows none
+        **({"step_counters": dict(sorted(sown.items()))} if sown else {}),
     }
 
 
@@ -415,6 +419,9 @@ def render_summary(s: dict, job_id: str = "") -> str:
     # look like HBM was never measured at all
     if s["peak_hbm_bytes"] is not None:
         lines.append(f"peak HBM: {s['peak_hbm_bytes'] / 1e9:.2f} GB")
+    if s.get("step_counters"):
+        lines.append("step counters (latest period): " + ", ".join(
+            f"{k} {v:g}" for k, v in s["step_counters"].items()))
     hb = s.get("hbm")
     if hb:
         from ddl_tpu.obs.hbm import fmt_bytes

@@ -250,7 +250,9 @@ def kernel_tiles(text: str) -> dict[str, dict[str, int]]:
     ``kernel_metadata`` (``pallas_call(metadata={"tiles_total": ...})``:
     the flash kernels' sub-tiles in the square, visited, and masked, and
     the (row, K step) pairs of their walk,
-    ``ops/flash_attention.flash_tile_plan``).  Kernels that carry none,
+    ``ops/flash_attention.flash_tile_plan``; the grouped products' column
+    block of a width that 512 does not divide, ``col<width>``, kept as it
+    is).  Kernels that carry none,
     and programs without kernels (or interpreted ones), give ``{}``."""
     out: dict[str, dict[str, int]] = {}
     for line in _entry_lines(text):
@@ -273,7 +275,9 @@ def kernel_tiles(text: str) -> dict[str, dict[str, int]]:
         row = out.setdefault(tag.removeprefix("kernel/"), {"calls": 0})
         row["calls"] += 1
         for k, v in tiles.items():
-            row[k] = row.get(k, 0) + v
+            # a grouped product's column block of a width (``col<width>``)
+            # is a size, the same in every call, not a count
+            row[k] = v if k.startswith("col") else row.get(k, 0) + v
     return dict(sorted(out.items()))
 
 

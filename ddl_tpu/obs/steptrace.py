@@ -61,7 +61,7 @@ from collections import defaultdict
 from contextlib import ExitStack, contextmanager, nullcontext
 
 from ddl_tpu.obs.anomaly import AnomalyMonitor
-from ddl_tpu.obs.events import EventWriter
+from ddl_tpu.obs.events import SOWN_COUNTERS, EventWriter
 
 __all__ = ["PER_STEP_PHASES", "PHASES", "StepTrace"]
 
@@ -139,14 +139,6 @@ class _CompileCounter:
                 pass
             cls._shared = counter
         return cls._shared
-
-
-# step metrics of a dropless expert layer, and of a stack with Mamba
-# layers (``ssm_state_absmax``), that the period event copies
-_SOWN_COUNTERS = (
-    "moe_local_rows", "moe_load_max_over_mean", "moe_rows_dropped", "moe_buffer_fill",
-    "ssm_state_absmax",
-)
 
 
 class StepTrace:
@@ -423,7 +415,7 @@ class StepTrace:
             # a dropless expert layer's counters and a Mamba stack's
             # largest state (lm_steps.sown_metrics), as the period's last
             # step read them; absent for every other program
-            **{k: float(metrics[k]) for k in _SOWN_COUNTERS
+            **{k: float(metrics[k]) for k in SOWN_COUNTERS
                if metrics and metrics.get(k) is not None},
         )
         self.anomaly.observe_period(

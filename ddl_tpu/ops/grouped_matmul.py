@@ -54,8 +54,12 @@ __all__ = [
 # a group is padding on average (ISSUE 27's cell: 512 rows an expert,
 # so a quarter more rows than routed).
 ROW_TILE = 256
-# Widest column block of a weight matrix (and of dw's two dims).
+# Column block of a weight matrix (and of dw's two dims) for a width that
+# divides by it.
 _COL_TILE = 512
+# For a width over 512 that 512 does not divide (896 = 7 x 128, 2304 = 18 x
+# 128): the widest multiple of 128 lanes that divides it, up to this many.
+_COL_TILE_128 = 1152
 
 
 def buffer_rows(choices: int, groups: int, tile: int = ROW_TILE) -> int:
@@ -90,9 +94,14 @@ def align_groups(counts, num_tiles: int, tile: int = ROW_TILE):
 def _col_tile(n: int) -> int:
     if n <= _COL_TILE:
         return n
-    if n % _COL_TILE:
-        raise ValueError(f"a grouped product's width {n} must divide by {_COL_TILE}")
-    return _COL_TILE
+    if n % _COL_TILE == 0:
+        return _COL_TILE
+    if n % 128:
+        raise ValueError(
+            f"a grouped product's width {n} must be a multiple of 128 lanes "
+            f"(a width over {_COL_TILE} goes through in column blocks)"
+        )
+    return max(t for t in range(128, _COL_TILE_128 + 1, 128) if n % t == 0)
 
 
 def _gmm_kernel(tg_ref, ts_ref, na_ref, x_ref, w_ref, o_ref, *, transpose_w):
@@ -137,7 +146,8 @@ def _gmm(x, w, tile_group, tile_src, n_active, *, tile, transpose_w,
         ),
         interpret=interpret,
         name=name,
-        metadata=_tiles_metadata(num_tiles * (n // tn), w.shape[0] * (n // tn)),
+        metadata=_tiles_metadata(num_tiles * (n // tn), w.shape[0] * (n // tn),
+                                 **_col_metadata(n, tn)),
     )(tile_group, tile_src, n_active, x, w)
 
 
@@ -186,17 +196,27 @@ def _tgmm(x, dy, tile_group, tile_src, n_active, *, groups, tile, interpret):
         interpret=interpret,
         name="moe_gmm_dw",
         metadata=_tiles_metadata(
-            num_tiles * (k // tk) * (n // tn), groups * (k // tk) * (n // tn)
+            num_tiles * (k // tk) * (n // tn), groups * (k // tk) * (n // tn),
+            **_col_metadata(k, tk), **_col_metadata(n, tn),
         ),
     )(tile_group, tile_src, n_active, x, dy)
 
 
-def _tiles_metadata(total: int, floor: int) -> dict:
+def _tiles_metadata(total: int, floor: int, **more: int) -> dict:
     """What ``obs/scope.kernel_tiles`` sums out of the compiled step: the
     grid steps the buffer's worst case gives the call and the fewest any
     routing leaves it (one row tile a group).  The steps really computed
     are the routing's and are not known to the program's text."""
-    return {"tiles_total": str(total), "tiles_floor": str(floor)}
+    return {"tiles_total": str(total), "tiles_floor": str(floor),
+            **{f"tiles_{k}": str(v) for k, v in more.items()}}
+
+
+def _col_metadata(n: int, block: int) -> dict:
+    """The column block chosen for a width the 128-lane rule cut
+    (``col<width>``, which ``kernel_tiles`` keeps and does not sum: a
+    width has one block in every call); a width whose block is the
+    constant's says nothing new and keeps the text it had."""
+    return {f"col{n}": block} if n > _COL_TILE and n % _COL_TILE else {}
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))

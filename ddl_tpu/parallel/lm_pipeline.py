@@ -1267,7 +1267,7 @@ def make_lm_pipeline_step_fns(
         raise ValueError(
             f"num_experts {cfg.num_experts} % mesh expert={spec.expert} != 0"
         )
-    if not cfg.layers_alike or cfg.moe_router == "sigmoid" or cfg.tie_embeddings:
+    if not cfg.layers_alike or cfg.moe_dropless or cfg.tie_embeddings:
         # one block module runs every layer of a stage over stacked
         # parameters, so layers that differ would all become layer 0; the
         # stages collect nothing sown, so a dropless layer's counters
@@ -1277,7 +1277,7 @@ def make_lm_pipeline_step_fns(
             "the pipeline stacks one block for all layers, carries no "
             "sown counters and holds embedding and head on different "
             "stages: layer_types, num_dense_layers, the dropless "
-            "('sigmoid') expert layer and tie_embeddings are built for "
+            "expert layer and tie_embeddings are built for "
             "make_lm_step_fns only"
         )
     mesh = build_lm_mesh(spec, devices)

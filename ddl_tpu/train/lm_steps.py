@@ -185,7 +185,7 @@ def moe_router_metrics(intermediates) -> dict:
     routing collapse."""
     drops, loads = [], []
     dropless = {"moe_local_rows": [], "moe_load_max_over_mean": [],
-                "moe_rows_dropped": [], "moe_buffer_fill": []}
+                "moe_rows_dropped": [], "moe_buffer_fill": [], "moe_topk_mass": []}
     for path, leaf in jax.tree_util.tree_leaves_with_path(intermediates):
         name = jax.tree_util.keystr(path)
         if "moe_drop_frac" in name:
@@ -199,14 +199,19 @@ def moe_router_metrics(intermediates) -> dict:
         # the dropless layers' counters: token-choices that landed on
         # held experts (all layers, a step), the worst layer's load of
         # its busiest held expert over the mean, rows not computed (0),
-        # the share of the sorted buffer's row tiles in use (mean layer)
-        return {
+        # the share of the sorted buffer's row tiles in use (mean layer);
+        # under softmax scores also the share of the softmax's mass that
+        # the chosen experts hold before normalisation (mean layer)
+        out = {
             "moe_local_rows": jnp.stack(dropless["moe_local_rows"]).sum(),
             "moe_load_max_over_mean": jnp.stack(
                 dropless["moe_load_max_over_mean"]).max(),
             "moe_rows_dropped": jnp.stack(dropless["moe_rows_dropped"]).sum(),
             "moe_buffer_fill": jnp.stack(dropless["moe_buffer_fill"]).mean(),
         }
+        if dropless["moe_topk_mass"]:
+            out["moe_topk_mass"] = jnp.stack(dropless["moe_topk_mass"]).mean()
+        return out
     if not drops:
         return {}
     load = jnp.stack(loads).mean(0)

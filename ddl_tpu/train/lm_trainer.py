@@ -243,7 +243,7 @@ class LMTrainer(BaseTrainer):
         capacity_factor_min docs for the measured warm-up/steady-state
         numbers."""
         cfg = self.cfg
-        if not cfg.num_experts or cfg.moe_router == "sigmoid":
+        if not cfg.num_experts or cfg.moe_dropless:
             return  # no experts, or a dropless layer: no capacity to anneal
         target = min(cfg.capacity_factor_min, cfg.capacity_factor)
         if cfg.capacity_factor <= target:

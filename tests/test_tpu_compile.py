@@ -195,10 +195,44 @@ def test_grouped_matmul_fwd_and_both_grads(compile_for_chip, k, n):
     assert got["moe_gmm_dw"]["total"] == tiles * (k // 512) * (n // 512)
 
 
-def test_moe_row_kernels_fwd_and_grads(compile_for_chip, monkeypatch):
+@pytest.mark.parametrize(
+    "k,n", [pytest.param(2304, 896, id="gate-up"), pytest.param(896, 2304, id="down")]
+)
+def test_grouped_matmul_at_widths_512_does_not_divide(compile_for_chip, k, n):
+    """The same products at the Mellum2 cell's widths, 896 = 7 x 128 and
+    2304 = 18 x 128, in column blocks of a multiple of 128 lanes: 16 held
+    experts, the buffer of 8192 x 8 choices' worst case; each kernel says
+    the block it took of each such width."""
+    from ddl_tpu.obs.scope import kernel_tiles
+    from ddl_tpu.ops.grouped_matmul import ROW_TILE, _col_tile, buffer_rows, grouped_matmul
+
+    rows = buffer_rows(8192 * 8, 16)
+    tiles = rows // ROW_TILE
+    idx = _s((tiles,), jnp.int32)
+    bk, bn = k // _col_tile(k), n // _col_tile(n)
+    col = lambda w: {f"col{w}": _col_tile(w)}  # noqa: E731
+
+    def product(x, w, tg, ts, na):
+        return grouped_matmul(x, w, tg, ts, na, interpret=False)
+
+    args = (_s((rows, k), BF16), _s((16, k, n), F32), idx, idx, _s((1,), jnp.int32))
+    fwd = kernel_tiles(compile_for_chip(product, *args))
+    assert fwd == {"moe_gmm_fwd": {"calls": 1, "total": tiles * bn, "floor": 16 * bn, **col(n)}}
+    text = compile_for_chip(
+        jax.grad(lambda x, w, *t: _sum_f32(product(x, w, *t)), argnums=(0, 1)), *args
+    )
+    got = kernel_tiles(text)
+    assert got["moe_gmm_dx"] == {"calls": 1, "total": tiles * bk, "floor": 16 * bk, **col(k)}
+    assert got["moe_gmm_dw"] == {"calls": 1, "total": tiles * bk * bn, "floor": 16 * bk * bn,
+                                 **col(k), **col(n)}
+
+
+@pytest.mark.parametrize("held,d", [(8, 2048), (16, 2304)], ids=["trinity", "mellum2"])
+def test_moe_row_kernels_fwd_and_grads(compile_for_chip, monkeypatch, held, d):
     """The dropless shuffle's four row kernels at the Trinity-Mini cell's
-    shapes: a 67,584 x 2,048 bf16 buffer, 8,192 tokens, top-8, 8 held
-    experts; what each says of its grid in ``kernel_tiles``."""
+    shapes (a 67,584 x 2,048 bf16 buffer, 8 held experts) and at the
+    Mellum2 cell's (69,632 x 2,304, 16 held): 8,192 tokens, top-8; what
+    each says of its grid in ``kernel_tiles``."""
     from ddl_tpu.models.transformer import _rows_combine, _rows_gather, dropless_plan
     from ddl_tpu.obs.scope import kernel_tiles
     from ddl_tpu.ops import moe_rows
@@ -208,9 +242,9 @@ def test_moe_row_kernels_fwd_and_grads(compile_for_chip, monkeypatch):
     # the layer's own entry points resolve the backend: here that is the CPU
     monkeypatch.setattr(moe_rows, "interpret_default", lambda: False)
 
-    tokens, k, held, d = 8192, 8, 8, 2048
+    tokens, k = 8192, 8
     rows = buffer_rows(tokens * k, held)
-    assert rows == 67584
+    assert rows == {8: 67584, 16: 69632}[held]
     token_tiles = tokens // ROW_TILE
     pairs = pairs_bound(rows // ROW_TILE, token_tiles, held)
 

@@ -1319,33 +1319,38 @@ def _row_weights(w, plan):
 
 
 @jax.custom_vjp
-def _rows_combine(o, w, plan):
+def _rows_combine(o, w, plan, shared=None):
     """``y[t] = sum_k w[t, k] * o[choice_row[t, k]]`` over the held
-    choices, in float32.  ``o``'s gradient is a gather through the
-    inverse map, 0 on rows that hold no choice; ``w``'s is each held
-    choice's row of ``o`` against the token's cotangent."""
+    choices, plus ``shared[t]`` (the shared experts' output, (tokens, D))
+    where there is one, all in float32 and cast to ``o``'s type once: the
+    result leaves in the compute type, so its cotangent arrives in it.
+    ``o``'s gradient is a gather through the inverse map, 0 on rows that
+    hold no choice; ``w``'s is each held choice's row of ``o`` against the
+    token's cotangent; ``shared``'s is the cotangent itself."""
     from ddl_tpu.ops.moe_rows import rows_combine
 
     return rows_combine(
         o, plan["pairs"], tokens=w.shape[0], groups=plan["counts"].shape[0],
-        out_dtype=jnp.float32, weights=_row_weights(w, plan),
+        out_dtype=o.dtype, weights=_row_weights(w, plan), add=shared,
     )
 
 
-def _rows_combine_fwd(o, w, plan):
-    return _rows_combine(o, w, plan), (o, w, plan)
+def _rows_combine_fwd(o, w, plan, shared):
+    # ``shared`` rides along for its presence only: the backward reads no
+    # value of it, so a compiled step keeps nothing alive for it
+    return _rows_combine(o, w, plan, shared), (o, w, plan, shared)
 
 
 def _rows_combine_bwd(res, g):
     from ddl_tpu.ops.moe_rows import rows_gather
 
-    o, w, plan = res
+    o, w, plan, shared = res
     do, dots = rows_gather(
         g, plan["pairs"], groups=plan["counts"].shape[0], out_dtype=o.dtype,
         scale=_row_weights(w, plan), dot_with=o, name="moe_rows_combine_bwd",
     )
     dw = jnp.where(plan["held"], jnp.take(dots.reshape(-1), plan["choice_row"]), 0.0)
-    return do, dw.astype(w.dtype), None
+    return do, dw.astype(w.dtype), None, None if shared is None else g
 
 
 _rows_combine.defvjp(_rows_combine_fwd, _rows_combine_bwd)
@@ -1671,13 +1676,14 @@ class MoeMlp(nn.Module):
         with jax.named_scope("moe/experts"):
             h = _swiglu(grouped_matmul(xs, wg, *tiles), grouped_matmul(xs, wi, *tiles))
             o = grouped_matmul(h, wo, *tiles)
-        with jax.named_scope("moe/combine"):
-            y = _rows_combine(o, weights, plan)
+        shared = None
         with jax.named_scope("moe/shared"):
             if cfg.num_shared_experts:
-                y = y + Mlp(cfg, f * cfg.num_shared_experts, name="shared")(
+                shared = Mlp(cfg, f * cfg.num_shared_experts, name="shared")(
                     x.astype(dt)
-                ).reshape(b * t, d).astype(jnp.float32)
+                ).reshape(b * t, d)
+        with jax.named_scope("moe/combine"):
+            y = _rows_combine(o, weights, plan, shared)
         counts = plan["counts"]
         rows = counts.sum()
         self.sow("intermediates", "moe_local_rows", rows.astype(jnp.float32))
@@ -1697,8 +1703,9 @@ class MoeMlp(nn.Module):
             "intermediates", "moe_rows_dropped",
             (rows - plan["row_valid"].sum()).astype(jnp.float32),
         )
-        y = y.astype(dt).reshape(b, t, d)
-        return nn.with_logical_constraint(y, ("batch", "act_seq", "act_embed"))
+        return nn.with_logical_constraint(
+            y.reshape(b, t, d), ("batch", "act_seq", "act_embed")
+        )
 
 
 class Block(nn.Module):

@@ -652,11 +652,18 @@ def render_hbm(account: dict, job_id: str = "") -> str:
                         f"{v} of {k[3:]}" for k, v in sorted(n.items())
                         if k.startswith("col")
                     )
+                    # a row kernel's MXU products a (row tile, token tile)
+                    # pair: 1 for bf16 operands, 3 for float32's addends
+                    passes = (
+                        f", {n['passes'] / max(n.get('calls', 0), 1):g} product(s) a pair"
+                        if "passes" in n else ""
+                    )
                     lines.append(
                         f"    tiles {kernel}: {n.get('calls')} call(s), "
                         f"{n.get('total')} grid steps at most, "
                         f"{n.get('floor')} at least"
                         + (f", column blocks {cols}" if cols else "")
+                        + passes
                     )
                     continue
                 share = 100.0 * n.get("computed", 0) / max(n.get("total", 0), 1)

@@ -13,16 +13,19 @@ extent must be a multiple of 8), so a row is not fetched: it is **selected
 by the MXU**.  A row tile of the buffer belongs to one expert and holds its
 choices in token order, so the tokens it draws on lie in a few *token
 tiles*; ``pair_plan`` lists every (row tile, token tile) pair that shares a
-row, and a kernel multiplies, pair by pair, a 0/1 (or weighted) selection
-matrix built from the row tile's token numbers with the other side's
-block:
+row, and a kernel multiplies, pair by pair, a 0/1 selection matrix built
+from the row tile's token numbers with the other side's block:
 
     row side     out[rows of i] += sel(i, j)^T @ src[tokens of j]
     token side   out[tokens of j] += sel(i, j) @ src[rows of i]
 
-A 0/1 selection of bf16 rows is exact; float32 operands are split into
-three bf16 addends, so what is summed is the float32 product and the sums
-are float32 (the same mathematics as a gather and a sum over the choices).
+A 0/1 selection of bf16 rows is exact: one MXU product a pair.  Float32
+never rides through the selection: a float32 operand is split into three
+bf16 addends (three products a pair, the rows exact again), and a float32
+weight is applied beside the selection, on the vector unit (the routing
+weight a token after the token side's product, the row's scale at the row
+side's close).  What is summed is the float32 product and the sums are
+float32 (the same mathematics as a gather and a sum over the choices).
 Pairs number at most ``groups * (token tiles - 1) + row tiles`` and, in
 use, about one a held expert a token tile plus one a filled row tile: the
 grid is sized for the most, a step past the pairs in use maps every block
@@ -34,6 +37,8 @@ an active tile select nothing and are written as zeros.
     ``moe_rows_gather``        xs[r] = x[token of r]
     ``moe_rows_gather_bwd``    dx[t] = sum over t's held choices of g[row]
     ``moe_rows_combine``       y[t]  = sum of w[t, k] * o[row of (t, k)]
+                               (+ the shared experts' row, where there is
+                               one), leaving in the compute type
     ``moe_rows_combine_bwd``   do[r] = g[token of r] * w[r], and
                                <o[r], g[token of r]> a row (the weights'
                                gradient, gathered by the caller)
@@ -132,11 +137,19 @@ def _select(tok_ref, j, tt: int):
     return local == jax.lax.broadcasted_iota(jnp.int32, (tt, local.shape[1]), 0)
 
 
+def _passes(dtype) -> int:
+    """MXU products a pair for a selected operand of this type: one for
+    bf16, three for a float32's addends (``tiles_passes`` in a call's
+    metadata)."""
+    return 1 if dtype == jnp.bfloat16 else 3
+
+
 def _addends(x):
     """bf16 arrays that sum to ``x``: itself, or a float32's three (8 + 8
-    + 8 bits of significand; each remainder is exact in float32)."""
+    + 8 bits of significand; each remainder is exact in float32, and so is
+    the sum of the three selected, taken in this order)."""
     # the operand's type is the layer's compute type: one program a model
-    if x.dtype == jnp.bfloat16:  # ddl-lint: disable=recompile-shape-branch
+    if _passes(x.dtype) == 1:  # ddl-lint: disable=recompile-shape-branch
         return [x]
     x = x.astype(jnp.float32)
     out = []
@@ -241,16 +254,18 @@ def _gather(pi, pj, n, tok, scale, src, other, *, groups, out_dtype,
         ),
         interpret=interpret,
         name=name,
-        metadata=_tiles_metadata(steps, groups),
+        metadata=_tiles_metadata(steps, groups, passes=_passes(src.dtype)),
     )(pi, pj, n, *args)
     return out[0] if other is None else tuple(out)
 
 
-def _combine_kernel(qi_ref, qj_ref, n_ref, tok_ref, *refs, tt, weighted):
+def _combine_kernel(qi_ref, qj_ref, n_ref, tok_ref, *refs, tt, weighted, added):
     del qi_ref
     refs = list(refs)
     w_ref = refs.pop(0) if weighted else None
-    src_ref, out_ref, acc = refs
+    src_ref = refs.pop(0)
+    add_ref = refs.pop(0) if added else None
+    out_ref, acc = refs
     q = pl.program_id(0)
 
     @pl.when(q < n_ref[0])
@@ -265,45 +280,53 @@ def _combine_kernel(qi_ref, qj_ref, n_ref, tok_ref, *refs, tt, weighted):
         @pl.when(jnp.logical_not(first))
         def _():
             sel = _select(tok_ref, qj_ref[q], tt)
-            parts = _addends(src_ref[...])
-            weights = (
-                [w.astype(jnp.float32) for w in _addends(w_ref[0])] if weighted else [1.0]
-            )
-            for w in weights:
-                picked = jnp.where(sel, w, 0.0).astype(jnp.bfloat16)
-                for part in parts:
-                    acc[...] += jnp.dot(
-                        picked, part, preferred_element_type=jnp.float32
-                    )
+            picked = jnp.where(sel, 1.0, 0.0).astype(jnp.bfloat16)
+            rows = functools.reduce(jnp.add, [
+                jnp.dot(picked, part, preferred_element_type=jnp.float32)
+                for part in _addends(src_ref[...])
+            ])
+            if weighted:
+                # a token holds one row of the tile at most: its weight is
+                # the one non-zero of its line of the selection
+                rows = rows * jnp.sum(jnp.where(sel, w_ref[0], 0.0), axis=1, keepdims=True)
+            acc[...] += rows
 
         @pl.when(last)
         def _():
-            out_ref[...] = acc[...].astype(out_ref.dtype)
+            total = acc[...]
+            if added:
+                total = total + add_ref[...].astype(jnp.float32)
+            out_ref[...] = total.astype(out_ref.dtype)
 
 
 @functools.partial(
     jax.jit, static_argnames=("tokens", "groups", "out_dtype", "interpret", "name")
 )
-def _combine(qi, qj, n, tok, w, src, *, tokens, groups, out_dtype, interpret, name):
+def _combine(qi, qj, n, tok, w, src, add, *, tokens, groups, out_dtype, interpret, name):
     _, _, tile = tok.shape
     d = src.shape[1]
     tt = token_tile(tokens, tile)
     steps = qi.shape[0]
     row_spec = pl.BlockSpec((1, 1, tile), lambda q, qi, qj, n: (qi[q], 0, 0))
+    tokens_spec = pl.BlockSpec((tt, d), lambda q, qi, qj, n: (qj[q], 0))
     in_specs, args = [row_spec], [tok]
     if w is not None:
         in_specs.append(row_spec)
         args.append(w)
     in_specs.append(pl.BlockSpec((tile, d), lambda q, qi, qj, n: (qi[q], 0)))
     args.append(src)
+    if add is not None:
+        in_specs.append(tokens_spec)
+        args.append(add)
     return pl.pallas_call(
-        functools.partial(_combine_kernel, tt=tt, weighted=w is not None),
+        functools.partial(_combine_kernel, tt=tt, weighted=w is not None,
+                          added=add is not None),
         out_shape=jax.ShapeDtypeStruct((tokens, d), out_dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(steps,),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((tt, d), lambda q, qi, qj, n: (qj[q], 0)),
+            out_specs=tokens_spec,
             scratch_shapes=[pltpu.VMEM((tt, d), jnp.float32)],
         ),
         compiler_params=pltpu.CompilerParams(
@@ -311,7 +334,8 @@ def _combine(qi, qj, n, tok, w, src, *, tokens, groups, out_dtype, interpret, na
         ),
         interpret=interpret,
         name=name,
-        metadata=_tiles_metadata(steps, tokens // tt + groups),
+        metadata=_tiles_metadata(steps, tokens // tt + groups,
+                                 passes=_passes(src.dtype)),
     )(qi, qj, n, *args)
 
 
@@ -336,17 +360,24 @@ def rows_gather(src, plan: dict, *, groups: int, out_dtype=None, scale=None,
 
 
 def rows_combine(src, plan: dict, *, tokens: int, groups: int, out_dtype,
-                 weights=None, name: str = "moe_rows_combine",
+                 weights=None, add=None, name: str = "moe_rows_combine",
                  interpret: bool | None = None):
     """``out[t] = sum over the buffer rows r that hold a choice of token t
     of weights[r] * src[r]`` (of ``src[r]`` without ``weights``), summed
-    in float32; 0 for a token with none.
+    in float32, plus ``add[t]`` (tokens, D) where given, in float32, then
+    cast to ``out_dtype`` once; without ``add``, 0 for a token with none.
 
     ``src`` (R, D), read only in row tiles that hold such a row;
-    ``weights`` (row tiles, 1, tile) float32."""
+    ``weights`` (row tiles, 1, tile) float32.  The weighted form rests on
+    **a token holding at most one row of a row tile**: the rows are
+    selected first and the token's weight, read off its line of the
+    selection, multiplies what was selected (each product of a weight and
+    a row in float32, rounded once).  A plan of ``dropless_plan`` has it:
+    a row tile belongs to one expert and ``top_k`` gives a token each
+    expert once.  The unweighted form sums whatever a tile holds."""
     if interpret is None:
         interpret = interpret_default()
     return _combine(
-        *plan["by_token"], plan["tok"], weights, src, tokens=tokens, groups=groups,
-        out_dtype=out_dtype, interpret=interpret, name=name,
+        *plan["by_token"], plan["tok"], weights, src, add, tokens=tokens,
+        groups=groups, out_dtype=out_dtype, interpret=interpret, name=name,
     )

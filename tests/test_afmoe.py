@@ -376,20 +376,47 @@ def buffer_like(plan, key, d, dtype):
     return valid_rows(plan, jax.random.normal(key, (rows, d), jnp.float32)).astype(dtype)
 
 
+# The largest |kernel - float64 sum| of the weighted combine's float32
+# result on these routings at the parent (PR 33), whose selection carried
+# the weight as three bf16 addends: a weight times a row is now rounded
+# once, so no routing may read larger.
+PARENT_COMBINE_ERROR = {
+    "f32": {"uniform": 1.92e-7, "none_held": 0.0, "one_expert": 2.19e-7,
+            "all_held": 4.55e-7, "on_the_tile": 3.51e-7},
+    "bf16": {"uniform": 1.57e-7, "none_held": 0.0, "one_expert": 2.28e-7,
+             "all_held": 4.05e-7, "on_the_tile": 2.37e-7},
+}
+
+
+def combine_in_float64(o, w, plan):
+    choice_row, held = np.asarray(plan["choice_row"]), np.asarray(plan["held"])
+    o64 = np.asarray(o.astype(jnp.float32), np.float64)
+    w64 = np.asarray(w, np.float64)
+    y = np.zeros((w.shape[0], o.shape[1]))
+    for j in range(w.shape[1]):
+        y += np.where(held[:, j, None], o64[choice_row[:, j]] * w64[:, j, None], 0.0)
+    return y
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
 @pytest.mark.parametrize("kind", ROUTINGS)
 def test_row_kernels_against_the_take_gathers(kind, dtype):
     """Values and every VJP of ``_rows_gather`` and ``_rows_combine``
     (interpret mode) against the ``jnp.take`` formulations, compared where
     a buffer row is written (an active tile): exact for the 0/1
-    selections, float32 rounding for the weighted sums."""
+    selections, float32 rounding for the weighted sums.  The combine
+    leaves in the compute type, with and without a shared addend: the
+    float32 sum cast once, its cotangent in that type against the
+    formulas fed the same values in float32."""
     plan, n, k = routing(kind)
     d = 16
+    loose = 1e-2 if dtype == jnp.bfloat16 else 1e-6
     x = jax.random.normal(jax.random.key(0), (n, d), jnp.float32).astype(dtype)
     w = jax.random.uniform(jax.random.key(1), (n, k), jnp.float32, 0.1, 1.0)
     o = buffer_like(plan, jax.random.key(2), d, dtype)
     g_rows = buffer_like(plan, jax.random.key(3), d, dtype)
-    g_tok = jax.random.normal(jax.random.key(5), (n, d), jnp.float32)
+    g_tok = jax.random.normal(jax.random.key(5), (n, d), jnp.float32).astype(dtype)
+    shared = jax.random.normal(jax.random.key(6), (n, d), jnp.float32).astype(dtype)
     tile = plan["pairs"]["tok"].shape[-1]
     written = jnp.repeat(jnp.arange(o.shape[0] // tile) < plan["n_active"][0], tile)[:, None]
     assert bool((plan["row_valid"][:, None] <= written).all())
@@ -400,18 +427,46 @@ def test_row_kernels_against_the_take_gathers(kind, dtype):
     (dx,) = gather_vjp(g_rows)
     assert dx.dtype == dtype
     np.testing.assert_allclose(dx.astype(jnp.float32), take_gather_bwd(g_rows, plan).astype(jnp.float32),
-                               rtol=1e-2 if dtype == jnp.bfloat16 else 1e-6, atol=1e-6)
+                               rtol=loose, atol=1e-6)
 
-    y, combine_vjp = jax.vjp(lambda o, w: transformer._rows_combine(o, w, plan), o, w)
-    assert y.dtype == jnp.float32
-    np.testing.assert_allclose(y, take_combine(o, w, plan), rtol=1e-6, atol=1e-6)
-    do, dw = combine_vjp(g_tok)
-    want_do, want_dw = take_combine_bwd(o, w, plan, g_tok)
-    assert do.dtype == dtype and dw.dtype == w.dtype
-    np.testing.assert_allclose(jnp.where(written, do, 0).astype(jnp.float32),
-                               want_do.astype(jnp.float32),
-                               rtol=1e-2 if dtype == jnp.bfloat16 else 1e-6, atol=1e-6)
-    np.testing.assert_allclose(dw, want_dw, rtol=1e-5, atol=1e-5)
+    # the float32 sum itself, as the kernel hands it over when asked to
+    y32 = moe_rows.rows_combine(
+        o, plan["pairs"], tokens=n, groups=plan["counts"].shape[0], out_dtype=jnp.float32,
+        weights=transformer._row_weights(w, plan))
+    np.testing.assert_allclose(y32, take_combine(o, w, plan), rtol=1e-6, atol=1e-6)
+    error = float(np.abs(np.asarray(y32, np.float64) - combine_in_float64(o, w, plan)).max())
+    assert error <= PARENT_COMBINE_ERROR["bf16" if dtype == jnp.bfloat16 else "f32"][kind]
+
+    want_do, want_dw = take_combine_bwd(o, w, plan, g_tok.astype(jnp.float32))
+    for add in ((), (shared,)):
+        y, combine_vjp = jax.vjp(lambda o, w, *add: transformer._rows_combine(o, w, plan, *add), o, w, *add)
+        assert y.dtype == dtype
+        want_y = take_combine(o, w, plan) + sum(a.astype(jnp.float32) for a in add)
+        if not add:
+            np.testing.assert_array_equal(y, y32.astype(dtype))  # one cast, of that sum
+        np.testing.assert_allclose(y.astype(jnp.float32), want_y.astype(dtype).astype(jnp.float32),
+                                   rtol=loose, atol=1e-6)
+        do, dw, *dadd = combine_vjp(g_tok)
+        assert do.dtype == dtype and dw.dtype == w.dtype
+        np.testing.assert_allclose(jnp.where(written, do, 0).astype(jnp.float32),
+                                   want_do.astype(jnp.float32), rtol=loose, atol=1e-6)
+        np.testing.assert_allclose(dw, want_dw, rtol=1e-5, atol=1e-5)
+        for da in dadd:  # the shared experts' cotangent is the token's own
+            np.testing.assert_array_equal(da, g_tok)
+
+
+@pytest.mark.parametrize("kind", ROUTINGS)
+def test_a_token_holds_at_most_one_row_of_a_row_tile(kind):
+    """What the weighted combine rests on (``moe_rows.rows_combine``): it
+    selects a tile's rows and then multiplies by the token's one weight
+    there, so a token with two rows in a tile would be weighed wrongly."""
+    plan, _, _ = routing(kind)
+    tok = np.asarray(plan["pairs"]["tok"])
+    tok = tok.reshape(-1, tok.shape[-1])
+    assert (tok >= 0).sum() == int(plan["row_valid"].sum())
+    for row_tile in tok:
+        held = row_tile[row_tile >= 0]
+        assert len(np.unique(held)) == len(held)
 
 
 @pytest.mark.parametrize("kind", ROUTINGS)

@@ -476,7 +476,9 @@ def test_obs_hbm_prints_the_events_scope_counts_and_file(tmp_path):
            kernel_tiles={"flash_fwd": {"calls": 12, "computed": 23040, "masked": 9216, "total": 36864,
                                        "row_steps": 2359296},
                          "flash_bwd_dkv": {"calls": 12, "computed": 23040, "masked": 9216, "total": 36864},
-                         "moe_rows_gather": {"calls": 4, "total": 2048, "floor": 32}})
+                         "moe_rows_gather": {"calls": 4, "total": 2048, "floor": 32},
+                         "moe_rows_combine": {"calls": 4, "total": 2176, "floor": 160, "passes": 4},
+                         "moe_rows_combine_bwd": {"calls": 4, "total": 2048, "floor": 32, "passes": 12}})
     w.emit("hbm_plan", label="eval_step", analysis="aval", argument_bytes=64, output_bytes=8)
     w.emit("hbm_sample", params_bytes=600, watermark=2000, peak=2000, limit=4096, synthetic=True)
     w.close()
@@ -491,8 +493,15 @@ def test_obs_hbm_prints_the_events_scope_counts_and_file(tmp_path):
     assert ("tiles flash_bwd_dkv: 12 call(s), 23040 of 36864 sub-tiles computed (62.5%), 9216 masked\n"
             in out)
     # a kernel whose steps the routing decides says its grid's two ends
-    assert "tiles moe_rows_gather: 4 call(s), 2048 grid steps at most, 32 at least" in out
-    assert out.count("    tiles ") == 3
+    # (a record from before the row kernels counted their products: no more)
+    assert "tiles moe_rows_gather: 4 call(s), 2048 grid steps at most, 32 at least\n" in out
+    # and its MXU products a pair, ``passes`` over ``calls``: 1 in bf16, 3
+    # for a float32's addends
+    assert ("tiles moe_rows_combine: 4 call(s), 2176 grid steps at most, 160 at least, "
+            "1 product(s) a pair\n" in out)
+    assert ("tiles moe_rows_combine_bwd: 4 call(s), 2048 grid steps at most, 32 at least, "
+            "3 product(s) a pair" in out)
+    assert out.count("    tiles ") == 5
 
 
 # the benchmark's cases (tests/benchmark: test_trace_reduction_on_a_hand_built_trace)

@@ -227,12 +227,17 @@ def test_grouped_matmul_at_widths_512_does_not_divide(compile_for_chip, k, n):
                                  **col(k), **col(n)}
 
 
-@pytest.mark.parametrize("held,d", [(8, 2048), (16, 2304)], ids=["trinity", "mellum2"])
-def test_moe_row_kernels_fwd_and_grads(compile_for_chip, monkeypatch, held, d):
+@pytest.mark.parametrize("dtype,passes", [(BF16, 1), (F32, 3)], ids=["bf16", "f32"])
+@pytest.mark.parametrize("held,d,shared", [(8, 2048, True), (16, 2304, False)],
+                         ids=["trinity", "mellum2"])
+def test_moe_row_kernels_fwd_and_grads(compile_for_chip, monkeypatch, held, d, shared, dtype, passes):
     """The dropless shuffle's four row kernels at the Trinity-Mini cell's
-    shapes (a 67,584 x 2,048 bf16 buffer, 8 held experts) and at the
-    Mellum2 cell's (69,632 x 2,304, 16 held): 8,192 tokens, top-8; what
-    each says of its grid in ``kernel_tiles``."""
+    shapes (a 67,584 x 2,048 buffer, 8 held experts, the shared experts'
+    output added in the combine) and at the Mellum2 cell's (69,632 x
+    2,304, 16 held, none): 8,192 tokens, top-8; what each says of its grid
+    in ``kernel_tiles``, and of its MXU products a (row tile, token tile)
+    pair: one where the operands are bf16, the cotangents among them (the
+    combine leaves in the compute type), three for a float32's addends."""
     from ddl_tpu.models.transformer import _rows_combine, _rows_gather, dropless_plan
     from ddl_tpu.obs.scope import kernel_tiles
     from ddl_tpu.ops import moe_rows
@@ -250,20 +255,22 @@ def test_moe_row_kernels_fwd_and_grads(compile_for_chip, monkeypatch, held, d):
 
     def shuffle(x, w, idx):
         plan = dropless_plan(idx, 0, held, ROW_TILE)
-        return _rows_combine(_rows_gather(x, plan), w, plan)
+        return _rows_combine(_rows_gather(x, plan), w, plan, x if shared else None)
 
-    args = (_s((tokens, d), BF16), _s((tokens, k), F32), _s((tokens, k), jnp.int32))
-    row_side = {"calls": 1, "total": pairs, "floor": held}
-    token_side = {"calls": 1, "total": pairs + token_tiles, "floor": held + token_tiles}
+    args = (_s((tokens, d), dtype), _s((tokens, k), F32), _s((tokens, k), jnp.int32))
+    row_side = {"calls": 1, "total": pairs, "floor": held, "passes": passes}
+    token_side = {"calls": 1, "total": pairs + token_tiles, "floor": held + token_tiles,
+                  "passes": passes}
     assert kernel_tiles(compile_for_chip(shuffle, *args)) == {
         "moe_rows_combine": token_side, "moe_rows_gather": row_side}
     text = compile_for_chip(
-        jax.grad(lambda x, w, idx: _sum_f32(shuffle(x, w, idx)), argnums=(0, 1)), *args
+        # quadratic, so that the gradient needs the combine's value too
+        jax.grad(lambda x, w, idx: _sum_f32(shuffle(x, w, idx).astype(F32) ** 2), argnums=(0, 1)),
+        *args,
     )
-    # the sum's gradient does not need the combine's value: its forward is gone
     assert kernel_tiles(text) == {
-        "moe_rows_combine_bwd": row_side, "moe_rows_gather": row_side,
-        "moe_rows_gather_bwd": token_side}
+        "moe_rows_combine": token_side, "moe_rows_combine_bwd": row_side,
+        "moe_rows_gather": row_side, "moe_rows_gather_bwd": token_side}
 
 
 @pytest.mark.parametrize("kv_heads", [12, 4], ids=["fused768", "fused256"])

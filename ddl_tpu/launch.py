@@ -22,9 +22,29 @@ import os
 import jax
 
 __all__ = [
-    "bootstrap", "host_id", "restart_epoch", "world_info",
-    "force_cpu_devices",
+    "bootstrap", "host_id", "process_start_ts", "restart_epoch",
+    "world_info", "force_cpu_devices",
 ]
+
+
+def process_start_ts() -> float | None:
+    """When this process began, on ``time.time()``'s clock, by the
+    kernel's record of it: field 22 of ``/proc/self/stat`` (clock ticks
+    after boot, a hundredth of a second) against ``/proc/uptime``.  What
+    ran before any Python did is in it: the origin of ``setup.boot``
+    (``obs/steptrace.stage``).  None where ``/proc`` does not say."""
+    import time
+
+    try:
+        with open("/proc/self/stat") as f:
+            # the command's name may hold blanks and brackets; the
+            # fields after its closing bracket start at the third
+            ticks = int(f.read().rpartition(")")[2].split()[19])
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+        return time.time() - uptime + ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return None
 
 
 def restart_epoch() -> int:

@@ -204,7 +204,11 @@ class EventWriter:
         self._file = open(self.path, "a", buffering=1)
         self._spans = threading.local()  # per-thread open-span name stack
 
-    def emit(self, kind: str, step: int | None = None, **fields) -> dict:
+    def emit(
+        self, kind: str, step: int | None = None, *, at: float | None = None, **fields
+    ) -> dict:
+        """Write one event, stamped now; ``at`` (a ``time.time()`` in the
+        past) stamps it then instead, ``mono`` moved back alike."""
         if kind not in EVENT_KINDS and kind not in _warned_kinds:
             # warn (once per kind), don't drop: ad-hoc kinds in probes/
             # tests still flow, but anything shipping in the package is
@@ -216,9 +220,10 @@ class EventWriter:
                 "name will not see it",
                 stacklevel=2,
             )
+        now = time.time()
         event = {
-            "ts": time.time(),
-            "mono": time.monotonic(),
+            "ts": now if at is None else at,
+            "mono": time.monotonic() - (0.0 if at is None else now - at),
             "run": self.run_id,
             "host": self.host,
             "step": step,
@@ -256,6 +261,22 @@ class EventWriter:
                 "span", step=step, name=name, dur=dur,
                 parent=parent, depth=len(stack), **fields,
             )
+
+    def span_at(
+        self, name: str, start: float, end: float, step: int | None = None, **fields
+    ) -> dict:
+        """One ``span`` event for a region that was timed elsewhere on
+        ``time.time()``'s clock (a compile that ``jax.monitoring``
+        reports, a stage that ran before this stream was open): ``ts`` is
+        its end and ``dur`` its length, as every span.  ``parent`` /
+        ``depth`` are this thread's open spans' unless the caller, who
+        knew the nesting when the region ran, hands them."""
+        stack = getattr(self._spans, "stack", None) or ()
+        fields.setdefault("parent", stack[-1] if stack else None)
+        fields.setdefault("depth", len(stack))
+        return self.emit(
+            "span", step=step, at=end, name=name, dur=end - start, **fields
+        )
 
     def close(self) -> None:
         with self._lock:

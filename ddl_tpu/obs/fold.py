@@ -78,9 +78,14 @@ SIDECAR_NAME = ".obs_fold.json"
 # peak-watermark category breakdown off hbm_sample, bounded last-wins
 # static plans off hbm_plan, and the hbm_oom_dump forensic cell —
 # obs/hbm.py renders the account); v11 adds the per-host last-wins map of
-# the model's sown step counters off the period event (events.SOWN_COUNTERS)
+# the model's sown step counters off the period event
+# (events.SOWN_COUNTERS); v12 adds the start-up account to the goodput
+# reducer (the seconds of the ``setup.*`` spans, the bucket ``startup``;
+# the incarnation's window begins where its ``setup.boot`` does; the
+# set-up line's sums by stage and of the ``compile.*`` spans before the
+# first period)
 # — older sidecars rebuild cleanly
-VERSION = 11
+VERSION = 12
 
 # the serving-cursor sidecar this module's cache superseded; removed
 # opportunistically when the fold sidecar is written so a job dir does
@@ -175,6 +180,12 @@ def _new_goodput() -> dict:
         "decision_ts": None,  # earliest restart decision INTO this repoch
         "phases": {}, "compile_s": 0.0, "restore_s": 0.0,
         "stall_s": 0.0, "gap_s": 0.0, "rolled_back_s": 0.0,
+        # start-up: seconds under ``setup.*`` spans (outside every phase
+        # by construction); the last relaunch gap charged, [from, to],
+        # which a new process's set-up spans take their part back from;
+        # and the set-up line: seconds by stage, and of the ``compile.*``
+        # spans heard before the incarnation's first period
+        "startup_s": 0.0, "gap_at": None, "setup": {}, "started": False,
         "serve_t0": None, "serve_t1": None,
         "periods": {}, "await_bad": None,
         # per-tenant chip-second split of the serving window: sums of
@@ -356,6 +367,7 @@ class StreamFold:
                     # the same repoch (single-host supervised relaunch):
                     # the dead time is restart gap, not untracked
                     g["gap_s"] += ts - g["last_ts"]
+                    g["gap_at"] = [g["last_ts"], ts]
                 if g["first_ts"] is None or ts < g["first_ts"]:
                     g["first_ts"] = ts
                 if g["last_ts"] is None or ts > g["last_ts"]:
@@ -372,6 +384,8 @@ class StreamFold:
                     self.span_sums.get(name, 0.0) + e.get("dur", 0.0)
                 )
             self._track_step(rec, step)
+            if g is not None and ts is not None:
+                self._consume_setup_span(g, e, ts)
         elif kind == "heartbeat":
             self._track_step(rec, step)
         elif kind == "stall":
@@ -590,6 +604,37 @@ class StreamFold:
                 {k: v for k, v in e.items() if k != "stacks"},
             )
 
+    def _consume_setup_span(self, g: dict, e: dict, ts: float) -> None:
+        """The start-up account of one incarnation.  A ``setup.*`` span
+        is start-up seconds, and it may begin before the stream's first
+        event (``setup.boot`` begins with the process): the incarnation's
+        window and the stream's span reach back to it, and where a
+        relaunch gap was charged up to this process's ``run_start`` the
+        span takes its part of that gap back, so no second is booked
+        twice.  ``compile.*`` spans before the first period are the
+        set-up line's compile sums (after it they are recompiles, which
+        ``period.compile_s`` carries)."""
+        name, dur = str(e.get("name", "")), float(e.get("dur", 0.0) or 0.0)
+        setup = g["setup"]
+        if name.startswith("setup."):
+            start = ts - dur
+            g["startup_s"] += dur
+            setup[name[6:]] = setup.get(name[6:], 0.0) + dur
+            if start < g["first_ts"]:
+                g["first_ts"] = start
+            if start < self.all_span[0]:
+                self.all_span[0] = start
+            gap = g["gap_at"]
+            if gap is not None:
+                g["gap_s"] -= max(0.0, min(ts, gap[1]) - max(start, gap[0]))
+        elif name.startswith("compile.") and not g["started"]:
+            if name == "compile.backend":
+                setup["backend"] = setup.get("backend", 0.0) + dur
+                key = "hits" if e.get("cache_hit") else "misses"
+                setup[key] = setup.get(key, 0) + 1
+            else:
+                setup["trace_lower"] = setup.get("trace_lower", 0.0) + dur
+
     @staticmethod
     def _charge_replay(g: dict, period: int, offset: int) -> None:
         """Move recorded period coverage at/beyond a resume cursor
@@ -642,6 +687,7 @@ class StreamFold:
         for name, dur in phases.items():
             g["phases"][name] = g["phases"].get(name, 0.0) + dur
         g["compile_s"] += float(e.get("compile_s", 0.0) or 0.0)
+        g["started"] = True
         step_fence = phases.get("step", 0.0) + phases.get("fence", 0.0)
         p = e.get("period")
         if p is not None:

@@ -36,11 +36,20 @@ Bucket taxonomy (``CATEGORIES``; seconds, per incarnation):
     restart_gap   relaunch decision -> first event of the incarnation
                   (minus the barrier wait inside it), plus dead gaps
                   between same-repoch attempts
+    startup       the incarnation's ``setup.*`` spans (``obs/steptrace``):
+                  from the process's start to its first trainer stage
+                  (``setup.boot``), building the model and its state, the
+                  data, and each program's ``hbm_plan``.  They lie outside
+                  every phase; the incarnation's window begins where its
+                  ``setup.boot`` does, so the seconds before the stream's
+                  first event are inside the wall they are carved from
     serve         serving activity window (decode requests)
     other         phase names outside the fixed vocabulary
     untracked     the residual — wall minus everything above.  Reported,
                   never dropped: it is what keeps the ledger honest
-                  (process boot, model build, import time, idle gaps).
+                  (what a start does between its stages, idle gaps; process
+                  boot, imports and model build too in a stream from
+                  before the ``setup.*`` spans).
 
 Precedence for overlapping attributions (documented contract, see
 ARCHITECTURE.md "Goodput accounting"): within step+fence time,
@@ -74,7 +83,7 @@ __all__ = [
 CATEGORIES = (
     "productive", "data_wait", "h2d", "recompile", "bubble",
     "rolled_back", "checkpoint", "eval", "logging", "stall", "barrier",
-    "restart_gap", "serve", "other", "untracked",
+    "restart_gap", "startup", "serve", "other", "untracked",
 )
 
 # period-event phase names with a dedicated bucket; step+fence form the
@@ -124,6 +133,7 @@ def _incarnation_account(
     barrier = min(max(0.0, barrier_s), pre_gap) if pre_gap else 0.0
     sec["barrier"] = barrier
     sec["restart_gap"] = (pre_gap - barrier) + g.get("gap_s", 0.0)
+    sec["startup"] = g.get("startup_s", 0.0)
     if g.get("serve_t0") is not None and g.get("serve_t1") is not None:
         sec["serve"] = max(0.0, g["serve_t1"] - g["serve_t0"])
 
@@ -133,6 +143,8 @@ def _incarnation_account(
         "start_ts": start, "end_ts": last, "wall_s": wall,
         "seconds": sec,
         "ratio": (sec["productive"] / wall) if wall > 0 else None,
+        # the set-up line's sums (fold.StreamFold._consume_setup_span)
+        "setup": dict(sorted((g.get("setup") or {}).items())),
         # per-tenant chip-second split inside this incarnation's serve
         # window (fold._new_tenant_goodput shape); sorted so the account
         # is byte-stable across fold resumes

@@ -474,6 +474,27 @@ def render_summary(s: dict, job_id: str = "") -> str:
             f" — `ddl_tpu obs goodput{f' {job_id}' if job_id else ''}`"
         )
         lines.append(line)
+        # the newest start the lowest host recorded (every host's is its
+        # own; the phase table reads that host too), stage by stage
+        # (setup.* spans) and compile by compile (compile.* spans before
+        # the first period); the stages hold the compiles made inside them
+        inc = max(
+            (a for a in gp["incarnations"] if a.get("setup")),
+            key=lambda a: (-a["host"], a["repoch"]), default=None,
+        )
+        if inc:
+            su = inc["setup"]
+            lines.append(
+                f"set-up (h{inc['host']}/e{inc['repoch']}): "
+                + ", ".join(
+                    f"{k} {su[k]:.2f}s"
+                    for k in ("boot", "model", "data", "plan") if k in su
+                )
+                + f" | compiles before the first period: trace+lower "
+                f"{su.get('trace_lower', 0.0):.2f}s, backend "
+                f"{su.get('backend', 0.0):.2f}s ({su.get('hits', 0)} cache "
+                f"hit(s), {su.get('misses', 0)} made)"
+            )
     rl = s.get("restart_latency")
     if rl:
         lines.append(

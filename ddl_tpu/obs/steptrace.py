@@ -49,6 +49,31 @@ with them the ``is_ready()`` calls).  Every phase and child also enters a
 besides), a flag test while no profiler session runs: a profile then holds
 the program's phases and the device's ops on one clock.
 
+Set-up has spans too, from the process's start to the first period.
+They are not phases either (no period total), and they are measured
+before a stream is open as well as after, so they go through the
+process's ``CompileLog`` (``utils/compile_cache.py``), which keeps what it
+is given until a ``StepTrace`` opens a stream and then writes through it:
+
+    setup.boot      the process's start, by the kernel's record of it, to
+                    the first stage of the first trainer: interpreter,
+                    imports, the backend's client, whatever the caller
+                    did first.  Once a process
+    setup.model     step functions, model and state initialisation
+    setup.data      datasets, corpus and loaders
+    setup.plan      one program's ``hbm_plan`` (``label``): the second
+                    lowering and compile, the compiled text, the scope
+                    tables and their file (``BaseTrainer.emit_hbm_plan``)
+    compile.trace   every trace, lowering and backend compile of the
+    compile.lower   process (``fn``: JAX's name for the function), as
+    compile.backend ``jax.monitoring`` times them; the last with
+                    ``cache_hit`` and ``cache_load_s``.  Inside a stage
+                    they name it as ``parent``; inside a ``step`` phase
+                    they are a recompile with its function and its step
+
+``period.compiles`` / ``compile_s`` count the ``compile.backend`` spans
+of the period and their seconds.
+
 ``AnomalyMonitor`` rides along: every ``end_period`` feeds the rolling
 detectors, and ``finish()`` surfaces everything they caught.
 """
@@ -63,7 +88,7 @@ from contextlib import ExitStack, contextmanager, nullcontext
 from ddl_tpu.obs.anomaly import AnomalyMonitor
 from ddl_tpu.obs.events import SOWN_COUNTERS, EventWriter
 
-__all__ = ["PER_STEP_PHASES", "PHASES", "StepTrace"]
+__all__ = ["PER_STEP_PHASES", "PHASES", "StepTrace", "stage"]
 
 PHASES = (
     "data_wait",
@@ -106,39 +131,42 @@ PER_STEP_SPANS = PER_STEP_PHASES | {"collate"}
 IDLE_PHASES = frozenset({"data_wait", "h2d"})
 
 
-class _CompileCounter:
-    """Process-wide recompile counter fed by ``jax.monitoring``'s
-    backend-compile duration events.  Registered once, never removed
-    (listener registries are append-only); counts every XLA backend
-    compile after the first use, which is exactly the recompile signal
-    a steady-state training loop wants to see stay flat."""
+_booted = False
 
-    _shared = None
 
-    def __init__(self) -> None:
-        self.count = 0
-        self.secs = 0.0
+@contextmanager
+def stage(name: str, obs: "StepTrace | None" = None, **fields):
+    """A ``setup.*`` stage of a trainer's start: a span through the
+    process's ``CompileLog`` (written now if a stream is open, else kept
+    for the next one) and a ``TraceAnnotation`` of the same name.  ``obs``
+    is the trainer's own trace, None while it has opened no stream: its
+    start is then kept for the stream it will open, whatever stream an
+    earlier trainer of the process left open.  The process's first stage
+    also writes ``setup.boot``, from the process's start to this stage's."""
+    global _booted
+    from jax.profiler import TraceAnnotation
 
-    @classmethod
-    def shared(cls) -> "_CompileCounter":
-        if cls._shared is None:
-            counter = cls()
-            try:
-                from jax import monitoring
+    from ddl_tpu.utils.compile_cache import compile_log
 
-                def _on_duration(event, duration, **kw):
-                    if "backend_compile" in event:
-                        counter.count += 1
-                        counter.secs += duration
+    log = compile_log()
+    if obs is None:
+        log.detach()
+    t0 = time.time()
+    if not _booted:
+        _booted = True
+        from ddl_tpu.launch import process_start_ts
 
-                monitoring.register_event_duration_secs_listener(_on_duration)
-            except (ImportError, AttributeError):
-                # no jax.monitoring on this runtime: the recompile
-                # counter stays at 0 — observability degrades, the run
-                # doesn't
-                pass
-            cls._shared = counter
-        return cls._shared
+        born = process_start_ts()
+        if born is not None:
+            log.record("setup.boot", min(born, t0), t0)
+    open_stages = log.stages()
+    with TraceAnnotation(name):
+        open_stages.append(name)
+        try:
+            yield
+        finally:
+            open_stages.pop()
+            log.record(name, t0, time.time(), **fields)
 
 
 class StepTrace:
@@ -174,7 +202,14 @@ class StepTrace:
         # (phase totals, throughput, anomalies) always flow.
         self.emit_step_spans = int(emit_step_spans)
         self.watchdog = None
-        self._compiles = _CompileCounter.shared()
+        from ddl_tpu.utils.compile_cache import compile_log
+
+        # the process's compiles: the counters a period's ``compiles`` /
+        # ``compile_s`` are differences of, and the ``compile.*`` and
+        # ``setup.*`` spans, which a stream that ``create`` opened writes
+        self._compiles = compile_log()
+        self._takes_spans = False
+        self._open_step = None  # the open phase's step, for those spans
         self._period_compiles = self._compiles.count
         self._period_compile_s = self._compiles.secs
         self._totals: dict[str, float] = defaultdict(float)
@@ -238,7 +273,12 @@ class StepTrace:
             writer,
             writer.path.parent / "xprof" / f"h{writer.host:03d}",
         )
-        return cls(writer, emit_step_spans=emit_step_spans, capturer=capturer)
+        trace = cls(writer, emit_step_spans=emit_step_spans, capturer=capturer)
+        # a trainer's stream: it takes the process's set-up and compile
+        # spans from now on, what was heard before it opened first
+        trace._takes_spans = True
+        trace._compiles.attach(trace)
+        return trace
 
     def _span_due(self, name: str, step: int | None) -> bool:
         """The 1-in-N step-span sampler.  Only per-step phases are
@@ -252,6 +292,14 @@ class StepTrace:
         if n == 1 or step is None or name.partition(".")[0] not in PER_STEP_SPANS:
             return True
         return step % n == 0
+
+    def heard(self, name: str, start: float, end: float, fields: dict) -> None:
+        """The ``CompileLog``'s sink: one ``setup.*`` or ``compile.*``
+        span, timed where it happened.  Written unless per-step spans are
+        off altogether; a compile inside a phase carries that phase's
+        step."""
+        if self.emit_step_spans > 0:
+            self.writer.span_at(name, start, end, step=self._open_step, **fields)
 
     # ------------------------------------------------------------------
     # the idle account
@@ -320,6 +368,7 @@ class StepTrace:
             self.capturer.on_step(step)
         t0 = time.perf_counter()
         completed = False
+        self._open_step = step
         try:
             with ExitStack() as stack:
                 if name == "step" and step is not None:
@@ -331,6 +380,7 @@ class StepTrace:
                 yield
             completed = True
         finally:
+            self._open_step = None
             dur = time.perf_counter() - t0
             self._totals[name] += dur
             self.run_totals[name] += dur
@@ -359,6 +409,10 @@ class StepTrace:
             # so run_end consumers don't attribute it to the previous one
             self.writer.emit("run_start", resumed=True)
             self._needs_run_start = False
+        if self._takes_spans and not self._compiles.attached(self):
+            # a stream closed by finish(), or taken over by a later
+            # trainer's start: the trainer that trains has the spans
+            self._compiles.attach(self)
         self._totals = defaultdict(float)
         self._period_compiles = self._compiles.count
         self._period_compile_s = self._compiles.secs
@@ -447,6 +501,7 @@ class StepTrace:
             print(f"[obs] {len(anomalies)} anomalies detected this run:")
             for line in self.anomaly.summary_lines():
                 print(f"[obs]   {line}")
+        self._compiles.detach(self)  # a closed stream takes no span
         self.writer.close()
         # reset per-run state so a second train() on the same trainer
         # reports its own segment, not cumulative double-counted totals

@@ -35,6 +35,7 @@ import numpy as np
 
 from ddl_tpu import checkpoint as ckpt
 from ddl_tpu.models.transformer import LMConfig
+from ddl_tpu.obs.steptrace import stage
 from ddl_tpu.parallel.sharding import LMMeshSpec
 from ddl_tpu.train.lm_steps import STEP_PARTS, make_lm_step_fns
 from ddl_tpu.train.loop import BaseTrainer, _child, _phase
@@ -111,7 +112,8 @@ class LMTrainer(BaseTrainer):
         self.job_id = run.job_id
         self._rng = rng if rng is not None else jax.random.key(0)
         self.tx = tx
-        self.fns = self._make_fns(cfg)
+        with stage("setup.model", self.obs):
+            self.fns = self._make_fns(cfg)
 
         # periods end at the union of the cadences' multiples, so each
         # cadence fires exactly at its own multiples (log 10 / eval 4 ->
@@ -130,7 +132,8 @@ class LMTrainer(BaseTrainer):
         self._boundaries = sorted(bounds)
         self.num_periods = len(self._boundaries)
 
-        self._build_data()
+        with stage("setup.data", self.obs):
+            self._build_data()
 
         proc = jax.process_index()
         self.is_logging_process = proc == 0
@@ -155,7 +158,8 @@ class LMTrainer(BaseTrainer):
         self.save_best = bool(run.checkpoint_dir) and bool(run.eval_every)
         self.best_value = float("inf")
 
-        self.state = self.fns.init_state()
+        with stage("setup.model", self.obs):
+            self.state = self.fns.init_state()
         self._start_step = 0
         resume_step = ckpt.resolve_resume(
             run.checkpoint_dir, run.job_id, run.resume_step,

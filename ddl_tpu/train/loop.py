@@ -205,7 +205,9 @@ class BaseTrainer:
         ``DDL_HBM_PLAN=off`` disables, ``=aval`` keeps the cheap
         shape-arithmetic budget without the executable analysis.
         ``parts``: the family's own scopes, for the plan's second scope
-        table (``hbm.plan_program``)."""
+        table (``hbm.plan_program``).  All of it (the second lowering and
+        compile, the compiled text, the scope tables, their file) lies in
+        one ``setup.plan`` span: what the plan costs a start."""
         if self.obs is None:
             return
         if self._hbm_planned is None:
@@ -217,11 +219,13 @@ class BaseTrainer:
         if mode in ("0", "off", "false"):
             return
         from ddl_tpu.obs import hbm
+        from ddl_tpu.obs.steptrace import stage
 
-        hbm.plan_program(
-            self.obs.writer, label, fn, args, kwargs,
-            mode="aval" if mode == "aval" else "full", parts=parts,
-        )
+        with stage("setup.plan", self.obs, label=label):
+            hbm.plan_program(
+                self.obs.writer, label, fn, args, kwargs,
+                mode="aval" if mode == "aval" else "full", parts=parts,
+            )
 
     def _emit_hbm_sample(self, step=None, context=None) -> None:
         """One ``hbm_sample`` live breakdown: tracked params/optimizer

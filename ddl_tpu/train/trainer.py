@@ -31,6 +31,7 @@ from ddl_tpu import checkpoint as ckpt
 from ddl_tpu.config import Config
 from ddl_tpu.data import DataLoader, ShardedEpochSampler, build_datasets, shard_batch
 from ddl_tpu.models import build_stages, stage_boundary_shapes
+from ddl_tpu.obs.steptrace import stage
 from ddl_tpu.parallel.mesh import MeshSpec, build_mesh
 from ddl_tpu.train.loop import BaseTrainer, _child, _phase
 from ddl_tpu.train.state import create_train_state, make_optimizer
@@ -65,87 +66,89 @@ class Trainer(BaseTrainer):
         cfg.validate()
         self.cfg = cfg
         self.job_id = resolve_job_id()
-        self.mesh = mesh if mesh is not None else build_mesh(
-            MeshSpec(cfg.mesh.data, cfg.mesh.pipe)
-        )
-
-        pipelined = cfg.strategy in ("pp", "dp_pp")
-        self.stages = build_stages(cfg.model, num_stages=None if pipelined else 1)
-        self.tx = make_optimizer(cfg.train)
-        self._zero = False
-        if cfg.train.zero_sharding:
-            from ddl_tpu.train.fused_optim import with_zero
-
-            # CNN DDP params are replicated (cnn_rules: everything P()),
-            # so param_specs=None; with_zero no-ops at mesh data=1
-            self.tx = with_zero(self.tx, self.mesh)
-            self._zero = getattr(self.tx, "zero", None) is not None
-        rng = jax.random.key(cfg.train.seed)
-        self.state = create_train_state(
-            self.stages, self.tx, rng, cfg.data.image_size,
-            mesh=self.mesh if self._zero else None,
-        )
-        if cfg.model.pretrained_path:
-            from ddl_tpu.models.convert import load_torch_checkpoint
-
-            p, bs, skipped = load_torch_checkpoint(
-                cfg.model.pretrained_path, self.state.params, self.state.batch_stats
-            )
-            self.state = self.state.replace(params=p, batch_stats=bs)
-            if skipped:
-                print(f"[ddl_tpu] pretrained overlay skipped keys: {skipped}")
-        self._rebuild_step_fns()
-        self.grad_stats_fn = None
-        if cfg.train.log_gradient_stats and not pipelined:
-            from ddl_tpu.train.steps import make_grad_stats_fn
-
-            self.grad_stats_fn = make_grad_stats_fn(
-                self.stages, self.mesh, jnp.dtype(cfg.model.compute_dtype),
-                zero_sharding=self._zero,
+        with stage("setup.model", self.obs):
+            self.mesh = mesh if mesh is not None else build_mesh(
+                MeshSpec(cfg.mesh.data, cfg.mesh.pipe)
             )
 
-        train_ds, test_ds = datasets if datasets is not None else build_datasets(cfg.data)
-        # Host-level sharding (DistributedSampler analog, ddp.py:343): each
-        # process loads 1/process_count of the global batch; per-chip
-        # sharding happens on-device via NamedSharding.
-        n_proc, proc = jax.process_count(), jax.process_index()
-        if cfg.data.global_batch_size % n_proc:
-            raise ValueError("global_batch_size must divide by process count")
-        per_proc_batch = cfg.data.global_batch_size // n_proc
-        per_proc_eval = cfg.data.eval_batch_size // n_proc
-        self.train_loader = DataLoader(
-            train_ds,
-            per_proc_batch,
-            sampler=ShardedEpochSampler(
-                len(train_ds), n_proc, proc,
-                shuffle=cfg.data.shuffle, drop_last=cfg.data.drop_last,
-                seed=cfg.train.seed,
-            ),
-            num_workers=cfg.data.num_workers,
-            drop_last=cfg.data.drop_last,
-            on_retry=self._note_io_retry,
-        )
-        # Eval is deterministic and full-coverage: ordered (no shuffle), no
-        # dropped tail — sentinel padding keeps batch shapes static (one
-        # compiled eval fn) and every test sample is counted exactly once,
-        # the SPMD analog of the reference evaluating everything
-        # (single.py:199-258).  Round 1 inherited shuffle+drop_last here,
-        # which made eval metrics (and the QWK save gate) a shifting subset.
-        self.test_loader = DataLoader(
-            test_ds,
-            per_proc_eval,
-            sampler=ShardedEpochSampler(
-                len(test_ds), n_proc, proc,
-                shuffle=False, drop_last=False, pad_mode="sentinel",
-                seed=cfg.train.seed + 1,
-            ),
-            num_workers=cfg.data.num_workers,
-            drop_last=False,
-            pad_last_batch=True,
-            on_retry=self._note_io_retry,
-        )
-        if len(test_ds) == 0:
-            raise ValueError("empty eval set")
+            pipelined = cfg.strategy in ("pp", "dp_pp")
+            self.stages = build_stages(cfg.model, num_stages=None if pipelined else 1)
+            self.tx = make_optimizer(cfg.train)
+            self._zero = False
+            if cfg.train.zero_sharding:
+                from ddl_tpu.train.fused_optim import with_zero
+
+                # CNN DDP params are replicated (cnn_rules: everything P()),
+                # so param_specs=None; with_zero no-ops at mesh data=1
+                self.tx = with_zero(self.tx, self.mesh)
+                self._zero = getattr(self.tx, "zero", None) is not None
+            rng = jax.random.key(cfg.train.seed)
+            self.state = create_train_state(
+                self.stages, self.tx, rng, cfg.data.image_size,
+                mesh=self.mesh if self._zero else None,
+            )
+            if cfg.model.pretrained_path:
+                from ddl_tpu.models.convert import load_torch_checkpoint
+
+                p, bs, skipped = load_torch_checkpoint(
+                    cfg.model.pretrained_path, self.state.params, self.state.batch_stats
+                )
+                self.state = self.state.replace(params=p, batch_stats=bs)
+                if skipped:
+                    print(f"[ddl_tpu] pretrained overlay skipped keys: {skipped}")
+            self._rebuild_step_fns()
+            self.grad_stats_fn = None
+            if cfg.train.log_gradient_stats and not pipelined:
+                from ddl_tpu.train.steps import make_grad_stats_fn
+
+                self.grad_stats_fn = make_grad_stats_fn(
+                    self.stages, self.mesh, jnp.dtype(cfg.model.compute_dtype),
+                    zero_sharding=self._zero,
+                )
+
+        with stage("setup.data", self.obs):
+            train_ds, test_ds = datasets if datasets is not None else build_datasets(cfg.data)
+            # Host-level sharding (DistributedSampler analog, ddp.py:343): each
+            # process loads 1/process_count of the global batch; per-chip
+            # sharding happens on-device via NamedSharding.
+            n_proc, proc = jax.process_count(), jax.process_index()
+            if cfg.data.global_batch_size % n_proc:
+                raise ValueError("global_batch_size must divide by process count")
+            per_proc_batch = cfg.data.global_batch_size // n_proc
+            per_proc_eval = cfg.data.eval_batch_size // n_proc
+            self.train_loader = DataLoader(
+                train_ds,
+                per_proc_batch,
+                sampler=ShardedEpochSampler(
+                    len(train_ds), n_proc, proc,
+                    shuffle=cfg.data.shuffle, drop_last=cfg.data.drop_last,
+                    seed=cfg.train.seed,
+                ),
+                num_workers=cfg.data.num_workers,
+                drop_last=cfg.data.drop_last,
+                on_retry=self._note_io_retry,
+            )
+            # Eval is deterministic and full-coverage: ordered (no shuffle), no
+            # dropped tail — sentinel padding keeps batch shapes static (one
+            # compiled eval fn) and every test sample is counted exactly once,
+            # the SPMD analog of the reference evaluating everything
+            # (single.py:199-258).  Round 1 inherited shuffle+drop_last here,
+            # which made eval metrics (and the QWK save gate) a shifting subset.
+            self.test_loader = DataLoader(
+                test_ds,
+                per_proc_eval,
+                sampler=ShardedEpochSampler(
+                    len(test_ds), n_proc, proc,
+                    shuffle=False, drop_last=False, pad_mode="sentinel",
+                    seed=cfg.train.seed + 1,
+                ),
+                num_workers=cfg.data.num_workers,
+                drop_last=False,
+                pad_last_batch=True,
+                on_retry=self._note_io_retry,
+            )
+            if len(test_ds) == 0:
+                raise ValueError("empty eval set")
 
         # resume decision happens BEFORE the logger so the CSV lineage
         # column records auto-resumed runs too, not just flag-resumed ones

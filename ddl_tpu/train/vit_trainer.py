@@ -24,6 +24,7 @@ from ddl_tpu.data import (
     shard_batch,
 )
 from ddl_tpu.models.vit import ViTConfig
+from ddl_tpu.obs.steptrace import stage
 from ddl_tpu.parallel.sharding import LMMeshSpec
 from ddl_tpu.train.loop import BaseTrainer, _child, _phase
 from ddl_tpu.train.vit_steps import make_vit_step_fns
@@ -84,34 +85,36 @@ class ViTTrainer(BaseTrainer):
         self.job_id = run.job_id
         self.tx = tx
         self._rng = rng if rng is not None else jax.random.key(0)
-        self.fns = self._make_fns()
+        with stage("setup.model", self.obs):
+            self.fns = self._make_fns()
 
-        dc = data if data is not None else DataConfig(
-            image_size=cfg.image_size,
-            global_batch_size=run.batch,
-            eval_batch_size=run.batch,
-        )
-        train_ds, test_ds = (
-            datasets if datasets is not None else build_datasets(dc)
-        )
-        n_proc, proc = jax.process_count(), jax.process_index()
-        self.train_loader = DataLoader(
-            train_ds, run.batch // n_proc,
-            sampler=ShardedEpochSampler(len(train_ds), n_proc, proc, seed=0),
-            on_retry=self._note_io_retry,
-        )
-        # deterministic full-coverage eval: ordered, sentinel-padded to
-        # static shapes, padded rows (label -1) masked out — same contract
-        # as the CNN Trainer's eval loop
-        self.test_loader = DataLoader(
-            test_ds, run.batch // n_proc,
-            sampler=ShardedEpochSampler(
-                len(test_ds), n_proc, proc,
-                shuffle=False, drop_last=False, pad_mode="sentinel", seed=1,
-            ),
-            drop_last=False, pad_last_batch=True,
-            on_retry=self._note_io_retry,
-        )
+        with stage("setup.data", self.obs):
+            dc = data if data is not None else DataConfig(
+                image_size=cfg.image_size,
+                global_batch_size=run.batch,
+                eval_batch_size=run.batch,
+            )
+            train_ds, test_ds = (
+                datasets if datasets is not None else build_datasets(dc)
+            )
+            n_proc, proc = jax.process_count(), jax.process_index()
+            self.train_loader = DataLoader(
+                train_ds, run.batch // n_proc,
+                sampler=ShardedEpochSampler(len(train_ds), n_proc, proc, seed=0),
+                on_retry=self._note_io_retry,
+            )
+            # deterministic full-coverage eval: ordered, sentinel-padded to
+            # static shapes, padded rows (label -1) masked out — same contract
+            # as the CNN Trainer's eval loop
+            self.test_loader = DataLoader(
+                test_ds, run.batch // n_proc,
+                sampler=ShardedEpochSampler(
+                    len(test_ds), n_proc, proc,
+                    shuffle=False, drop_last=False, pad_mode="sentinel", seed=1,
+                ),
+                drop_last=False, pad_last_batch=True,
+                on_retry=self._note_io_retry,
+            )
 
         self.is_logging_process = proc == 0
         self.logger = (
@@ -136,7 +139,8 @@ class ViTTrainer(BaseTrainer):
         self.save_best = run.save_best_qwk and bool(run.checkpoint_dir)
         self.best_value = -1.0
 
-        self.state = self.fns.init_state()
+        with stage("setup.model", self.obs):
+            self.state = self.fns.init_state()
         self.periods_run = 0
         resume_epoch = ckpt.resolve_resume(
             run.checkpoint_dir, run.job_id, run.resume_epoch, run.auto_resume

@@ -34,10 +34,11 @@ Under a root this module chose, three pieces keep it safe and observable:
   directory (``<pod>/compile_cache``), which outlives launches by
   construction.
 * **Hit/miss counters** (:func:`cache_stats`): entry counts before the
-  run plus ``jax.monitoring`` cache-hit/miss listeners, emitted as the
-  ``compile_cache`` obs event so `obs summarize`/`obs diff` can gate
-  "the second incarnation must be warm" (``restart_latency`` and the
-  ``recompile`` goodput bucket strictly lower).
+  run plus the cache hits and misses that :class:`CompileLog` hears,
+  emitted as the ``compile_cache`` obs event so `obs summarize`/`obs
+  diff` can gate "the second incarnation must be warm"
+  (``restart_latency`` and the ``recompile`` goodput bucket strictly
+  lower).
 
 * **Byte bound** (:func:`evict_to_byte_bound`): the shared NAS root
   otherwise grows without bound — every elastic shrink/grow leaves
@@ -49,20 +50,32 @@ Under a root this module chose, three pieces keep it safe and observable:
 
 ``DDL_COMPILE_CACHE=off`` leaves the cache as JAX finds it: nothing is
 activated, counted or evicted.
+
+:class:`CompileLog` is the package's one ``jax.monitoring`` listener: it
+hears every trace, lowering and backend compile of the process with its
+true start and end, the cache load inside a backend compile, and the
+cache's hits and misses.  The counters above, ``period.compiles`` /
+``compile_s`` and the ``compile.*`` spans of the event stream
+(``obs/steptrace.py``) all come from it.
 """
 
 from __future__ import annotations
 
 import os
+import threading
+import weakref
+from collections import deque
 from pathlib import Path
 
 __all__ = [
     "ENV_CACHE",
     "ENV_CACHE_MAX_BYTES",
     "ENV_CACHE_MIN_S",
+    "CompileLog",
     "activate_compile_cache",
     "cache_entries",
     "cache_stats",
+    "compile_log",
     "default_cache_root",
     "emit_cache_event",
     "evict_to_byte_bound",
@@ -92,7 +105,6 @@ ENV_CACHE_MAX_BYTES = "DDL_COMPILE_CACHE_MAX_BYTES"
 # is global), read back by cache_stats()/emit_cache_event().
 _active: dict | None = None
 _counters = {"hits": 0, "misses": 0, "evicted": 0, "evicted_bytes": 0}
-_listener_installed = False
 
 
 def default_cache_root() -> Path:
@@ -124,23 +136,135 @@ def cache_entries(cache_dir: str | os.PathLike) -> int:
         return 0
 
 
-def _install_counters() -> None:
-    """Count persistent-cache hits/misses via ``jax.monitoring`` —
-    the same listener surface steptrace's compile timer uses."""
-    global _listener_installed
-    if _listener_installed:
-        return
-    _listener_installed = True
-    from jax import monitoring
+class CompileLog:
+    """What ``jax.monitoring`` says of the process's compiles, heard in
+    one place (:func:`compile_log` makes it and registers it, once;
+    listener registries are append-only).
 
-    def _on_event(event: str, **kw) -> None:
+    Every trace, lowering and backend compile becomes a span
+    ``compile.trace`` / ``compile.lower`` / ``compile.backend`` with
+    ``fn`` (JAX's ``fun_name``) and JAX's own start and end on
+    ``time.time()``, the clock of the event stream's ``ts``.  A trace
+    that runs inside another trace or inside a lowering (the jitted
+    functions a step calls: 2,000 of them in a small DenseNet's, and the
+    hundreds that lowering a random key traces) is a part of that one and
+    not a span of its own; JAX announces each start as a scalar, which is
+    what tells them apart.  JAX times a backend compile around
+    ``compile_or_get_cached``, so a persistent-cache hit's load lies
+    INSIDE it: the span carries it as ``cache_hit`` and ``cache_load_s``
+    and its seconds are counted once.  ``count`` and ``secs`` are the
+    backend compiles and their seconds so far, from which ``StepTrace``
+    takes a period's ``compiles`` / ``compile_s``.
+
+    A span goes to the open stream (``attach``: the newest ``StepTrace``
+    that ``StepTrace.create`` made, held weakly) or, while none is open, into ``kept``, a bounded list
+    that the next stream writes first, true times and order kept.  The
+    trainers' stage spans (``obs/steptrace.stage``) take the same path
+    through ``record``, and a span recorded inside an open stage names it
+    as its parent."""
+
+    KEEP = 4096
+    SPANS = {
+        "/jax/core/compile/jaxpr_trace_duration": "compile.trace",
+        "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower",
+        "/jax/core/compile/backend_compile_duration": "compile.backend",
+    }
+
+    def __init__(self) -> None:
+        self.count = 0
+        self.secs = 0.0
+        self.kept: deque = deque(maxlen=self.KEEP)
+        self._sink = None  # weakref to the StepTrace that writes
+        # per thread: the open stages, whether each open compile event
+        # began inside another, the cache load heard last
+        self._local = threading.local()
+
+    def _mine(self, name: str) -> list:
+        return self._local.__dict__.setdefault(name, [])
+
+    # ---------------------------------------------- jax.monitoring's side
+    def _on_event(self, event: str, **kw) -> None:
         if "compilation_cache" in event:
             if "hit" in event:
                 _counters["hits"] += 1
             elif "miss" in event:
                 _counters["misses"] += 1
 
-    monitoring.register_event_listener(_on_event)
+    def _on_duration(self, event: str, duration: float, **kw) -> None:
+        # fires inside the backend compile it belongs to, on its thread
+        if event.endswith("cache_retrieval_time_sec"):
+            self._local.load_s = duration
+
+    def _on_start(self, event: str, value, **kw) -> None:
+        if event in self.SPANS:
+            inside = self._mine("inside")
+            inside.append(bool(inside))
+
+    def _on_span(self, event: str, start: float, end: float, fun_name="", **kw) -> None:
+        name = self.SPANS.get(event)
+        if name is None:
+            return
+        inside = self._mine("inside")
+        nested = inside.pop() if inside else False
+        if nested and name == "compile.trace":
+            return
+        fields = {"fn": str(fun_name)}
+        if name == "compile.backend":
+            self.count += 1
+            self.secs += end - start
+            load_s, self._local.load_s = getattr(self._local, "load_s", None), None
+            fields.update(cache_hit=load_s is not None, cache_load_s=load_s or 0.0)
+        self.record(name, start, end, **fields)
+
+    # --------------------------------------------------- the spans' side
+    def stages(self) -> list:
+        """This thread's open stage spans, outermost first."""
+        return self._mine("stages")
+
+    def record(self, name: str, start: float, end: float, **fields) -> None:
+        open_stages = self.stages()
+        if open_stages:
+            fields.update(parent=open_stages[-1], depth=len(open_stages))
+        sink = self._taker()
+        if sink is None:
+            self.kept.append((name, start, end, fields))
+        else:
+            sink.heard(name, start, end, fields)
+
+    def _taker(self):
+        return self._sink() if self._sink is not None else None
+
+    def attach(self, sink) -> None:
+        """``sink.heard(name, start, end, fields)`` takes every span from
+        now on, the kept ones first."""
+        self._sink = weakref.ref(sink)
+        while self.kept:
+            sink.heard(*self.kept.popleft())
+
+    def detach(self, sink=None) -> None:
+        """``sink`` takes no more spans (None: whichever does)."""
+        if sink is None or self._taker() is sink:
+            self._sink = None
+
+    def attached(self, sink) -> bool:
+        return self._taker() is sink
+
+
+_log: CompileLog | None = None
+
+
+def compile_log() -> CompileLog:
+    """The process's :class:`CompileLog`, registered on first use."""
+    global _log
+    if _log is None:
+        from jax import monitoring
+
+        _log = CompileLog()
+        monitoring.register_event_listener(_log._on_event)
+        monitoring.register_event_duration_secs_listener(_log._on_duration)
+        monitoring.register_scalar_listener(_log._on_start)
+        monitoring.register_event_time_span_listener(_log._on_span)
+    return _log
 
 
 def _cache_max_bytes() -> int:
@@ -296,7 +420,7 @@ def activate_compile_cache(
             # a process that already compiled keeps its first directory
             # open until the cache object is rebuilt
             compilation_cache.reset_cache()
-    _install_counters()
+    compile_log()
     entries = cache_entries(cache_dir)
     _active = {
         "dir": str(cache_dir),

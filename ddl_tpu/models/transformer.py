@@ -46,6 +46,14 @@ layer is recomputed from them and their layers are not.  LayerNorm with
 bias for RMSNorm, no positions, and a head tied to the embedding complete
 the SambaY stack of ``benchmark/configs/phi4-mini-flash.json``.
 
+A ``conv`` layer's mixer is a gated short convolution (``ShortConv``):
+``[B, C, x]`` from one projection, ``C * conv(B * x)`` with a causal
+depthwise convolution of ``short_conv`` taps and no bias, then an out
+projection.  Conv layers beside full-attention ones with q/k norms and
+plain rotary, sigmoid-scored dropless experts selected with a bias and
+normalised with ``route_eps``, a leading dense layer and a tied head are
+the lfm2_moe stack of ``benchmark/configs/lfm2-8b-a1b.json``.
+
 No torch/CUDA analog exists in the reference; parity citations therefore
 point at the subsystems this family plugs into: the mesh backbone
 (SURVEY.md §2 C10), the trainer (C3), and the checkpointing layout (C8).
@@ -81,7 +89,7 @@ __all__ = [
 
 
 LAYER_KINDS = ("sliding_attention", "full_attention", "mamba", "gmu",
-               "cross_attention")
+               "cross_attention", "conv")
 # {a kind of layer that reads a carried value: the kind that keeps it}
 _CARRIED = {"gmu": "mamba", "cross_attention": "full_attention"}
 
@@ -137,6 +145,12 @@ class Rope:
 
 @dataclasses.dataclass(frozen=True)
 class LMConfig:
+    """What the LM is, field by field; the defaults are the plain block
+    (pre-RMSNorm, rotary, GELU MLP).  ``layer_types`` names each layer's
+    mixer (``LAYER_KINDS``): sliding or full attention, a Mamba layer, a
+    GMU, cross-attention, or a gated short convolution (``conv``, taps
+    ``short_conv``); the fields after it say the rest of a layer."""
+
     vocab_size: int = 256  # byte-level by default
     d_model: int = 256
     n_layers: int = 4
@@ -318,9 +332,11 @@ class LMConfig:
     # under 'softmax', 'dropless' under 'sigmoid'.
     moe_layer: str = ""
     moe_d_ff: int = 0  # an expert's width (0 = d_ff)
-    # the three below are the dropless layer's, under either score
+    # the four below are the dropless layer's, under either score; the
+    # chosen scores are normalised as picked / (sum of picked + route_eps)
     num_shared_experts: int = 0
     route_scale: float = 1.0
+    route_eps: float = 1e-20
     # (share index, shares): this program holds experts [i * E / n,
     # (i + 1) * E / n) of each layer's num_experts, routes over all of
     # them and computes its own experts' part of the result; choices of
@@ -329,8 +345,9 @@ class LMConfig:
     expert_share: tuple = (0, 1)
     # --- a hybrid stack: layer_types may also name "mamba" (a Mamba-1
     # mixer), "gmu" (a Gated Memory Unit over the scan output of the
-    # nearest mamba layer before it) and "cross_attention" (queries of its
-    # own over the K/V of the nearest full_attention layer before it) ---
+    # nearest mamba layer before it), "cross_attention" (queries of its
+    # own over the K/V of the nearest full_attention layer before it) and
+    # "conv" (a gated short convolution of short_conv taps, ShortConv) ---
     # "layer": LayerNorm with scale and bias where "rms" has RMSNorm
     norm: str = "rms"
     # logits = x E^T with the embedding's own rows: one leaf, no lm_head
@@ -348,6 +365,8 @@ class LMConfig:
     ssm_state: int = 16
     ssm_conv: int = 4
     ssm_expand: int = 2
+    # the gated short convolution's taps (a conv layer's causal window)
+    short_conv: int = 3
 
     def __post_init__(self):
         if self.layer_types:
@@ -366,6 +385,9 @@ class LMConfig:
             if "cross_attention" in self.layer_types and not self.diff_attn:
                 raise ValueError("cross_attention layers are built as differential "
                                  "attention (diff_attn=True)")
+            if "conv" in self.layer_types and self.short_conv < 1:
+                raise ValueError(f"a conv layer needs short_conv >= 1 taps, "
+                                 f"got {self.short_conv}")
         if self.diff_attn and (self.n_heads % 2 or self.kv_heads % 2
                                or self.qk_norm or self.attn_gate):
             raise ValueError("diff_attn pairs heads up: n_heads and n_kv_heads must "
@@ -397,9 +419,10 @@ class LMConfig:
             raise ValueError("sigmoid scores under a token capacity are not built: "
                              "moe_router='sigmoid' takes moe_layer='dropless'")
         if not self.moe_dropless and (
-                shares > 1 or self.num_shared_experts or self.route_scale != 1.0):
+                shares > 1 or self.num_shared_experts or self.route_scale != 1.0
+                or self.route_eps != 1e-20):
             raise ValueError(
-                "expert_share, num_shared_experts and route_scale are the dropless "
+                "expert_share, num_shared_experts, route_scale and route_eps are the dropless "
                 "layer's (moe_layer='dropless', under either score function); "
                 "the capacity layer would ignore them"
             )
@@ -530,7 +553,7 @@ class LMConfig:
     @property
     def recurrent(self) -> tuple:
         """The kinds of layer in the pattern that no decode cache holds."""
-        return tuple(k for k in ("mamba", "gmu", "cross_attention")
+        return tuple(k for k in ("mamba", "gmu", "cross_attention", "conv")
                      if k in self.layer_types)
 
     @property
@@ -906,10 +929,12 @@ class DiffAttention(nn.Module):
 
 
 class CausalConv(nn.Module):
-    """Causal depthwise convolution over time with a bias, in float32:
-    ``y_t = b + sum_j w_j x_{t - (taps - 1) + j}``."""
+    """Causal depthwise convolution over time, in float32:
+    ``y_t = b + sum_j w_j x_{t - (taps - 1) + j}``, ``b`` 0 and no leaf
+    without ``use_bias``."""
 
     taps: int
+    use_bias: bool = True
 
     @nn.compact
     def __call__(self, x):
@@ -920,11 +945,14 @@ class CausalConv(nn.Module):
                 nn.initializers.variance_scaling(1.0, "fan_in", "normal", in_axis=0,
                                                  out_axis=1), (None, "mlp")),
             (self.taps, c), jnp.float32)
+        padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (self.taps - 1, 0), (0, 0)))
+        y = sum(padded[:, j:j + t] * w[j] for j in range(self.taps))
+        if not self.use_bias:
+            return y
         bias = self.param(
             "bias", nn.with_logical_partitioning(nn.initializers.zeros_init(), ("mlp",)),
             (c,), jnp.float32)
-        padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (self.taps - 1, 0), (0, 0)))
-        return bias + sum(padded[:, j:j + t] * w[j] for j in range(self.taps))
+        return bias + y
 
 
 def _ssm_dense(cfg, features, axes, name, **kw):
@@ -996,6 +1024,31 @@ class Gmu(nn.Module):
         g = nn.with_logical_constraint(g, ("batch", "act_seq", "act_mlp"))
         gated = (m.astype(jnp.float32) * jax.nn.silu(g.astype(jnp.float32))).astype(cfg.dtype)
         out = _ssm_dense(cfg, cfg.d_model, ("mlp", "embed"), "out_proj")(gated)
+        return nn.with_logical_constraint(out, ("batch", "act_seq", "act_embed"))
+
+
+class ShortConv(nn.Module):
+    """The gated short convolution: ``[B, C, x] = split3(x W_in)`` (in that
+    order), ``v = conv(B * x)`` causal and depthwise over ``short_conv``
+    taps with no bias, ``out = (C * v) W_out``; no biases anywhere.  The
+    products and the taps are float32 from the compute-type projection,
+    under the scope ``short``; the out projection takes the compute type.
+    Its decode state would be the last ``short_conv - 1`` positions of
+    ``B * x``, per lane: ``LMConfig.recurrent``."""
+
+    cfg: LMConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        d = cfg.d_model
+        bcx = _ssm_dense(cfg, 3 * d, ("embed", "mlp"), "in_proj")(x)
+        bcx = nn.with_logical_constraint(bcx, ("batch", "act_seq", "act_mlp"))
+        with jax.named_scope("short"):
+            b, c, u = (bcx[..., i * d:(i + 1) * d].astype(jnp.float32) for i in range(3))
+            v = CausalConv(cfg.short_conv, use_bias=False, name="conv")(b * u)
+            y = (c * v).astype(cfg.dtype)
+        out = _ssm_dense(cfg, d, ("mlp", "embed"), "out_proj")(y)
         return nn.with_logical_constraint(out, ("batch", "act_seq", "act_embed"))
 
 
@@ -1654,7 +1707,7 @@ class MoeMlp(nn.Module):
                 # 1 as the router sharpens (it collapses before the rows do)
                 self.sow("intermediates", "moe_topk_mass", picked.sum(-1).mean())
             weights = cfg.route_scale * picked / (
-                picked.sum(-1, keepdims=True) + 1e-20
+                picked.sum(-1, keepdims=True) + cfg.route_eps
             )
 
         def bank(name, shape, axes):
@@ -1712,7 +1765,7 @@ class Block(nn.Module):
     """Pre-norm decoder block: ``x + mixer(norm(x))``, then ``x +
     mlp(norm(x))``.  The mixer is the layer's kind (``LMConfig.layer_kind``):
     attention (``Attention``, or ``DiffAttention`` under ``diff_attn``), a
-    ``Mamba`` layer, a ``Gmu`` or cross-attention.  With ``cache``
+    ``Mamba`` layer, a ``Gmu``, cross-attention or a ``ShortConv``.  With ``cache``
     (incremental decode) the return gains the updated cache: ``(x, aux,
     new_cache)``.  With ``carry`` (a stack in which layers read what
     earlier ones kept, ``LMConfig.carries``: a dict, empty before the
@@ -1749,6 +1802,8 @@ class Block(nn.Module):
                 carry = dict(carry, ssm=s)
         elif kind == "gmu":
             a = Gmu(cfg, name="gmu")(h, carry["ssm"])
+        elif kind == "conv":
+            a = ShortConv(cfg, name="conv")(h)
         elif cfg.diff_attn:
             cross = kind == "cross_attention"
             a, kv = DiffAttention(

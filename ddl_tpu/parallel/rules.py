@@ -330,6 +330,11 @@ def _transformer_block_rules(E) -> tuple[tuple[str, P], ...]:
         (r"ssm/D$", P("model")),
         (r"(ssm|gmu)/out_proj/kernel$", P("model", E)),
         (r"gmu/in_proj/kernel$", P(E, "model")),
+        # the gated short convolution (ShortConv) likewise: its in
+        # projection column-parallel, the taps by channel, out row-parallel
+        (r"conv/in_proj/kernel$", P(E, "model")),
+        (r"conv/conv/kernel$", P(None, "model")),
+        (r"conv/out_proj/kernel$", P("model", E)),
     )
 
 

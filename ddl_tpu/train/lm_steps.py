@@ -168,8 +168,12 @@ STEP_PARTS = {
     "attn": "attn", "mlp": "mlp", "lm_head": "head", "head": "head",
     # a hybrid stack's mixers: the Mamba layer (projections, convolution,
     # gate) and inside it the scan kernels' calls, the Gated Memory Unit,
-    # the cross-attention layers (``attn`` stays the self layers')
+    # the cross-attention layers (``attn`` stays the self layers'); the
+    # gated short convolution and inside it its elementwise part (the two
+    # products and the taps).  The first scope on an op's path gives its
+    # tag, so the Mamba layer's own ``ssm/conv`` stays under ``ssm``.
     "ssm": "ssm", "ssm/scan": "ssm/scan", "gmu": "gmu", "xattn": "xattn",
+    "conv": "conv", "conv/short": "conv/short",
 }
 
 

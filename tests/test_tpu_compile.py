@@ -267,6 +267,104 @@ def test_grouped_swiglu_fwd_and_grads(compile_for_chip, d, f, held):
     }
 
 
+def test_grouped_products_in_column_blocks_at_lfm2_s_widths(compile_for_chip):
+    """The LFM2 cell's expert layer: d 2048, F 1792, 8 held experts, the
+    buffer of 8192 x 4 choices' worst case.  Both banks' whole blocks (2 x
+    2048 x 1792 bf16, 14.7 MB) pass the gated pair's budget, so its forward
+    takes ``_col_tile``'s blocks, 896 of 1792, and computes the activation
+    a block; its dx sums both products over blocks of 512 of 2048; ``wo``'s
+    products take the same blocks.  Every call within its VMEM limit."""
+    from ddl_tpu.obs.scope import kernel_tiles
+    from ddl_tpu.ops.grouped_matmul import (
+        ROW_TILE, _GATED_BLOCK_BYTES, buffer_rows, grouped_matmul, grouped_swiglu,
+    )
+
+    d, f, held = 2048, 1792, 8
+    assert 2 * d * f * 2 > _GATED_BLOCK_BYTES
+    rows = buffer_rows(8192 * 4, held)
+    tiles = rows // ROW_TILE
+    idx = _s((tiles,), jnp.int32)
+    col = {"col1792": 896}
+
+    def layer(x, wg, wi, wo, tg, ts, na):
+        h = grouped_swiglu(x, wg, wi, tg, ts, na, interpret=False)
+        return grouped_matmul(h, wo, tg, ts, na, interpret=False)
+
+    bank = _s((held, d, f), F32)
+    args = (_s((rows, d), BF16), bank, bank, _s((held, f, d), F32), idx, idx,
+            _s((1,), jnp.int32))
+    fwd = {"calls": 2, "total": tiles * (2 + 4), "floor": held * (2 + 4), "products": 2 + 1,
+           **col}
+    assert kernel_tiles(compile_for_chip(layer, *args)) == {"moe_gmm_fwd": fwd}
+    text = compile_for_chip(
+        jax.grad(lambda x, *w: _sum_f32(layer(x, *w)), argnums=(0, 1, 2, 3)), *args)
+    assert kernel_tiles(text) == {
+        # the sum is linear in wo's product: only the pair's forward is kept
+        "moe_gmm_fwd": {"calls": 1, "total": tiles * 2, "floor": held * 2, "products": 2, **col},
+        # the pair's dx in blocks of 512 of 2048 (no col entry: the
+        # constant's block), wo's dx in blocks of 896 of 1792
+        "moe_gmm_dx": {"calls": 2, "total": tiles * (4 + 2), "floor": held * (4 + 2),
+                       "products": 2 + 1, **col},
+        # the pair's two dw (2048 x 1792) and wo's (1792 x 2048): 8 blocks each
+        "moe_gmm_dw": {"calls": 3, "total": 3 * tiles * 8, "floor": 3 * held * 8,
+                       "products": 3, **col},
+    }
+
+
+def test_the_lfm2_cell_s_whole_step_fits_one_chip(topo, no_persistent_cache, monkeypatch):
+    """The LFM2 cell's train step at its every width, batch 2 x 4096,
+    compiled for one described v5e from shapes alone: the grouped products,
+    the row kernels and the flash kernels in it, and its plan (arguments,
+    outputs not aliased to them, temporaries) under the chip's
+    ``bytes_limit`` (16,909,336,064 bytes), with no remat."""
+    import importlib
+    import os
+    import sys
+
+    import flax.linen as nn
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import harness
+    from benchmark.families.lfm2 import lm_config
+    from ddl_tpu.models.transformer import TransformerLM
+    from ddl_tpu.parallel.sharding import LMMeshSpec
+    from ddl_tpu.train.lm_steps import LMTrainState, make_lm_step_fns
+    from ddl_tpu.train.state import build_optimizer
+
+    # the kernels' own entry points resolve the backend: here that is the CPU
+    for mod in ("flash_attention", "grouped_matmul", "moe_rows"):
+        monkeypatch.setattr(importlib.import_module(f"ddl_tpu.ops.{mod}"),
+                            "interpret_default", lambda: False)
+    spec = harness.load_cell(root, "lfm2-8b.train_b2_t4096")
+    cfg, w = lm_config(spec["config"]["model"]), spec["workload"]
+    assert not cfg.remat
+    o = w["optimizer"]
+    tx = build_optimizer(o["learning_rate"], b1=o["b1"], b2=o["b2"], eps=o["eps"])
+    fns = make_lm_step_fns(cfg, LMMeshSpec(), tx, jax.random.key(0), w["batch"], w["seq_len"],
+                           devices=topo.devices[:1])
+    tok = jnp.zeros((w["batch"], w["seq_len"]), jnp.int32)
+    params = nn.meta.unbox(jax.eval_shape(
+        lambda: TransformerLM(cfg).init(jax.random.key(0), tok)["params"]))
+    state = LMTrainState(step=_s((), jnp.int32), params=params,
+                         opt_state=jax.eval_shape(tx.init, params))
+    rep = NamedSharding(fns.mesh, P())
+    state, tok = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=rep), (state, tok))
+    compiled = fns.train.lower(state, tok, tok).compile()
+    text = compiled.as_text()
+    for kernel in ("moe_gmm_fwd", "moe_gmm_dx", "moe_gmm_dw", "moe_rows_gather", "flash_fwd"):
+        assert kernel in text, kernel
+    ma = compiled.memory_analysis()
+    plan = (ma.argument_size_in_bytes + ma.output_size_in_bytes - ma.alias_size_in_bytes
+            + ma.temp_size_in_bytes)
+    # 16 bytes a parameter: weights, Adam's two moments, the gradient's room
+    assert ma.argument_size_in_bytes >= 12 * 507_820_288
+    assert plan < 16_909_336_064, plan
+
+
 @pytest.mark.parametrize("dtype,passes", [(BF16, 1), (F32, 3)], ids=["bf16", "f32"])
 @pytest.mark.parametrize("held,d,shared", [(8, 2048, True), (16, 2304, False)],
                          ids=["trinity", "mellum2"])

@@ -20,7 +20,10 @@ its forward takes both products of an ``x`` tile and writes ``silu(x wg)
 * (x wi)`` beside the two products (the backward's residuals), and its
 ``moe_gmm_dx`` forms both products' cotangents from ``dh`` and those
 residuals and sums ``dgate wg^T + dup wi^T`` in one float32 accumulator,
-so nothing between the products walks the worst-case buffer.
+so nothing between the products walks the worst-case buffer.  Each takes
+the widest block of both banks whose VMEM reckoning fits (``_gated_tile``:
+whole at every cell's widths), so a row tile is read, and its activation
+or cotangents formed, once.
 
 A grid step past ``n_active`` maps every block to the last active
 tile's (no new DMA) and computes nothing, so time follows the rows
@@ -67,14 +70,10 @@ _COL_TILE = 512
 # For a width over 512 that 512 does not divide (896 = 7 x 128, 2304 = 18 x
 # 128): the widest multiple of 128 lanes that divides it, up to this many.
 _COL_TILE_128 = 1152
-# The gated pair holds a group's block of both banks beside a row tile: a
-# width whose two blocks fit in this many bytes goes through whole, so the
-# activation (forward) and its derivative (dx) are computed once a row
-# tile; a wider one takes ``_col_tile``'s blocks.  Both cells' widths fit
-# (2 x 2304 x 896 and 2 x 2048 x 1024 bf16: 8.3 and 8.4 MB).
-_GATED_BLOCK_BYTES = 9 * 1024 * 1024
-# Those blocks twice (the pipeline's buffers), the row tiles and the
-# float32 products: about 25 MB at 2304 x 896, past Mosaic's 16 MB default
+# What the gated pair may hold in VMEM (``_gated_vmem``): both banks'
+# blocks, the row tiles in and out, each twice (the pipeline's buffers),
+# and the float32 temporaries of a row tile.  LFM2's dx takes both
+# 2048 x 1792 blocks whole at about 54 MB, past Mosaic's 16 MB default
 # (a v5e core has 128 MiB).
 _GATED_VMEM_LIMIT = 64 * 1024 * 1024
 
@@ -219,12 +218,31 @@ def _tgmm(x, dy, tile_group, tile_src, n_active, *, groups, tile, interpret):
     )(tile_group, tile_src, n_active, x, dy)
 
 
-def _gated_tile(n: int, k: int, itemsize: int) -> int:
-    """The gated pair's block of a width ``n`` against a contraction of
-    ``k``: the whole width where both banks' blocks fit, else
-    ``_col_tile``'s (which also refuses a width off the 128 lanes)."""
-    block = _col_tile(n)
-    return n if 2 * k * n * itemsize <= _GATED_BLOCK_BYTES else block
+def _gated_vmem(block: int, k: int, tile: int, itemsize: int, *, dx: bool) -> int:
+    """Bytes of VMEM the gated pair holds at a block of ``block`` of the
+    blocked width against the whole other width ``k``: both banks'
+    blocks and the row tiles, double-buffered, and the float32
+    temporaries of a row tile.  Forward: ``x`` (tile, k) in; ``h``,
+    ``gate``, ``up`` (tile, block) out and in float32.  dx: ``dh``,
+    ``gate``, ``up`` (tile, k) in, ``dgate``, ``dup`` (tile, k) and ``dx``
+    (tile, block) out; in float32 the three inputs, the sigmoid, both
+    cotangents and the (tile, block) accumulator."""
+    if dx:
+        rows, f32 = 5 * tile * k + tile * block, 6 * tile * k + tile * block
+    else:
+        rows, f32 = tile * k + 3 * tile * block, 3 * tile * block
+    return 2 * (2 * k * block + rows) * itemsize + 4 * f32
+
+
+def _gated_tile(n: int, k: int, tile: int, itemsize: int, *, dx: bool) -> int:
+    """The gated pair's block of a width ``n`` against the whole other
+    width ``k``: the widest of ``n`` itself and the multiples of 128 lanes
+    that divide it whose ``_gated_vmem`` fits ``_GATED_VMEM_LIMIT``; where
+    none fits, the narrowest, and the compiler judges."""
+    blocks = [b for b in range(n, 0, -128) if n % b == 0]
+    return next((b for b in blocks
+                 if _gated_vmem(b, k, tile, itemsize, dx=dx) <= _GATED_VMEM_LIMIT),
+                blocks[-1])
 
 
 def _gated_fwd_kernel(tg_ref, ts_ref, na_ref, x_ref, wg_ref, wi_ref,
@@ -271,7 +289,7 @@ def _gated_gmm(x, wg, wi, tile_group, tile_src, n_active, *, tile, interpret):
     each active row tile; an ``x`` tile is read once for both."""
     rows, k = x.shape
     n = wg.shape[2]
-    tn = _gated_tile(n, k, x.dtype.itemsize)
+    tn = _gated_tile(n, k, tile, x.dtype.itemsize, dx=False)
     num_tiles = rows // tile
     w_spec = pl.BlockSpec((1, k, tn), lambda j, i, tg, ts, na: (tg[i], 0, j))
     o_spec = pl.BlockSpec((tile, tn), lambda j, i, tg, ts, na: (ts[i], j))
@@ -304,11 +322,12 @@ def _gated_gmm_dx(dh, gate, up, wg, wi, tile_group, tile_src, n_active, *,
     """``dx = dgate @ wg[g]^T + dup @ wi[g]^T`` (R, K) from ``dh`` and the
     forward's two products, and ``dgate``, ``dup`` (R, F) for the weight
     gradients, all in ``dh``'s type; inactive row tiles stay unwritten.
-    A block of K narrower than K recomputes the cotangents and writes the
-    same ``dgate`` and ``dup`` once a block."""
+    At every cell's widths K is one block, so a row tile's cotangents are
+    formed and written once; where ``_gated_tile`` cuts K narrower, each
+    block recomputes them and writes the same ``dgate`` and ``dup``."""
     rows, n = dh.shape
     k = wg.shape[1]
-    tk = _gated_tile(k, n, dh.dtype.itemsize)
+    tk = _gated_tile(k, n, tile, dh.dtype.itemsize, dx=True)
     num_tiles = rows // tile
     w_spec = pl.BlockSpec((1, tk, n), lambda j, i, tg, ts, na: (tg[i], j, 0))
     row_spec = pl.BlockSpec((tile, n), lambda j, i, tg, ts, na: (ts[i], 0))
@@ -347,7 +366,7 @@ def _tiles_metadata(total: int, floor: int, **more: int) -> dict:
 
 def _col_metadata(n: int, block: int) -> dict:
     """The column block chosen for a width over 512 that the 128-lane rule
-    cut or the gated pair took whole (``col<width>``, which
+    cut or the gated pair's reckoning chose (``col<width>``, which
     ``kernel_tiles`` keeps and does not sum: a width has one block in
     every call); a width whose block is the constant's says nothing new
     and keeps the text it had."""

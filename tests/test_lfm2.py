@@ -365,18 +365,19 @@ def test_lfm2_table_matches_logical_resolution(fsdp):
 
 
 def test_obs_hbm_prints_the_gated_pair_s_column_blocks_of_1792(tmp_path):
-    """At the cell's widths the gated pair's two whole blocks (2 x 2048 x
-    1792 bf16, 14.7 MB) pass its budget, so its forward takes ``_col_tile``'s
-    896 of 1792, and the plan says so."""
+    """At the cell's widths the gated pair's VMEM reckoning fits both
+    banks' whole blocks (2 x 2048 x 1792 bf16, 14.7 MB, double-buffered
+    beside the row tiles), so its forward takes F in one block of 1792 and
+    its dx K in one of 2048, and the plan says so."""
     from ddl_tpu.obs.events import EventWriter
     from ddl_tpu.obs.fold import fold_job
     from ddl_tpu.obs.hbm import account_from_fold, render_hbm
     from ddl_tpu.ops import grouped_matmul as gm
 
-    block = gm._gated_tile(1792, 2048, 2)
-    assert block == gm._col_tile(1792) == 896 and gm._gated_tile(2048, 1792, 2) == 512
+    block = gm._gated_tile(1792, 2048, gm.ROW_TILE, 2, dx=False)
+    assert block == 1792 and gm._gated_tile(2048, 1792, gm.ROW_TILE, 2, dx=True) == 2048
     col = gm._col_metadata(1792, block)
-    assert col == {"col1792": 896}
+    assert col == {"col1792": 1792}
     tiles = {"moe_gmm_fwd": {"calls": 8, "total": 2 * 4 * 136, "floor": 48, "products": 12,
                              **col}}
     w = EventWriter(tmp_path, "plan", host=0)
@@ -385,4 +386,4 @@ def test_obs_hbm_prints_the_gated_pair_s_column_blocks_of_1792(tmp_path):
     w.emit("hbm_sample", params_bytes=600, watermark=2000, peak=2000, limit=4096, synthetic=True)
     w.close()
     out = render_hbm(account_from_fold(fold_job(tmp_path, "plan", cache=False)), "plan")
-    assert "tiles moe_gmm_fwd: 8 call(s)" in out and "column blocks 896 of 1792" in out
+    assert "tiles moe_gmm_fwd: 8 call(s)" in out and "column blocks 1792 of 1792" in out

@@ -401,7 +401,9 @@ def swiglu_sides(request):
     out = {"valid": valid, "dtype": dtype}
     with pytest.MonkeyPatch.context() as m:
         if blocks == "blocks":
-            m.setattr(gm, "_GATED_BLOCK_BYTES", 0)
+            # no block fits: the narrowest, 128 lanes, so dx walks K in
+            # 16 or 18 passes and the forward F in 8 or 7
+            m.setattr(gm, "_GATED_VMEM_LIMIT", 0)
         for name, fn in [("fused", fused), ("composition", composition), ("loop", loop)]:
             out[name] = (fn(x, wg, wi), *jax.grad(loss(fn), (0, 1, 2))(x, wg, wi))
     return out
@@ -420,6 +422,26 @@ def test_gated_pair_against_the_composition_and_the_loop(swiglu_sides, which, ag
         got, want = (jnp.where(swiglu_sides["valid"], a, 0.0) for a in (got, want))
     tol = 2e-4 if swiglu_sides["dtype"] == jnp.float32 else 2e-2
     assert float(jnp.abs(got - want).max()) < tol * float(jnp.abs(want).max())
+
+
+@pytest.mark.parametrize("d,f,fwd,dx", [
+    pytest.param(2048, 1024, 1024, 2048, id="trinity"),
+    pytest.param(2304, 896, 896, 2304, id="mellum2"),
+    pytest.param(2048, 1792, 1792, 2048, id="lfm2"),
+    pytest.param(4096, 2048, 1024, 2048, id="past-the-limit"),
+])
+def test_the_gated_pair_takes_the_widest_block_whose_vmem_fits(d, f, fwd, dx):
+    """The pair's forward blocks F against the whole of d, its dx blocks d
+    against the whole of F: each takes the whole width where its VMEM
+    reckoning fits, else the widest multiple of 128 lanes that divides
+    it and fits; the next wider candidate does not fit."""
+    for n, k, want, is_dx in [(f, d, fwd, False), (d, f, dx, True)]:
+        got = gm._gated_tile(n, k, gm.ROW_TILE, 2, dx=is_dx)
+        assert got == want
+        assert gm._gated_vmem(got, k, gm.ROW_TILE, 2, dx=is_dx) <= gm._GATED_VMEM_LIMIT
+        wider = [b for b in range(got + 128, n + 1, 128) if n % b == 0]
+        assert all(gm._gated_vmem(b, k, gm.ROW_TILE, 2, dx=is_dx) > gm._GATED_VMEM_LIMIT
+                   for b in wider)
 
 
 def test_a_width_that_128_does_not_divide_is_refused_by_name():

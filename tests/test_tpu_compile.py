@@ -267,24 +267,27 @@ def test_grouped_swiglu_fwd_and_grads(compile_for_chip, d, f, held):
     }
 
 
-def test_grouped_products_in_column_blocks_at_lfm2_s_widths(compile_for_chip):
+def test_grouped_products_at_lfm2_s_widths_take_the_pair_s_blocks_whole(compile_for_chip):
     """The LFM2 cell's expert layer: d 2048, F 1792, 8 held experts, the
-    buffer of 8192 x 4 choices' worst case.  Both banks' whole blocks (2 x
-    2048 x 1792 bf16, 14.7 MB) pass the gated pair's budget, so its forward
-    takes ``_col_tile``'s blocks, 896 of 1792, and computes the activation
-    a block; its dx sums both products over blocks of 512 of 2048; ``wo``'s
-    products take the same blocks.  Every call within its VMEM limit."""
+    buffer of 8192 x 4 choices' worst case.  The gated pair's VMEM
+    reckoning fits both banks' whole 2048 x 1792 blocks (about 42 MB in
+    the forward, 54 MB in dx, under its 64 MiB limit), so its forward
+    takes F in one block of 1792 and its dx K in one block of 2048: each
+    row tile read, and its cotangents formed, once.  ``wo``'s products
+    and every dw keep ``_col_tile``'s blocks, 896 of 1792 and 512 of
+    2048.  Every call within its VMEM limit."""
     from ddl_tpu.obs.scope import kernel_tiles
     from ddl_tpu.ops.grouped_matmul import (
-        ROW_TILE, _GATED_BLOCK_BYTES, buffer_rows, grouped_matmul, grouped_swiglu,
+        ROW_TILE, _GATED_VMEM_LIMIT, _gated_vmem, buffer_rows, grouped_matmul,
+        grouped_swiglu,
     )
 
     d, f, held = 2048, 1792, 8
-    assert 2 * d * f * 2 > _GATED_BLOCK_BYTES
+    assert _gated_vmem(f, d, ROW_TILE, 2, dx=False) <= _GATED_VMEM_LIMIT
+    assert _gated_vmem(d, f, ROW_TILE, 2, dx=True) <= _GATED_VMEM_LIMIT
     rows = buffer_rows(8192 * 4, held)
     tiles = rows // ROW_TILE
     idx = _s((tiles,), jnp.int32)
-    col = {"col1792": 896}
 
     def layer(x, wg, wi, wo, tg, ts, na):
         h = grouped_swiglu(x, wg, wi, tg, ts, na, interpret=False)
@@ -293,21 +296,22 @@ def test_grouped_products_in_column_blocks_at_lfm2_s_widths(compile_for_chip):
     bank = _s((held, d, f), F32)
     args = (_s((rows, d), BF16), bank, bank, _s((held, f, d), F32), idx, idx,
             _s((1,), jnp.int32))
-    fwd = {"calls": 2, "total": tiles * (2 + 4), "floor": held * (2 + 4), "products": 2 + 1,
-           **col}
+    # the pair's forward in one block of F, wo's in blocks of 512 of 2048
+    pair = {"col1792": 1792}
+    fwd = {"calls": 2, "total": tiles * (1 + 4), "floor": held * (1 + 4), "products": 2 + 1,
+           **pair}
     assert kernel_tiles(compile_for_chip(layer, *args)) == {"moe_gmm_fwd": fwd}
     text = compile_for_chip(
         jax.grad(lambda x, *w: _sum_f32(layer(x, *w)), argnums=(0, 1, 2, 3)), *args)
     assert kernel_tiles(text) == {
         # the sum is linear in wo's product: only the pair's forward is kept
-        "moe_gmm_fwd": {"calls": 1, "total": tiles * 2, "floor": held * 2, "products": 2, **col},
-        # the pair's dx in blocks of 512 of 2048 (no col entry: the
-        # constant's block), wo's dx in blocks of 896 of 1792
-        "moe_gmm_dx": {"calls": 2, "total": tiles * (4 + 2), "floor": held * (4 + 2),
-                       "products": 2 + 1, **col},
+        "moe_gmm_fwd": {"calls": 1, "total": tiles, "floor": held, "products": 2, **pair},
+        # the pair's dx in one block of 2048, wo's in blocks of 896 of 1792
+        "moe_gmm_dx": {"calls": 2, "total": tiles * (1 + 2), "floor": held * (1 + 2),
+                       "products": 2 + 1, "col2048": 2048, "col1792": 896},
         # the pair's two dw (2048 x 1792) and wo's (1792 x 2048): 8 blocks each
         "moe_gmm_dw": {"calls": 3, "total": 3 * tiles * 8, "floor": 3 * held * 8,
-                       "products": 3, **col},
+                       "products": 3, "col1792": 896},
     }
 
 

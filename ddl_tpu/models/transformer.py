@@ -54,6 +54,15 @@ plain rotary, sigmoid-scored dropless experts selected with a bias and
 normalised with ``route_eps``, a leading dense layer and a tied head are
 the lfm2_moe stack of ``benchmark/configs/lfm2-8b-a1b.json``.
 
+Latent attention (``Mla``, where ``kv_lora_rank`` is set) takes the place
+of ``Attention``: K and V projected up from one shared latent behind its
+own RMSNorm, and a rotary key part shared by every head beside each
+query's own rotary part (interleaved pairs, ``Rope(interleave=True)``);
+Q and K are wider than V.  With a leading dense layer, sigmoid-scored
+dropless experts selected with a bias beside two shared experts' width,
+a routed scaling factor and an untied head it is the deepseek_v3 stack
+of ``benchmark/configs/kanana-2-30b-a3b.json``.
+
 No torch/CUDA analog exists in the reference; parity citations therefore
 point at the subsystems this family plugs into: the mesh backbone
 (SURVEY.md §2 C10), the trainer (C3), and the checkpointing layout (C8).
@@ -75,6 +84,7 @@ from ddl_tpu.ops.attention import dense_attention
 __all__ = [
     "LAYER_KINDS",
     "LMConfig",
+    "Mla",
     "Rope",
     "REMAT_POLICIES",
     "TransformerLM",
@@ -104,7 +114,10 @@ class Rope:
     r_i)`` with ``r_i = clip((i - low) / (high - low), 0, 1)``, ``low``
     and ``high`` the indices whose wavelengths make ``beta_fast`` and
     ``beta_slow`` turns over ``original`` positions; cos and sin times
-    ``attention_factor``."""
+    ``attention_factor``.  ``interleave`` pairs a head's dims ``(2i,
+    2i + 1)`` and rotates each pair in place, as DeepSeek's
+    ``rope_interleave`` weights are laid out; otherwise pair ``i`` is
+    ``(i, i + half)``, the half-split layout."""
 
     theta: float = 10000.0
     factor: float = 1.0
@@ -112,6 +125,7 @@ class Rope:
     beta_fast: float = 32.0
     beta_slow: float = 1.0
     attention_factor: float = 1.0
+    interleave: bool = False
 
     def __post_init__(self):
         if self.factor < 1.0 or (self.factor > 1.0 and self.original <= 0):
@@ -367,6 +381,17 @@ class LMConfig:
     ssm_expand: int = 2
     # the gated short convolution's taps (a conv layer's causal window)
     short_conv: int = 3
+    # --- latent attention (Mla, DeepSeek-V2/V3) in every attention layer
+    # where kv_lora_rank > 0: q = x W_q (n_heads x (qk_nope_head_dim +
+    # qk_rope_head_dim)); [c, k_pe] = x W_kva, c the kv_lora_rank-wide
+    # latent behind its own RMSNorm; [k_nope, v] = c W_kvb per head;
+    # the rotary part of q and the one k_pe every head shares rotate by
+    # the layer's Rope; K/V heads are the query heads (head_dim and
+    # n_kv_heads unused) ---
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     def __post_init__(self):
         if self.layer_types:
@@ -435,6 +460,8 @@ class LMConfig:
                 )
         if self.rope_by_kind and self.diff_attn:
             raise ValueError("diff_attn rotates nothing: it takes no rope_by_kind")
+        if self.kv_lora_rank:
+            self._validate_mla()
         if self.moe_ep not in ("auto", "gspmd", "alltoall"):
             raise ValueError(
                 f"moe_ep must be 'auto', 'gspmd' or 'alltoall', got "
@@ -474,6 +501,32 @@ class LMConfig:
             raise ValueError(
                 f"ce_chunk must be >= 0, got {self.ce_chunk} (0 = dense CE)"
             )
+
+    def _validate_mla(self):
+        widths = (self.kv_lora_rank, self.qk_nope_head_dim, self.qk_rope_head_dim,
+                  self.v_head_dim)
+        if min(widths) <= 0 or self.qk_rope_head_dim % 2:
+            raise ValueError(
+                f"latent attention needs kv_lora_rank, qk_nope_head_dim, an even "
+                f"qk_rope_head_dim and v_head_dim all > 0, got {widths}")
+        if self.n_kv_heads not in (0, self.n_heads):
+            raise ValueError("latent attention projects K and V for every query head: "
+                             f"n_kv_heads must be 0 or n_heads, got {self.n_kv_heads}")
+        if self.diff_attn or self.qk_norm or self.attn_gate:
+            raise ValueError("latent attention has no differential heads, q/k norms "
+                             "or output gate")
+        if self.num_experts and not self.moe_dropless:
+            raise ValueError("latent attention beside the capacity expert layer is not "
+                             "built: it takes moe_layer='dropless'")
+        if self.attn_impl != "dense":
+            raise ValueError(f"latent attention runs on the dense and flash cores; "
+                             f"attn_impl={self.attn_impl!r} (ring, Ulysses) is not built "
+                             "for its Q/K wider than V")
+
+    @property
+    def mla(self) -> bool:
+        """Attention layers are latent attention (``Mla``)."""
+        return self.kv_lora_rank > 0
 
     @property
     def kv_heads(self) -> int:
@@ -622,6 +675,9 @@ def _rope(x, rope: Rope, positions=None):
         return y[:, :, None, :].astype(x.dtype)
 
     cos, sin = table(jnp.cos), table(jnp.sin)
+    if rope.interleave:
+        x1, x2 = x[..., 0::2], x[..., 1::2]
+        return jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).reshape(x.shape)
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
 
@@ -733,6 +789,13 @@ def refuse_cache_over_layer_types(cfg: LMConfig) -> None:
     """The one refusal of incremental decode over a layer pattern, raised
     by ``Block`` and ``Attention`` for any cache and by the serving
     factory before it builds a program."""
+    if cfg.mla:
+        raise NotImplementedError(
+            "this configuration trains only: its latent attention would cache "
+            "one latent and one rotary key a token and decode with the K and V "
+            "up-projections absorbed into the query and the output; neither "
+            "that cache kind nor that decode is built (ROADMAP R3)"
+        )
     if cfg.recurrent:
         raise NotImplementedError(
             f"this configuration trains only: its {', '.join(cfg.recurrent)} "
@@ -846,6 +909,60 @@ class Attention(nn.Module):
         )(o)
         out = nn.with_logical_constraint(out, ("batch", "act_seq", "act_embed"))
         return out if cache is None else (out, cache)
+
+
+class Mla(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2/V3), the training form:
+
+        q = x W_q                       (n_heads, nope + rope) a token
+        [c, k_pe] = x W_kva             the latent (kv_lora_rank) and one
+                                        rotary key (rope) every head shares
+        [k_nope, v] = RMSNorm(c) W_kvb  (n_heads, nope + v_head_dim)
+        Q = [q_nope, rot(q_pe)], K = [k_nope, rot(k_pe)], V = v
+
+    then the attention core over Q and K ``nope + rope`` wide and V
+    ``v_head_dim`` wide, scaled by ``(nope + rope)^-1/2`` (the cores scale
+    by Q's width), and ``W_o``.  Every projection is a ``QDense`` with no
+    bias in the compute type; the rotation is the layer's ``Rope`` over
+    the rotary parts only.  The latent path (``W_kva``, the latent's norm,
+    ``W_kvb`` and ``k_pe`` broadcast to the heads into K) runs under the
+    scope ``latent``: what an absorbed or fused latent path would take."""
+
+    cfg: LMConfig
+    attn_core: Optional[Callable] = None
+    window: int = 0
+    rope: Optional[Rope] = None
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.cfg
+        b, t, _ = x.shape
+        h, nope, rot = cfg.n_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+        rank, dv = cfg.kv_lora_rank, cfg.v_head_dim
+
+        def proj(features, axes, name):
+            return QDense(features, dtype=cfg.dtype, name=name,
+                          kernel_init=nn.with_logical_partitioning(
+                              nn.initializers.lecun_normal(), axes))
+
+        q = proj(h * (nope + rot), ("embed", "heads"), "q")(x).reshape(b, t, h, nope + rot)
+        with jax.named_scope("latent"):
+            ckv = proj(rank + rot, ("embed", None), "kv_a")(x)
+            c = RMSNorm(cfg.dtype, cfg.norm_eps, name="kv_norm")(ckv[..., :rank])
+            kv = proj(h * (nope + dv), (None, "heads"), "kv_b")(c).reshape(b, t, h, nope + dv)
+        q_pe, k_pe = q[..., nope:], ckv[..., None, rank:]
+        if self.rope is not None:
+            q_pe, k_pe = _rope(q_pe, self.rope), _rope(k_pe, self.rope)
+        q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+        with jax.named_scope("latent"):
+            k = jnp.concatenate([kv[..., :nope], jnp.broadcast_to(k_pe, (b, t, h, rot))],
+                                axis=-1)
+        spec = ("batch", "act_seq", "act_heads", None)
+        q, k, v = (nn.with_logical_constraint(a, spec) for a in (q, k, kv[..., nope:]))
+        o = nn.with_logical_constraint(_layer_core(cfg, self.attn_core, self.window)(q, k, v),
+                                       spec)
+        out = proj(cfg.d_model, ("heads", "embed"), "out")(o.reshape(b, t, h * dv))
+        return nn.with_logical_constraint(out, ("batch", "act_seq", "act_embed"))
 
 
 class DiffAttention(nn.Module):
@@ -1764,7 +1881,8 @@ class MoeMlp(nn.Module):
 class Block(nn.Module):
     """Pre-norm decoder block: ``x + mixer(norm(x))``, then ``x +
     mlp(norm(x))``.  The mixer is the layer's kind (``LMConfig.layer_kind``):
-    attention (``Attention``, or ``DiffAttention`` under ``diff_attn``), a
+    attention (``Attention``, ``Mla`` under ``kv_lora_rank``, or
+    ``DiffAttention`` under ``diff_attn``), a
     ``Mamba`` layer, a ``Gmu``, cross-attention or a ``ShortConv``.  With ``cache``
     (incremental decode) the return gains the updated cache: ``(x, aux,
     new_cache)``.  With ``carry`` (a stack in which layers read what
@@ -1804,6 +1922,9 @@ class Block(nn.Module):
             a = Gmu(cfg, name="gmu")(h, carry["ssm"])
         elif kind == "conv":
             a = ShortConv(cfg, name="conv")(h)
+        elif cfg.mla:
+            a = Mla(cfg, self.attn_core, cfg.layer_window(self.layer),
+                    cfg.layer_rope(self.layer), name="mla")(h)
         elif cfg.diff_attn:
             cross = kind == "cross_attention"
             a, kv = DiffAttention(

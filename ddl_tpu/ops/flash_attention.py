@@ -576,7 +576,7 @@ def _flash_fwd_impl(
     q_heads, kv_heads,
 ):
     bh, t, d = q.shape
-    dv = v.shape[-1]  # V's head may be wider than Q's and K's
+    dv = v.shape[-1]  # V's head may be wider or narrower than Q's and K's
     scale = 1.0 / (d ** 0.5)
     kv_idx = lambda b, i, j: (_kv_row(b, q_heads, kv_heads), j, 0)
     walk, metadata = _walk(
@@ -791,7 +791,10 @@ def flash_attention(
 ):
     """Flash attention. q: (B, T, H, D), k: (B, T, Hkv, D), v: (B, T, Hkv,
     Dv) -> (B, T, H, Dv); Dv is D unless V's head is wider than Q's and K's
-    (differential attention's ``[v1, v2]``).
+    (differential attention's ``[v1, v2]``) or narrower (latent attention's
+    128 under Q and K 192, which are not a whole number of lane tiles: the
+    blocks take the array's last dimension whole).  The scale is
+    ``1/sqrt(D)``.
 
     Grouped-query attention is native: ``Hkv < H`` (``H % Hkv == 0``) makes
     each K/V head serve ``H/Hkv`` query heads via BlockSpec indexing — the

@@ -1280,6 +1280,11 @@ def make_lm_pipeline_step_fns(
             "expert layer and tie_embeddings are built for "
             "make_lm_step_fns only"
         )
+    if cfg.mla:
+        raise NotImplementedError(
+            "latent attention is built for make_lm_step_fns only: the "
+            "pipeline's nested attention regions take Q, K and V of one width"
+        )
     mesh = build_lm_mesh(spec, devices)
     rules = lm_logical_rules(cfg.fsdp)
 

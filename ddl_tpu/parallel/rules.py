@@ -335,6 +335,13 @@ def _transformer_block_rules(E) -> tuple[tuple[str, P], ...]:
         (r"conv/in_proj/kernel$", P(E, "model")),
         (r"conv/conv/kernel$", P(None, "model")),
         (r"conv/out_proj/kernel$", P("model", E)),
+        # latent attention (Mla): q and the latent's up-projection
+        # column-parallel by heads, the out projection row-parallel; the
+        # latent and the shared rotary key are every head's, replicated
+        (r"mla/q/kernel$", P(E, "model")),
+        (r"mla/kv_a/kernel$", P(E, None)),
+        (r"mla/kv_b/kernel$", P(None, "model")),
+        (r"mla/out/kernel$", P("model", E)),
     )
 
 

@@ -174,6 +174,9 @@ STEP_PARTS = {
     # tag, so the Mamba layer's own ``ssm/conv`` stays under ``ssm``.
     "ssm": "ssm", "ssm/scan": "ssm/scan", "gmu": "gmu", "xattn": "xattn",
     "conv": "conv", "conv/short": "conv/short",
+    # latent attention, and inside it the latent path (the latent's
+    # projection and norm, K and V's up-projection, K's assembly)
+    "mla": "mla", "mla/latent": "mla/latent",
 }
 
 

@@ -725,9 +725,9 @@ def test_parts_table_of_a_compiled_step_names_every_tag(tmp_path):
     hbm.plan_program(writer, "train_step", fns.train, (state, tok, tok), parts=STEP_PARTS)
     parts, direction = hbm.scope_table("train_step.parts"), hbm.scope_table("train_step")
     assert set(parts) == set(direction)  # every ENTRY instruction, both ways
-    # every tag but the hybrid stacks' mixers' (tests/test_sambay.py and
-    # tests/test_lfm2.py have those)
-    hybrid = {"ssm", "ssm/scan", "gmu", "xattn", "conv", "conv/short"}
+    # every tag but the other stacks' mixers' (tests/test_sambay.py,
+    # tests/test_lfm2.py and tests/test_mla.py have those)
+    hybrid = {"ssm", "ssm/scan", "gmu", "xattn", "conv", "conv/short", "mla", "mla/latent"}
     assert set(parts.values()) == {*STEP_PARTS.values(), "other"} - hybrid
     assert set(direction.values()) <= {"fwd", "bwd", "update"}  # interpreted kernels: no custom call
     # the parts table does not displace the direction table by module

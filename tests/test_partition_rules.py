@@ -201,6 +201,39 @@ def test_sambay_table_matches_logical_resolution(fsdp):
 
 
 @pytest.mark.parametrize("fsdp", [False, True])
+def test_mla_table_matches_logical_resolution(fsdp):
+    """Latent attention's leaves each have a rule, and it is the one their
+    logical annotations resolve to: q and the latent's up-projection split
+    by heads over 'model', the out projection row-parallel, the latent's
+    projection whole on every model shard (its norm under the norm rule)."""
+    import flax.linen as nn
+
+    from ddl_tpu.models.transformer import LMConfig, TransformerLM
+
+    cfg = LMConfig(
+        vocab_size=512, d_model=64, n_layers=2, n_heads=4, d_ff=192,
+        compute_dtype="float32", fsdp=fsdp, mlp_gated=True,
+        kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=12,
+    )
+    abs_params = jax.eval_shape(
+        lambda r: TransformerLM(cfg, None).init(
+            r, jnp.zeros((4, 8), jnp.int32)
+        )["params"],
+        jax.random.key(0),
+    )
+    unboxed = nn.meta.unbox(abs_params)
+    specs = {R.tree_path_str(p): s for p, s in jtu.tree_leaves_with_path(
+        R.lm_rules(fsdp).specs(unboxed), is_leaf=lambda x: isinstance(x, P))}
+    e = "data" if fsdp else None
+    assert {k: v for k, v in specs.items() if k.startswith("block1/mla/")} == {
+        "block1/mla/q/kernel": P(e, "model"), "block1/mla/kv_a/kernel": P(e, None),
+        "block1/mla/kv_norm/scale": P(), "block1/mla/kv_b/kernel": P(None, "model"),
+        "block1/mla/out/kernel": P("model", e),
+    }
+    _assert_table_matches_logical(abs_params, R.lm_rules(fsdp), fsdp, _lm_mesh())
+
+
+@pytest.mark.parametrize("fsdp", [False, True])
 def test_vit_table_matches_logical_resolution(fsdp):
     from ddl_tpu.models.vit import ViT, ViTConfig
 

@@ -142,6 +142,77 @@ def test_flash_attention_with_a_wider_v_head(compile_for_chip, window):
         assert kernel_tiles(text)[name]["row_steps"] == 2 * 40 * plan[name]["row_steps"]
 
 
+def test_flash_attention_with_a_narrower_v_head(compile_for_chip):
+    """The Kanana-2 cell's latent attention: 32 query heads on as many
+    K/V heads, Q and K 192 wide (1.5 lane tiles), V 128; the output and dV
+    take V's width.  The backward keeps a head's dK and dV resident, 4096
+    x (256 + 128) lanes x 8 bytes = 12.6 MB of VMEM, under its limit."""
+    from ddl_tpu.obs.scope import kernel_tiles
+    from ddl_tpu.ops.flash_attention import flash_attention, flash_tile_plan
+
+    def attend(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=False)
+
+    qk, v = _s((2, 4096, 32, 192), BF16), _s((2, 4096, 32, 128), BF16)
+    assert jax.eval_shape(attend, qk, qk, v).shape == (2, 4096, 32, 128)
+    compile_for_chip(attend, qk, qk, v)
+    text = compile_for_chip(
+        jax.grad(lambda q, k, v: _sum_f32(attend(q, k, v)), argnums=(0, 1, 2)), qk, qk, v)
+    plan = flash_tile_plan(4096, causal=True)
+    assert kernel_tiles(text) == {
+        name: {"calls": 1, **{k: 2 * 32 * n for k, n in plan[name].items()}}
+        for name in ("flash_bwd_dkv", "flash_fwd")
+    }
+
+
+# (B, T, H, Hkv, D, Dv, window) of every flash call the benchmark's other LM
+# cells make, and the sha256 (first 16 hex digits) of each kernel's custom
+# call in the lowered gradient program, forward then backward, with no
+# source locations in it: taken at the commit before latent attention, so
+# those cells run the kernels they ran
+FLASH_PINS = {
+    "gpt2s": ((16, 1024, 12, 12, 64, 64, 0), ("bd20659f1635997a", "e7a45fbb992da802")),
+    "trinity-sliding": ((2, 4096, 32, 4, 128, 128, 2048),
+                        ("0fa27745bca6ecf3", "6edeedcd2a3a288d")),
+    "trinity-mellum-full": ((2, 4096, 32, 4, 128, 128, 0),
+                            ("f504e98e3c5dd97b", "cd41c7d7c8d8893d")),
+    "mellum-sliding": ((2, 4096, 32, 4, 128, 128, 1024),
+                       ("232c7a5401aef518", "204aa48323cf14f3")),
+    "phi4-sliding": ((2, 4096, 40, 20, 64, 128, 512), ("8d14f490146cbf40", "de3f894b611b6f64")),
+    "phi4-full": ((2, 4096, 40, 20, 64, 128, 0), ("99f81f1a21b54215", "7085bbc578bd86eb")),
+    "lfm2-full": ((2, 4096, 32, 8, 64, 64, 0), ("2401aefffa544332", "de6b1a26dae3c795")),
+}
+
+
+def test_the_other_cells_flash_kernels_lower_as_before(one_chip, no_persistent_cache):
+    import hashlib
+    import re
+
+    from ddl_tpu.ops.flash_attention import flash_attention
+
+    def s(shape):
+        return jax.ShapeDtypeStruct(shape, BF16, sharding=one_chip)
+
+    limit = jax.config.jax_traceback_in_locations_limit
+    jax.config.update("jax_traceback_in_locations_limit", 0)
+    jax.clear_caches()  # no kernel lowered with its source locations is reused
+    try:
+        got = {}
+        for name, ((b, t, h, hkv, d, dv, w), _) in FLASH_PINS.items():
+            def attend(q, k, v, w=w):
+                return flash_attention(q, k, v, causal=True, window=w, interpret=False)
+
+            text = jax.jit(jax.grad(lambda q, k, v: _sum_f32(attend(q, k, v)),
+                                    argnums=(0, 1, 2))).lower(
+                s((b, t, h, d)), s((b, t, hkv, d)), s((b, t, hkv, dv))).as_text()
+            got[name] = tuple(hashlib.sha256(c.encode()).hexdigest()[:16]
+                              for c in re.findall(r"tpu_custom_call\(.*", text))
+    finally:
+        jax.config.update("jax_traceback_in_locations_limit", limit)
+        jax.clear_caches()
+    assert got == {name: pins for name, (_, pins) in FLASH_PINS.items()}
+
+
 def test_selective_scan_fwd_and_grad_at_the_cell_s_widths(compile_for_chip):
     """The SambaY cell's Mamba layer: B=2, T=4096, d_inner 5120, state 16.
     Both kernels compile (128 unrolled steps a chunk; the backward's
@@ -366,6 +437,63 @@ def test_the_lfm2_cell_s_whole_step_fits_one_chip(topo, no_persistent_cache, mon
             + ma.temp_size_in_bytes)
     # 16 bytes a parameter: weights, Adam's two moments, the gradient's room
     assert ma.argument_size_in_bytes >= 12 * 507_820_288
+    assert plan < 16_909_336_064, plan
+
+
+def test_the_kanana_cell_s_whole_step_fits_one_chip(topo, no_persistent_cache, monkeypatch):
+    """The Kanana-2 cell's train step at its every width, batch 2 x 4096,
+    compiled for one described v5e from shapes alone: latent attention's
+    flash kernels at Q/K 192 over V 128, the grouped products and the row
+    kernels in it, and its plan (arguments, outputs not aliased to them,
+    temporaries) under the chip's ``bytes_limit`` (16,909,336,064 bytes),
+    with no remat."""
+    import importlib
+    import os
+    import sys
+
+    import flax.linen as nn
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark import harness
+    from benchmark.families.deepseek_v3 import lm_config
+    from ddl_tpu.models.transformer import TransformerLM
+    from ddl_tpu.parallel.sharding import LMMeshSpec
+    from ddl_tpu.train.lm_steps import LMTrainState, make_lm_step_fns
+    from ddl_tpu.train.state import build_optimizer
+
+    # the kernels' own entry points resolve the backend: here that is the CPU
+    for mod in ("flash_attention", "grouped_matmul", "moe_rows"):
+        monkeypatch.setattr(importlib.import_module(f"ddl_tpu.ops.{mod}"),
+                            "interpret_default", lambda: False)
+    spec = harness.load_cell(root, "kanana2.train_b2_t4096")
+    cfg, w = lm_config(spec["config"]["model"]), spec["workload"]
+    assert not cfg.remat and cfg.mla
+    o = w["optimizer"]
+    tx = build_optimizer(o["learning_rate"], b1=o["b1"], b2=o["b2"], eps=o["eps"])
+    fns = make_lm_step_fns(cfg, LMMeshSpec(), tx, jax.random.key(0), w["batch"], w["seq_len"],
+                           devices=topo.devices[:1])
+    tok = jnp.zeros((w["batch"], w["seq_len"]), jnp.int32)
+    params = nn.meta.unbox(jax.eval_shape(
+        lambda: TransformerLM(cfg).init(jax.random.key(0), tok)["params"]))
+    state = LMTrainState(step=_s((), jnp.int32), params=params,
+                         opt_state=jax.eval_shape(tx.init, params))
+    rep = NamedSharding(fns.mesh, P())
+    state, tok = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=rep), (state, tok))
+    compiled = fns.train.lower(state, tok, tok).compile()
+    text = compiled.as_text()
+    for kernel in ("moe_gmm_fwd", "moe_gmm_dx", "moe_gmm_dw", "moe_rows_gather", "flash_fwd",
+                   "flash_bwd_dkv"):
+        assert kernel in text, kernel
+    ma = compiled.memory_analysis()
+    plan = (ma.argument_size_in_bytes + ma.output_size_in_bytes - ma.alias_size_in_bytes
+            + ma.temp_size_in_bytes)
+    # 12 bytes a parameter in: weights and Adam's two moments (the
+    # gradient's room is among the temporaries)
+    assert ma.argument_size_in_bytes >= 12 * 424_961_024
     assert plan < 16_909_336_064, plan
 
 
